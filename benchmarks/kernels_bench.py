@@ -51,6 +51,41 @@ def _operands(n, w, seed=0):
     return weights, pre, v, st, teach
 
 
+def _mesh_bench(argv: list[str], marker: str) -> dict | None:
+    """Run ``repro.distributed.snn_mesh --bench`` in a child process on
+    a forced 8-device CPU mesh (the forced mesh would split this
+    process's thread pool and skew every other wall-clock row) and
+    return the ``marker`` line's key=value fields, None when it failed.
+
+    A child cannot reach an accelerator this process already holds, so
+    off the CPU the row fails loudly instead of timing something else.
+    """
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"the {marker} row forks a forced-CPU-mesh child and only runs "
+            f"on the CPU; this process holds {jax.default_backend()}")
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=8"
+                        ).strip()
+    env["PYTHONPATH"] = (str(_REPO / "src")
+                         + os.pathsep + env.get("PYTHONPATH", ""))
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-m", "repro.distributed.snn_mesh", "--bench",
+             *argv], env=env, capture_output=True, text=True, timeout=600)
+    except subprocess.TimeoutExpired as e:
+        proc = subprocess.CompletedProcess(e.cmd, -1, stdout="",
+                                           stderr="timeout after 600s")
+    row = next((ln for ln in proc.stdout.splitlines()
+                if ln.startswith(marker + " ")), None)
+    if proc.returncode != 0 or row is None:
+        print(f"# {marker} row skipped "
+              f"(rc={proc.returncode}): {proc.stderr.strip()[:200]}")
+        return None
+    return dict(p.split("=", 1) for p in row.split()[1:])
+
+
 def run() -> dict:
     out = {}
     for n, w in ((256, 32), (1024, 64), (4096, 256)):
@@ -174,31 +209,13 @@ def run() -> dict:
             "time_ratio": t_q / max(t_b, 1e-9)}
 
     # --- neuron axis: sharded window ops vs single-core -----------------
-    # Runs in a subprocess: the forced multi-device CPU mesh would split
-    # this process's thread pool and skew every other wall-clock row.
     ndev = 8
     n, w, t_steps, b = 1024, 64, 32, 8
     n_syn = w * 32
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={ndev}"
-                        ).strip()
-    env["PYTHONPATH"] = (str(_REPO / "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "repro.distributed.snn_mesh",
-             "--bench", "--devices", str(ndev), "--neurons", str(n),
-             "--words", str(w), "--steps", str(t_steps),
-             "--batch", str(b)],
-            env=env, capture_output=True, text=True, timeout=600)
-    except subprocess.TimeoutExpired as e:
-        proc = subprocess.CompletedProcess(e.cmd, -1, stdout="",
-                                           stderr="timeout after 600s")
-    row = next((ln for ln in proc.stdout.splitlines()
-                if ln.startswith("BENCH ")), None)
-    if proc.returncode == 0 and row is not None:
-        kv = dict(p.split("=", 1) for p in row.split()[1:])
+    kv = _mesh_bench(["--devices", str(ndev), "--neurons", str(n),
+                      "--words", str(w), "--steps", str(t_steps),
+                      "--batch", str(b)], "BENCH")
+    if kv is not None:
         t_1, t_d = float(kv["t_single_us"]), float(kv["t_shard_us"])
         # analytic per-device weight traffic: each device reads only its
         # n/D rows once per launch — the capacity metric that lets
@@ -211,9 +228,6 @@ def run() -> dict:
         out[("shard", n, n_syn, ndev)] = {
             "bytes_ratio": float(ndev),
             "time_ratio": t_1 / max(t_d, 1e-9)}
-    else:
-        print(f"# window-shard row skipped "
-              f"(rc={proc.returncode}): {proc.stderr.strip()[:200]}")
 
     # --- on-core encode: intensity stream vs pre-packed spike windows ---
     # The serving input shrinks from the T*w*4-byte packed window to the
@@ -304,25 +318,14 @@ def run() -> dict:
             "time_ratio": t_pre / max(t_enc, 1e-9)}
 
     # --- 2-D (data × neuron) mesh: the batched training grid sharded
-    # over BOTH axes vs the 1-D neuron mesh (same 8 devices).  Runs in a
-    # subprocess for the same thread-pool reason as the shard row.
+    # over BOTH axes vs the 1-D neuron mesh (same 8 devices).
     d2, n2 = 2, 4
     n, w, t_steps, b = 1024, 64, 32, 32
     n_syn = w * 32
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "repro.distributed.snn_mesh",
-             "--bench", "--mesh-shape", f"{d2},{n2}",
-             "--neurons", str(n), "--words", str(w),
-             "--steps", str(t_steps), "--batch", str(b)],
-            env=env, capture_output=True, text=True, timeout=600)
-    except subprocess.TimeoutExpired as e:
-        proc = subprocess.CompletedProcess(e.cmd, -1, stdout="",
-                                           stderr="timeout after 600s")
-    row = next((ln for ln in proc.stdout.splitlines()
-                if ln.startswith("BENCH2D ")), None)
-    if proc.returncode == 0 and row is not None:
-        kv = dict(p.split("=", 1) for p in row.split()[1:])
+    kv = _mesh_bench(["--mesh-shape", f"{d2},{n2}", "--neurons", str(n),
+                      "--words", str(w), "--steps", str(t_steps),
+                      "--batch", str(b)], "BENCH2D")
+    if kv is not None:
         t_1d, t_2d = float(kv["t_1d_us"]), float(kv["t_2d_us"])
         # structural per-device metrics: a (d, n) grid gives each device
         # b/d streams × 1/n of every regfile — weight traffic drops
@@ -335,9 +338,6 @@ def run() -> dict:
         out[("train-2d", n, n_syn, t_steps, b)] = {
             "bytes_ratio": float(d2),
             "time_ratio": t_1d / max(t_2d, 1e-9)}
-    else:
-        print(f"# train-2d row skipped "
-              f"(rc={proc.returncode}): {proc.stderr.strip()[:200]}")
 
     # analytic streaming extreme: at T=2048 the pre-packed input stream
     # is 256x the intensity bytes (and the encode kernel's VMEM holds no

@@ -50,7 +50,7 @@ def _static_int(x) -> int | None:
 
 
 def _make_plan(lif: LIFParams, stdp: STDPParams | None,
-               kernel_backend: str, window_chunk: int | None
+               kernel_backend: str | None, window_chunk: int | None
                ) -> SNNEnginePlan | None:
     """An engine plan from (possibly traced) params, or None if traced."""
     th, lk = _static_int(lif.threshold), _static_int(lif.leak)
@@ -78,7 +78,7 @@ def run_sample(
     teach: jnp.ndarray | None = None,
     *,
     cycle_backend: str = "window",
-    kernel_backend: str = "ref",
+    kernel_backend: str | None = None,
     window_chunk: int | None = None,
 ) -> SNNOutput:
     """Present one sample for T cycles.  stdp=None -> inference."""
@@ -103,7 +103,7 @@ def infer_batch(
     lif: LIFParams,
     *,
     cycle_backend: str = "window",
-    kernel_backend: str = "ref",
+    kernel_backend: str | None = None,
     window_chunk: int | None = None,
 ) -> jnp.ndarray:
     """Spike counts int32[B, n] for a batch (weights frozen).
@@ -134,7 +134,7 @@ def train_stream(
     stdp: STDPParams,
     *,
     cycle_backend: str = "window",
-    kernel_backend: str = "ref",
+    kernel_backend: str | None = None,
     window_chunk: int | None = None,
 ) -> tuple[SnnRegFile, jnp.ndarray]:
     """Online STDP over a stream of samples (sequential, as in hardware).
@@ -169,7 +169,7 @@ def train_stream_batch(
     stdp: STDPParams,
     *,
     cycle_backend: str = "window",
-    kernel_backend: str = "ref",
+    kernel_backend: str | None = None,
     window_chunk: int | None = None,
 ) -> tuple[SnnRegFile, jnp.ndarray]:
     """Online STDP over B independent streams, batched per launch.

@@ -77,7 +77,8 @@ class SNNTrainConfig:
     epochs: int = 2
     seed: int = 0x22A
     cycle_backend: str = "window"   # "window" (time-resident) | "step"
-    kernel_backend: str = "ref"     # "ref" | "interp" | "tpu"
+    kernel_backend: str | None = None  # "ref" | "interp" | "tpu"; None
+                                       # = the platform's (see plan.py)
     train_mode: str = "active"      # "active" (sequential blocks on the
                                     # error set) | "parallel" (batched
                                     # training grid, all blocks at once)

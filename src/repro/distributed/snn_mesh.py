@@ -74,7 +74,6 @@ if __name__ == "__main__":  # before any jax backend initialization
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.distributed.sharding import logical_spec, use_rules
@@ -156,10 +155,10 @@ def sharded_infer_window_batch(weights, spike_trains, *, threshold: int,
     row, trains, out = _specs(mesh, ("neurons", "syn_words"),
                               ("data", None, "syn_words"),
                               ("data", "neurons"))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ops.infer_window_batch, threshold=threshold,
                           leak=leak, t_chunk=t_chunk, backend=backend),
-        mesh=mesh, in_specs=(row, trains), out_specs=out, check_rep=False)
+        mesh=mesh, in_specs=(row, trains), out_specs=out, check_vma=False)
     return fn(wp, tp)[:b, :n]
 
 
@@ -189,13 +188,13 @@ def sharded_fused_snn_window(weights, spike_train, v, lfsr_state, teach, *,
     row, vec, rep2, ras = _specs(
         mesh, ("neurons", "syn_words"), ("neurons",),
         (None, "syn_words"), (None, "neurons"))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ops.fused_snn_window, threshold=threshold,
                           leak=leak, w_exp=w_exp, gain=gain, n_syn=n_syn,
                           ltp_prob=ltp_prob, train=train, t_chunk=t_chunk,
                           backend=backend),
         mesh=mesh, in_specs=(row, rep2, vec, row, vec),
-        out_specs=(row, vec, ras, row), check_rep=False)
+        out_specs=(row, vec, ras, row), check_vma=False)
     w2, v2, fired, s2 = fn(wp, spike_train, vp, sp, tp)
     return w2[:n], v2[:n], fired[:, :n], s2[:n]
 
@@ -236,9 +235,9 @@ def sharded_train_window_batch(weights, spike_trains, v, lfsr_state,
             w_exp=w_exp, gain=gain, n_syn=n_syn, ltp_prob=lp_,
             t_chunk=t_chunk, backend=backend)
 
-    fn = shard_map(call, mesh=mesh,
-                   in_specs=(row3, trains, vecb, row3, vecb, per),
-                   out_specs=(row3, vecb, ras3, row3), check_rep=False)
+    fn = jax.shard_map(call, mesh=mesh,
+                       in_specs=(row3, trains, vecb, row3, vecb, per),
+                       out_specs=(row3, vecb, ras3, row3), check_vma=False)
     w2, v2, fired, s2 = fn(wp, kp, vp, sp, tp, lp)
     return w2[:b, :n], v2[:b, :n], fired[:b, :, :n], s2[:b, :n]
 
@@ -278,8 +277,8 @@ def sharded_infer_window_batch_encode(weights, intensities, seeds, *,
             w, x, s, n_steps=n_steps, threshold=threshold, leak=leak,
             t_total=t, t_chunk=t_chunk, backend=backend)
 
-    fn = shard_map(call, mesh=mesh, in_specs=(row, inten, per, per),
-                   out_specs=out, check_rep=False)
+    fn = jax.shard_map(call, mesh=mesh, in_specs=(row, inten, per, per),
+                       out_specs=out, check_vma=False)
     return fn(wp, xp, sd, tt)[:b, :n]
 
 
@@ -318,8 +317,9 @@ def sharded_fused_snn_window_encode(weights, intensities, seed, v,
             ltp_prob=ltp_prob, train=train, t_chunk=t_chunk,
             backend=backend)
 
-    fn = shard_map(call, mesh=mesh, in_specs=(row, rep1, vec, row, vec),
-                   out_specs=(row, vec, ras, row), check_rep=False)
+    fn = jax.shard_map(call, mesh=mesh,
+                       in_specs=(row, rep1, vec, row, vec),
+                       out_specs=(row, vec, ras, row), check_vma=False)
     w2, v2, fired, s2 = fn(wp, intensities, vp, sp, tp)
     return w2[:n], v2[:n], fired[:, :n], s2[:n]
 
@@ -363,9 +363,9 @@ def sharded_train_window_batch_encode(weights, intensities, seeds, v,
             leak=leak, w_exp=w_exp, gain=gain, n_syn=n_syn, ltp_prob=lp_,
             t_chunk=t_chunk, backend=backend)
 
-    fn = shard_map(call, mesh=mesh,
-                   in_specs=(row3, inten, per, vecb, row3, vecb, per),
-                   out_specs=(row3, vecb, ras3, row3), check_rep=False)
+    fn = jax.shard_map(call, mesh=mesh,
+                       in_specs=(row3, inten, per, vecb, row3, vecb, per),
+                       out_specs=(row3, vecb, ras3, row3), check_vma=False)
     w2, v2, fired, s2 = fn(wp, xp, sd, vp, sp, tp, lp)
     return w2[:b, :n], v2[:b, :n], fired[:b, :, :n], s2[:b, :n]
 

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 from jax.sharding import Mesh
 
 from repro.core.lif import LIFParams, lif_params
@@ -24,6 +25,13 @@ from repro.core.stdp import STDPParams, stdp_params
 _CYCLE_BACKENDS = ("window", "step")
 _KERNEL_BACKENDS = ("ref", "interp", "tpu")
 _ENCODE_BACKENDS = ("host", "kernel")
+
+
+def default_kernel_backend() -> str:
+    """The platform's kernel path: the compiled Pallas kernels on a TPU,
+    the pure-jnp reference everywhere else.  ``"interp"`` (Pallas
+    interpret mode) is never a default — only callers that ask get it."""
+    return "tpu" if jax.default_backend() == "tpu" else "ref"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,7 +59,8 @@ class SNNEnginePlan:
     ltp_prob: int = 16
     # --- dispatch -------------------------------------------------------
     cycle_backend: str = "window"    # "window" | "step"
-    kernel_backend: str = "ref"      # "ref" | "interp" | "tpu"
+    kernel_backend: str | None = None  # "ref" | "interp" | "tpu";
+                                       # None = default_kernel_backend()
     t_chunk: int | None = None       # VMEM spike-slab cycles (None = T)
     # --- encoding --------------------------------------------------------
     # Where intensity-driven verbs run the Poisson encode: "host" builds
@@ -67,6 +76,9 @@ class SNNEnginePlan:
                                      # built via snn_mesh2d on first use
 
     def __post_init__(self):
+        if self.kernel_backend is None:
+            object.__setattr__(self, "kernel_backend",
+                               default_kernel_backend())
         if self.cycle_backend not in _CYCLE_BACKENDS:
             raise ValueError(f"cycle_backend must be one of "
                              f"{_CYCLE_BACKENDS}, got "

@@ -135,6 +135,38 @@ def _encode_cycle(seed, cycle, iw):
     return out
 
 
+# --- block specs of the batched window grids ---------------------------------
+# Grid (i, j, k) = (neuron block, batch stream, time chunk).  The batch
+# dim of every block is squeezed (None), so kernels see 2-D state blocks
+# and 1-D per-neuron rows.  Per-neuron [B, n] arrays cross the call as
+# [B, 1, n]: a block's last two dims must be multiples of (8, 128) or
+# equal the array's, and (1, block_n) on [B, 1, n] is the latter.
+# Per-stream scalars (seeds, LTP probabilities, window lengths) are whole
+# i32[B] SMEM operands indexed by ``pl.program_id(1)``.
+
+_SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _state_block(bn, w):
+    return pl.BlockSpec((None, bn, w), lambda i, j, k: (j, i, 0))
+
+
+def _slab_block(rows, w):
+    return pl.BlockSpec((None, rows, w), lambda i, j, k: (j, k, 0))
+
+
+def _row_block(bn):
+    return pl.BlockSpec((None, None, bn), lambda i, j, k: (j, 0, i))
+
+
+def _intens_block(w):
+    return pl.BlockSpec((None, 8, w), lambda i, j, k: (j, 0, 0))
+
+
+def _raster_block(tc, bn):
+    return pl.BlockSpec((None, tc, bn), lambda i, j, k: (j, k, i))
+
+
 # --- SPU: spike process -------------------------------------------------------
 
 def _spike_process_kernel(s_ref, w_ref, o_ref):
@@ -203,7 +235,8 @@ def lif_step(v, count, threshold: int, leak: int, *, block_n=128,
 
 def _stdp_body(w, pre, fired, st, *, w_exp, gain, n_syn, ltp_prob):
     """Shared LTP+LTD dataflow (uint32 blocks).  Returns (w', st')."""
-    fired_u = fired[:, None]
+    # column mask via int32: Mosaic has no i1 shape cast (n,) -> (n, 1)
+    fired_u = fired.astype(jnp.int32)[:, None] != 0
     s1 = _lfsr_step(st)
     x_ltp = jnp.bitwise_and(s1, jnp.uint32(0x3FF))
     s2 = _lfsr_step(s1)
@@ -331,15 +364,14 @@ def _train_window_kernel(threshold, leak, w_exp, gain, n_syn,
     # per-stream LTP probability: an SMEM scalar operand rather than a
     # kernel literal, so the B streams of one launch can run different
     # active-learning schedules (ltp_prob vs ltp_prob_active)
-    ltp_prob = lp_ref[0, 0]
-    teach = t_ref[...][0]
+    ltp_prob = lp_ref[pl.program_id(1)]
+    teach = t_ref[...]
     base = k * t_chunk
     masked = t_total % t_chunk != 0   # zero-padded ragged tail present
 
     def cycle(t, carry):
         w, v, st = carry
-        pre = pl.load(s_ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                              slice(None)))[0]         # (1, W)
+        pre = s_ref[pl.ds(t, 1), :]                     # (1, W)
         counts = _popcount_rows(jnp.bitwise_and(pre, w)) + teach
         v_int = v + counts
         fired = v_int >= threshold
@@ -349,8 +381,7 @@ def _train_window_kernel(threshold, leak, w_exp, gain, n_syn,
             active = base + t < t_total
             fired = jnp.logical_and(fired, active)
             v_next = jnp.where(active, v_next, v)
-        pl.store(f_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 fired[None, None, :])
+        f_ref[pl.ds(t, 1), :] = fired[None, :]
         # masked `fired` also gates STDP: _stdp_body only commits w/LFSR
         # for fired rows, so padded cycles advance no state.
         w, st = _stdp_body(w, pre, fired, st, w_exp=w_exp, gain=gain,
@@ -358,11 +389,10 @@ def _train_window_kernel(threshold, leak, w_exp, gain, n_syn,
         return w, v_next, st
 
     w, v, st = jax.lax.fori_loop(
-        0, t_chunk, cycle,
-        (wo_ref[...][0], vo_ref[...][0], sto_ref[...][0]))
-    wo_ref[...] = w[None]
-    vo_ref[...] = v[None]
-    sto_ref[...] = st[None]
+        0, t_chunk, cycle, (wo_ref[...], vo_ref[...], sto_ref[...]))
+    wo_ref[...] = w
+    vo_ref[...] = v
+    sto_ref[...] = st
 
 
 def train_window_batch(weights, spike_trains, v, lfsr_state, teach, *,
@@ -381,8 +411,8 @@ def train_window_batch(weights, spike_trains, v, lfsr_state, teach, *,
     (including the LFSR sequence).
 
     ``ltp_prob`` is an int shared by every stream or an i32[B] vector —
-    it enters the kernel as an SMEM scalar operand (one (1, 1) block per
-    batch grid step), NOT a lowering literal, so parallel-mode training
+    it enters the kernel as a whole-array SMEM operand indexed by the
+    batch program id, NOT a lowering literal, so parallel-mode training
     keeps per-block active-learning schedules in a single launch.
 
     ``t_chunk`` bounds the spike words in VMEM to t_chunk * w per grid
@@ -407,30 +437,30 @@ def train_window_batch(weights, spike_trains, v, lfsr_state, teach, *,
                          f"got {lp.shape}")
     kern = functools.partial(_train_window_kernel, int(threshold),
                              int(leak), w_exp, gain, n_syn, tc, tt)
-    return pl.pallas_call(
+    w2, v2, fired, s2 = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((b, n, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((b, n), jnp.int32),
+                   jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
                    jax.ShapeDtypeStruct((b, t_steps, n), jnp.bool_),
                    jax.ShapeDtypeStruct((b, n, w), jnp.uint32)),
         grid=(n // block_n, b, t_steps // tc),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k: (j, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_n, w), lambda i, j, k: (j, i, 0)),
-            pl.BlockSpec((1, tc, w), lambda i, j, k: (j, k, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (j, i)),
-            pl.BlockSpec((1, block_n, w), lambda i, j, k: (j, i, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (j, i)),
+            _SMEM_WHOLE,
+            _state_block(block_n, w),
+            _slab_block(tc, w),
+            _row_block(block_n),
+            _state_block(block_n, w),
+            _row_block(block_n),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_n, w), lambda i, j, k: (j, i, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (j, i)),
-            pl.BlockSpec((1, tc, block_n), lambda i, j, k: (j, k, i)),
-            pl.BlockSpec((1, block_n, w), lambda i, j, k: (j, i, 0)),
+            _state_block(block_n, w),
+            _row_block(block_n),
+            _raster_block(tc, block_n),
+            _state_block(block_n, w),
         ),
         interpret=interpret,
-    )(lp[:, None], weights, spike_trains, v, lfsr_state, teach)
+    )(lp, weights, spike_trains, v[:, None], lfsr_state, teach[:, None])
+    return w2, v2[:, 0], fired, s2
 
 
 # --- time-resident fused window (T cycles per launch) -------------------------
@@ -449,7 +479,7 @@ def _window_infer_kernel(threshold, leak, t_chunk, t_total,
     masked = t_total % t_chunk != 0
 
     def cycle(t, v):
-        pre = pl.load(s_ref, (pl.dslice(t, 1), slice(None)))   # (1, W)
+        pre = s_ref[pl.ds(t, 1), :]                     # (1, W)
         v_int = v + _popcount_rows(jnp.bitwise_and(pre, w)) + teach
         fired = v_int >= threshold
         v_next = jnp.where(
@@ -458,7 +488,7 @@ def _window_infer_kernel(threshold, leak, t_chunk, t_total,
             active = base + t < t_total
             fired = jnp.logical_and(fired, active)
             v_next = jnp.where(active, v_next, v)
-        pl.store(f_ref, (pl.dslice(t, 1), slice(None)), fired[None, :])
+        f_ref[pl.ds(t, 1), :] = fired[None, :]
         return v_next
 
     vo_ref[...] = jax.lax.fori_loop(0, t_chunk, cycle, vo_ref[...])
@@ -536,8 +566,7 @@ def _infer_window_kernel(threshold, leak, t_chunk, t_total,
 
     def cycle(t, carry):
         v, acc = carry
-        pre = pl.load(s_ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                              slice(None)))[0]        # (1, W)
+        pre = s_ref[pl.ds(t, 1), :]                     # (1, W)
         v_int = v + _popcount_rows(jnp.bitwise_and(pre, w))
         fired = v_int >= threshold
         v_next = jnp.where(
@@ -549,9 +578,9 @@ def _infer_window_kernel(threshold, leak, t_chunk, t_total,
         return v_next, acc + fired.astype(jnp.int32)
 
     v, acc = jax.lax.fori_loop(
-        0, t_chunk, cycle, (vo_ref[...][0], o_ref[...][0]))
-    o_ref[...] = acc[None, :]
-    vo_ref[...] = v[None, :]
+        0, t_chunk, cycle, (vo_ref[...], o_ref[...]))
+    o_ref[...] = acc
+    vo_ref[...] = v
 
 
 def infer_window_batch(weights, spike_trains, *, threshold: int,
@@ -579,18 +608,17 @@ def infer_window_batch(weights, spike_trains, *, threshold: int,
                              int(leak), tc, tt)
     counts, _ = pl.pallas_call(
         kern,
-        out_shape=(jax.ShapeDtypeStruct((b, n), jnp.int32),
-                   jax.ShapeDtypeStruct((b, n), jnp.int32)),
+        out_shape=(jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
+                   jax.ShapeDtypeStruct((b, 1, n), jnp.int32)),
         grid=(n // block_n, b, t_steps // tc),
         in_specs=[
             pl.BlockSpec((block_n, w), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((1, tc, w), lambda i, j, k: (j, k, 0)),
+            _slab_block(tc, w),
         ],
-        out_specs=(pl.BlockSpec((1, block_n), lambda i, j, k: (j, i)),
-                   pl.BlockSpec((1, block_n), lambda i, j, k: (j, i))),
+        out_specs=(_row_block(block_n), _row_block(block_n)),
         interpret=interpret,
     )(weights, spike_trains)
-    return counts
+    return counts[:, 0]
 
 
 # --- encode-fused windows: spikes generated in VMEM, never read from HBM -----
@@ -613,7 +641,7 @@ def _window_infer_enc_kernel(threshold, leak, t_chunk, t_total,
     w = w_ref[...]
     iw = iw_ref[...]
     teach = t_ref[...]
-    seed = seed_ref[0, 0].astype(jnp.uint32)
+    seed = seed_ref[0].astype(jnp.uint32)
     base = k * t_chunk
     masked = t_total % t_chunk != 0
 
@@ -627,7 +655,7 @@ def _window_infer_enc_kernel(threshold, leak, t_chunk, t_total,
             active = base + t < t_total
             fired = jnp.logical_and(fired, active)
             v_next = jnp.where(active, v_next, v)
-        pl.store(f_ref, (pl.dslice(t, 1), slice(None)), fired[None, :])
+        f_ref[pl.ds(t, 1), :] = fired[None, :]
         return v_next
 
     vo_ref[...] = jax.lax.fori_loop(0, t_chunk, cycle, vo_ref[...])
@@ -643,12 +671,13 @@ def _infer_window_enc_kernel(threshold, leak, t_chunk,
         o_ref[...] = jnp.zeros_like(o_ref)
         vo_ref[...] = jnp.zeros_like(vo_ref)
 
+    j = pl.program_id(1)
     w = w_ref[...]
-    iw = iw_ref[...][0]
-    seed = seed_ref[0, 0].astype(jnp.uint32)
+    iw = iw_ref[...]
+    seed = seed_ref[j].astype(jnp.uint32)
     # per-SAMPLE window length from SMEM (not a literal): one launch
     # serves a ragged batch, masking each stream past its own t_total
-    tt = tt_ref[0, 0]
+    tt = tt_ref[j]
     base = k * t_chunk
 
     def cycle(t, carry):
@@ -664,9 +693,9 @@ def _infer_window_enc_kernel(threshold, leak, t_chunk,
         return v_next, acc + fired.astype(jnp.int32)
 
     v, acc = jax.lax.fori_loop(
-        0, t_chunk, cycle, (vo_ref[...][0], o_ref[...][0]))
-    o_ref[...] = acc[None, :]
-    vo_ref[...] = v[None, :]
+        0, t_chunk, cycle, (vo_ref[...], o_ref[...]))
+    o_ref[...] = acc
+    vo_ref[...] = v
 
 
 def _train_window_enc_kernel(threshold, leak, w_exp, gain, n_syn,
@@ -682,10 +711,11 @@ def _train_window_enc_kernel(threshold, leak, w_exp, gain, n_syn,
         vo_ref[...] = v_ref[...]
         sto_ref[...] = st_ref[...]
 
-    ltp_prob = lp_ref[0, 0]
-    seed = seed_ref[0, 0].astype(jnp.uint32)
-    iw = iw_ref[...][0]
-    teach = t_ref[...][0]
+    j = pl.program_id(1)
+    ltp_prob = lp_ref[j]
+    seed = seed_ref[j].astype(jnp.uint32)
+    iw = iw_ref[...]
+    teach = t_ref[...]
     base = k * t_chunk
     masked = t_total % t_chunk != 0
 
@@ -701,19 +731,17 @@ def _train_window_enc_kernel(threshold, leak, w_exp, gain, n_syn,
             active = base + t < t_total
             fired = jnp.logical_and(fired, active)
             v_next = jnp.where(active, v_next, v)
-        pl.store(f_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 fired[None, None, :])
+        f_ref[pl.ds(t, 1), :] = fired[None, :]
         # padded cycles: masked `fired` gates STDP (see train kernel)
         w, st = _stdp_body(w, pre, fired, st, w_exp=w_exp, gain=gain,
                            n_syn=n_syn, ltp_prob=ltp_prob)
         return w, v_next, st
 
     w, v, st = jax.lax.fori_loop(
-        0, t_chunk, cycle,
-        (wo_ref[...][0], vo_ref[...][0], sto_ref[...][0]))
-    wo_ref[...] = w[None]
-    vo_ref[...] = v[None]
-    sto_ref[...] = st[None]
+        0, t_chunk, cycle, (wo_ref[...], vo_ref[...], sto_ref[...]))
+    wo_ref[...] = w
+    vo_ref[...] = v
+    sto_ref[...] = st
 
 
 def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
@@ -744,33 +772,31 @@ def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
     kern = functools.partial(_train_window_enc_kernel, int(threshold),
                              int(leak), w_exp, gain, n_syn, tc,
                              int(n_steps))
-    return pl.pallas_call(
+    w2, v2, fired, s2 = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((b, n, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((b, n), jnp.int32),
+                   jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
                    jax.ShapeDtypeStruct((b, t_pad, n), jnp.bool_),
                    jax.ShapeDtypeStruct((b, n, w), jnp.uint32)),
         grid=(n // block_n, b, t_pad // tc),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k: (j, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j, k: (j, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_n, w), lambda i, j, k: (j, i, 0)),
-            pl.BlockSpec((1, 8, w), lambda i, j, k: (j, 0, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (j, i)),
-            pl.BlockSpec((1, block_n, w), lambda i, j, k: (j, i, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (j, i)),
+            _SMEM_WHOLE,
+            _SMEM_WHOLE,
+            _state_block(block_n, w),
+            _intens_block(w),
+            _row_block(block_n),
+            _state_block(block_n, w),
+            _row_block(block_n),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_n, w), lambda i, j, k: (j, i, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (j, i)),
-            pl.BlockSpec((1, tc, block_n), lambda i, j, k: (j, k, i)),
-            pl.BlockSpec((1, block_n, w), lambda i, j, k: (j, i, 0)),
+            _state_block(block_n, w),
+            _row_block(block_n),
+            _raster_block(tc, block_n),
+            _state_block(block_n, w),
         ),
         interpret=interpret,
-    )(lp[:, None], sd[:, None], weights, intens_words, v, lfsr_state,
-      teach)
+    )(lp, sd, weights, intens_words, v[:, None], lfsr_state, teach[:, None])
+    return w2, v2[:, 0], fired, s2
 
 
 def fused_snn_window_encode(weights, intens_words, seed, v, lfsr_state,
@@ -789,7 +815,7 @@ def fused_snn_window_encode(weights, intens_words, seed, v, lfsr_state,
     n, w = weights.shape
     tc, t_pad = _t_grid(n_steps, t_chunk)
     if not train:
-        sd = jnp.reshape(jnp.asarray(seed, jnp.int32), (1, 1))
+        sd = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
         v2, fired = pl.pallas_call(
             functools.partial(_window_infer_enc_kernel, int(threshold),
                               int(leak), tc, int(n_steps)),
@@ -797,8 +823,7 @@ def fused_snn_window_encode(weights, intens_words, seed, v, lfsr_state,
                        jax.ShapeDtypeStruct((t_pad, n), jnp.bool_)),
             grid=(n // block_n, t_pad // tc),
             in_specs=[
-                pl.BlockSpec((1, 1), lambda i, k: (0, 0),
-                             memory_space=pltpu.SMEM),
+                _SMEM_WHOLE,
                 pl.BlockSpec((block_n, w), lambda i, k: (i, 0)),
                 pl.BlockSpec((8, w), lambda i, k: (0, 0)),
                 pl.BlockSpec((block_n,), lambda i, k: (i,)),
@@ -841,19 +866,16 @@ def infer_window_batch_encode(weights, intens_words, seeds, t_totals, *,
                              int(leak), tc)
     counts, _ = pl.pallas_call(
         kern,
-        out_shape=(jax.ShapeDtypeStruct((b, n), jnp.int32),
-                   jax.ShapeDtypeStruct((b, n), jnp.int32)),
+        out_shape=(jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
+                   jax.ShapeDtypeStruct((b, 1, n), jnp.int32)),
         grid=(n // block_n, b, t_pad // tc),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j, k: (j, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, j, k: (j, 0),
-                         memory_space=pltpu.SMEM),
+            _SMEM_WHOLE,
+            _SMEM_WHOLE,
             pl.BlockSpec((block_n, w), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((1, 8, w), lambda i, j, k: (j, 0, 0)),
+            _intens_block(w),
         ],
-        out_specs=(pl.BlockSpec((1, block_n), lambda i, j, k: (j, i)),
-                   pl.BlockSpec((1, block_n), lambda i, j, k: (j, i))),
+        out_specs=(_row_block(block_n), _row_block(block_n)),
         interpret=interpret,
-    )(sd[:, None], tt[:, None], weights, intens_words)
-    return counts
+    )(sd, tt, weights, intens_words)
+    return counts[:, 0]

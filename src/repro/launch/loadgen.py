@@ -267,6 +267,7 @@ def main(argv=None) -> None:
                          "armed")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.loadgen import generate_rows, read_trace, write_trace
     from repro.loadgen.runner import rate_sweep
 
@@ -281,6 +282,7 @@ def main(argv=None) -> None:
     else:
         arrivals, workload = _build_specs(args)
         rows = None
+    enable_compile_cache()
 
     if args.record is not None:
         header = write_trace(args.record, arrivals, workload, rows,

@@ -161,6 +161,9 @@ def _serve_snn(args) -> None:
               f"version-audit={'ok' if version_bad == 0 else 'VIOLATION'}")
         if not args.inject_faults:
             gain_bad = int(acc_final <= acc_seed)
+    if eng.first_error is not None:
+        print(f"first-error: {eng.first_error} "
+              f"(degraded_launches={eng.degraded_launches})")
     if args.bench:
         stats["padded_slot_waste"] = round(stats["padded_slot_waste"], 4)
         if injector is not None:
@@ -170,7 +173,13 @@ def _serve_snn(args) -> None:
             # the k=v line stays whitespace-splittable
             f"{k}={'/'.join(map(str, v)) if isinstance(v, list) else v}"
             for k, v in sorted(stats.items())))
-    if non_terminal or mismatches or version_bad or gain_bad:
+    # with no faults injected, a launch served below rung 0 or any
+    # contained error means the fast path is broken: fail, loudly
+    degraded_bad = 0
+    if not args.inject_faults:
+        degraded_bad = int(eng.degraded_launches > 0
+                           or eng.first_error is not None)
+    if non_terminal or mismatches or version_bad or gain_bad or degraded_bad:
         sys.exit(1)
 
 
@@ -480,11 +489,15 @@ def main() -> None:
                     help="time-compression factor for the storm runs")
     args = ap.parse_args()
 
+    if args.arch == "wenquxing-snn" and args.chaos:
+        # the harness parent only starts and audits children: it stays
+        # off JAX, so a child can own the chip
+        return _chaos_snn(args)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.arch == "wenquxing-snn":
         if args.overload_storm:
             return _overload_storm_snn(args)
-        if args.chaos:
-            return _chaos_snn(args)
         return _serve_snn(args)
 
     cfg = reduced(get_config(args.arch))

@@ -481,6 +481,7 @@ class SNNServingEngine:
         self.failed = 0
         self.retried = 0            # launch re-attempts (all rungs)
         self.degraded = 0           # ladder steps taken
+        self.degraded_launches = 0  # launches that served below rung 0
         self.integrity_failures = 0
         self.canary_checks = 0
         self.canary_failures = 0
@@ -503,6 +504,7 @@ class SNNServingEngine:
         self._t_last_ms: float | None = None    # last completed step
         self._step_faults = 0
         self._last_error: str | None = None
+        self.first_error: str | None = None   # kept for the run's report
         self._canary_window: np.ndarray | None = None
         self._canary_golden: np.ndarray | None = None
         self._canary_version: int | None = None
@@ -947,7 +949,14 @@ class SNNServingEngine:
             counts = self._serve_windows(eng, batch, t_pad)
         if corrupt is not None:
             counts = np.asarray(corrupt(counts))
+        if level > 0:
+            self.degraded_launches += 1
         return counts
+
+    def _note_error(self, e: Exception) -> None:
+        self._last_error = f"{type(e).__name__}: {e}"
+        if self.first_error is None:
+            self.first_error = self._last_error
 
     def _degrade(self, reason: str) -> None:
         frm = self.level
@@ -977,7 +986,7 @@ class SNNServingEngine:
                                                attempt=attempts)
                 except Exception as e:  # noqa: BLE001 — contain faults
                     self._step_faults += 1
-                    self._last_error = f"{type(e).__name__}: {e}"
+                    self._note_error(e)
                     if attempts >= pol.max_retries:
                         break
                     if (self.overload is not None
@@ -1023,7 +1032,7 @@ class SNNServingEngine:
             for j, i in enumerate(bad):
                 counts[i] = good[j]
         except Exception as e:  # noqa: BLE001 — oracle re-serve failed
-            self._last_error = f"{type(e).__name__}: {e}"
+            self._note_error(e)
             unrepaired = set(bad)
         if (self.policy.degrade_on_integrity
                 and self.level < len(self._plans) - 1):
@@ -1061,7 +1070,7 @@ class SNNServingEngine:
                                       kind="canary")[0]
             ok = bool(np.array_equal(got, self._canary_golden))
         except Exception as e:  # noqa: BLE001 — canary launch died
-            self._last_error = f"{type(e).__name__}: {e}"
+            self._note_error(e)
             ok = False
         if not ok:
             self.canary_failures += 1
@@ -1116,7 +1125,7 @@ class SNNServingEngine:
                     "batch_size": 0, "t_lens": []})
             cand_w, epoch = rf.next_candidate(serving.weights)
         except Exception as e:  # noqa: BLE001 — contain refresh faults
-            self._last_error = f"{type(e).__name__}: {e}"
+            self._note_error(e)
             self.refresh_failed += 1
             self._refresh_event("refresh_failed", error=self._last_error)
             return
@@ -1145,7 +1154,7 @@ class SNNServingEngine:
             acc_cand = rf.probe(cand.weights)
             acc_cur = rf.probe(serving.weights)
         except Exception as e:  # noqa: BLE001 — probe died
-            self._last_error = f"{type(e).__name__}: {e}"
+            self._note_error(e)
             self.refresh_failed += 1
             self._store.reject(cand, f"probe failed: {self._last_error}")
             self._refresh_event("refresh_failed", version=cand.version,
@@ -1349,6 +1358,7 @@ class SNNServingEngine:
             "failed": self.failed,
             "retried": self.retried,
             "degraded": self.degraded,
+            "degraded_launches": self.degraded_launches,
             "integrity_failures": self.integrity_failures,
             "canary_checks": self.canary_checks,
             "canary_failures": self.canary_failures,

@@ -156,6 +156,22 @@ def test_retry_exhaustion_degrades_kernel_encode_to_host():
     assert eng.stats()["degraded"] == 1
 
 
+def test_degraded_launches_and_first_error_are_reported():
+    """Launches served below rung 0 are counted, and the first contained
+    error is kept for the run's report (the CLI fails a clean run on
+    either)."""
+    eng = SNNServingEngine(_weights(2), KPLAN,
+                           policy=SNNServingPolicy(max_retries=2),
+                           on_launch=FailFirstN(3))
+    eng.run([_intensity_request(i, 9) for i in range(3)])
+    assert eng.first_error == "FaultInjectedError: boom #1"
+    assert eng.degraded_launches == 1
+    assert eng.stats()["degraded_launches"] == 1
+    clean = SNNServingEngine(_weights(2), KPLAN)
+    clean.run([_intensity_request(i, 9) for i in range(3)])
+    assert clean.first_error is None and clean.degraded_launches == 0
+
+
 def test_failure_on_last_rung_marks_batch_failed_without_raising():
     weights = _weights(3)
     hook = FailFirstN(10**9)             # every launch dies
