@@ -1,0 +1,5 @@
+"""Windows served in the window over the window's seconds."""
+
+
+def read(run):
+    return run.completed / run.window_s if run.window_s > 0 else None
