@@ -259,6 +259,26 @@ rejected, rollbacks, save_crashes) and refresh-path counters
 / refresh_failed, probe_accuracy, version_violations — the latter must
 stay 0: every served response is attributable to a version that was
 promoted and live at serve time).
+
+Each ``step()`` is also a span, ``snn.step``, with child spans on the
+same thread (:mod:`repro.serving.spans`; they are
+:class:`jax.profiler.TraceAnnotation` s, so a profiler trace holds them
+beside the device's operations, whose clock may stand a millisecond or
+two off the host's): ``snn.refresh`` (a refresh cycle), ``snn.form``
+(batch formation; stat ``queued``), ``snn.journal`` (WAL append, sync,
+snapshot), one ``snn.launch`` per launch attempt (stats ``kind``,
+``level``, ``attempt``, ``batch``, ``slots``) holding ``snn.pad`` (host
+assembly of the padded batch), ``snn.put`` (host-to-device copies),
+``snn.dispatch`` (the engine call, until it returns the result not yet
+computed, and the release of the input buffers) and ``snn.fetch`` (the
+wait for the device, the copy back and the release of the result's
+buffer), then ``snn.guard`` and ``snn.finish`` (per-request bookkeeping,
+health and ladder counters, and the canary's ``snn.canary`` when one is
+due). ``snn.step`` carries ``step``, ``batch`` (0 when none formed),
+``cpu_us`` (the thread's CPU time), ``gc`` (collector passes) and
+``retraces`` (jaxpr traces in the process, a jit cache miss each). The
+last three are read only while a profiler is active; with none, every
+span is one shared no-op context.
 """
 
 from __future__ import annotations
@@ -276,6 +296,7 @@ from repro.kernels import ops
 from repro.loadgen.histogram import LatencyHistogram
 from repro.serving.journal import (_COUNTER_KEYS, RequestJournal, RingLog,
                                    replay)
+from repro.serving import spans
 from repro.serving.overload import (SHED_CODEL, LadderBreakers,
                                     OverloadController, OverloadPolicy)
 from repro.serving.weights import SNNWeightRefresher, VersionedWeightStore
@@ -898,33 +919,48 @@ class SNNServingEngine:
         lengths in, counts out; the batch tail pads with zero intensity
         (silent) and t_total=0."""
         plan = eng.plan
-        inten = np.zeros((plan.max_batch, self.n_inputs), np.uint8)
-        seeds = np.zeros((plan.max_batch,), np.int32)
-        t_total = np.zeros((plan.max_batch,), np.int32)
-        for i, r in enumerate(batch):
-            inten[i, :r.intensities.shape[0]] = r.intensities
-            seeds[i] = r.seed
-            t_total[i] = r.n_steps
-        return np.asarray(eng.infer(
-            self._pinned.weights, intensities=jnp.asarray(inten),
-            seeds=jnp.asarray(seeds), n_steps=t_pad,
-            t_total=jnp.asarray(t_total)))
+        with spans.span("pad"):
+            inten = np.zeros((plan.max_batch, self.n_inputs), np.uint8)
+            seeds = np.zeros((plan.max_batch,), np.int32)
+            t_total = np.zeros((plan.max_batch,), np.int32)
+            for i, r in enumerate(batch):
+                inten[i, :r.intensities.shape[0]] = r.intensities
+                seeds[i] = r.seed
+                t_total[i] = r.n_steps
+        return self._infer(eng, n_steps=t_pad, intensities=inten,
+                           seeds=seeds, t_total=t_total)
 
     def _serve_windows(self, eng: SNNEngine, batch,
                        t_pad: int) -> np.ndarray:
         """One pre-packed launch; intensity requests in a mixed batch
         are host-encoded here (bit-exact with the kernel draw)."""
         plan = eng.plan
-        stacked = np.zeros((plan.max_batch, t_pad, self.words),
-                           np.uint32)
-        for i, r in enumerate(batch):
-            win = r.window
-            if win is None:
-                win = np.asarray(encode_from_counter(
-                    r.seed, jnp.asarray(r.intensities), r.n_steps))
-            stacked[i, :win.shape[0], :win.shape[1]] = win
-        return np.asarray(
-            eng.infer(self._pinned.weights, jnp.asarray(stacked)))
+        with spans.span("pad"):
+            stacked = np.zeros((plan.max_batch, t_pad, self.words),
+                               np.uint32)
+            for i, r in enumerate(batch):
+                win = r.window
+                if win is None:
+                    win = np.asarray(encode_from_counter(
+                        r.seed, jnp.asarray(r.intensities), r.n_steps))
+                stacked[i, :win.shape[0], :win.shape[1]] = win
+        return self._infer(eng, windows=stacked)
+
+    def _infer(self, eng: SNNEngine, n_steps: int | None = None,
+               **host) -> np.ndarray:
+        """Copy the host arrays in, dispatch one launch on the pinned
+        bank, then wait for its counts and copy them back.  The input
+        buffers are released while the device runs, the result's once
+        it is copied, each inside its span."""
+        with spans.span("put"):
+            dev = {k: jnp.asarray(v) for k, v in host.items()}
+        with spans.span("dispatch"):
+            out = eng.infer(self._pinned.weights, n_steps=n_steps, **dev)
+            del dev
+        with spans.span("fetch"):
+            counts = np.asarray(out)
+            del out
+        return counts
 
     def _launch_counts(self, batch, t_pad: int, level: int, *,
                        hooked: bool = True, attempt: int = 0,
@@ -934,24 +970,26 @@ class SNNServingEngine:
         count-corruption callable) — except on ``kind="fallback"``
         oracle re-serves, which are never hooked."""
         eng = self._engine_for(level)
-        corrupt = None
-        if hooked and self.on_launch is not None:
-            corrupt = self.on_launch({
-                "step": self.steps, "attempt": attempt, "level": level,
-                "kind": kind, "batch_size": len(batch), "t_pad": t_pad,
-                "t_lens": [self._t_len(r) for r in batch]})
-        plan = eng.plan
-        intensity_only = all(r.window is None for r in batch)
-        if (intensity_only and plan.encode == "kernel"
-                and plan.cycle_backend == "window"):
-            counts = self._serve_intensities(eng, batch, t_pad)
-        else:
-            counts = self._serve_windows(eng, batch, t_pad)
-        if corrupt is not None:
-            counts = np.asarray(corrupt(counts))
-        if level > 0:
-            self.degraded_launches += 1
-        return counts
+        with spans.span("launch", kind=kind, level=level, attempt=attempt,
+                        batch=len(batch), slots=eng.plan.max_batch):
+            corrupt = None
+            if hooked and self.on_launch is not None:
+                corrupt = self.on_launch({
+                    "step": self.steps, "attempt": attempt, "level": level,
+                    "kind": kind, "batch_size": len(batch), "t_pad": t_pad,
+                    "t_lens": [self._t_len(r) for r in batch]})
+            plan = eng.plan
+            intensity_only = all(r.window is None for r in batch)
+            if (intensity_only and plan.encode == "kernel"
+                    and plan.cycle_backend == "window"):
+                counts = self._serve_intensities(eng, batch, t_pad)
+            else:
+                counts = self._serve_windows(eng, batch, t_pad)
+            if corrupt is not None:
+                counts = np.asarray(corrupt(counts))
+            if level > 0:
+                self.degraded_launches += 1
+            return counts
 
     def _note_error(self, e: Exception) -> None:
         self._last_error = f"{type(e).__name__}: {e}"
@@ -1100,7 +1138,8 @@ class SNNServingEngine:
         if self.steps - self._last_refresh_step < rf.policy.refresh_every:
             return
         self._last_refresh_step = self.steps
-        self._refresh_cycle()
+        with spans.span("refresh"):
+            self._refresh_cycle()
 
     def _refresh_cycle(self) -> None:
         """One probe-gated refresh, run BETWEEN serving steps (the
@@ -1201,15 +1240,26 @@ class SNNServingEngine:
         serving version — every launch this step (serve, retry, oracle
         re-serve, canary) reads the pinned bank, so a swap can never
         tear a batch."""
-        pol = self.policy
+        start = spans.counters() if spans.enabled() else None
+        with spans.span("step", step=self.steps) as sp:
+            finished, served = self._serve_step()
+            if start is not None and sp is not None:
+                sp.set_metadata(batch=served, **spans.since(start))
+        return finished
+
+    def _serve_step(self) -> tuple[int, int]:
+        """The body of :meth:`step`; returns (requests finished, size of
+        the batch served, 0 when none formed)."""
         self._maybe_refresh()
         self._store.swap_if_pending()
         self._pinned = self._store.serving
-        batch, finished = self._form_batch()
+        with spans.span("form", queued=len(self.queue)):
+            batch, finished = self._form_batch()
         if not batch:
             if self.journal is not None:
-                self._journal_sync()     # expiries found this step
-            return finished
+                with spans.span("journal"):
+                    self._journal_sync()     # expiries found this step
+            return finished, 0
         t0 = time.perf_counter()
         t_start_ms = self.clock.now_ms()
         self._step_faults = 0
@@ -1218,20 +1268,45 @@ class SNNServingEngine:
         if self.journal is not None:
             # group commit: buffered ADMITs + this DISPATCH become
             # durable together, before the launch can observe them
-            self.journal.append({
-                "ev": "D", "step": self.steps, "n": len(batch),
-                "pad": self.plan.max_batch - len(batch),
-                "ver": self._pinned.version,
-                "rids": [r.rid for r in batch], "at": t_start_ms})
-            self._journal_sync()
+            with spans.span("journal"):
+                self.journal.append({
+                    "ev": "D", "step": self.steps, "n": len(batch),
+                    "pad": self.plan.max_batch - len(batch),
+                    "ver": self._pinned.version,
+                    "rids": [r.rid for r in batch], "at": t_start_ms})
+                self._journal_sync()
             self._consult_crash("crash_before_dispatch")
         counts = self._launch_with_recovery(batch, t_pad)
         unrepaired: set[int] = set()
         if counts is not None:
-            counts, unrepaired = self._integrity_guard(batch, counts,
-                                                       t_pad)
+            with spans.span("guard"):
+                counts, unrepaired = self._integrity_guard(batch, counts,
+                                                           t_pad)
         if self.journal is not None:
             self._consult_crash("crash_after_serve")
+        with spans.span("finish"):
+            self._finish_batch(batch, counts, unrepaired, t_pad,
+                               t_start_ms)
+        finished += len(batch)
+        if self.journal is not None:
+            with spans.span("journal"):
+                self._journal_sync()     # TERMINALs durable at step end
+                if self.snapshot_every and \
+                        self.steps % self.snapshot_every == 0:
+                    self.journal.snapshot(
+                        self._snapshot_state(),
+                        crash_point=lambda: self._consult_crash(
+                            "crash_mid_snapshot"))
+        dt = time.perf_counter() - t0
+        self.step_seconds += dt
+        self.last_step_seconds = dt
+        return finished, len(batch)
+
+    def _finish_batch(self, batch, counts, unrepaired: set[int],
+                      t_pad: int, t_start_ms: float) -> None:
+        """Per-request bookkeeping of a served batch, the canary when one
+        is due, and the health and ladder counters."""
+        pol = self.policy
         infl_fn = getattr(self.on_launch, "service_inflation", None)
         infl = 1.0 if infl_fn is None else infl_fn(
             {"step": self.steps, "batch_size": len(batch),
@@ -1258,13 +1333,13 @@ class SNNServingEngine:
             self.windows_served += 1
             if self.overload is not None:
                 self.overload.note_served(r.service_ms)
-        finished += len(batch)
         self.steps += 1
         self.batches += 1
         self.slots_offered += self.plan.max_batch
         self.slots_padded += self.plan.max_batch - len(batch)
         if pol.canary_every and self.steps % pol.canary_every == 0:
-            self._canary_check()
+            with spans.span("canary"):
+                self._canary_check()
         if self._step_faults == 0:
             self.healthy_steps += 1
             if (self.level > 0 and pol.reprobe_after is not None
@@ -1282,18 +1357,6 @@ class SNNServingEngine:
                 self.breakers.close_trials()    # half-open trial passed
         else:
             self.healthy_steps = 0
-        if self.journal is not None:
-            self._journal_sync()         # TERMINALs durable at step end
-            if self.snapshot_every and \
-                    self.steps % self.snapshot_every == 0:
-                self.journal.snapshot(
-                    self._snapshot_state(),
-                    crash_point=lambda: self._consult_crash(
-                        "crash_mid_snapshot"))
-        dt = time.perf_counter() - t0
-        self.step_seconds += dt
-        self.last_step_seconds = dt
-        return finished
 
     def run(self, requests: list[SNNRequest], max_steps: int = 10_000
             ) -> list[SNNRequest]:
