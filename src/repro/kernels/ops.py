@@ -28,6 +28,7 @@ from repro.kernels import ref as _ref
 from repro.kernels import snn_kernels as _k
 
 _LANES = 128
+_SAMPLE_BLOCK = 32   # the MXU serving kernel's sample block: one int8 tile
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int, fill=0) -> jnp.ndarray:
@@ -326,7 +327,7 @@ def infer_window_batch_encode(weights, intensities, seeds, *,
 
     intensities uint8[B, n_in], seeds int | i32[B].  ``t_total``
     (i32[B], optional) is each sample's true window length — a traced
-    SMEM operand, NOT a static — so ragged serving batches share one
+    operand, NOT a static — so ragged serving batches share one
     compiled launch per (B, n_steps) bucket.  Returns counts i32[B, n];
     bit-exact in counts with host-encode + zero-mask + pre-packed serve
     (requires threshold >= 1, which serving enforces).
@@ -335,18 +336,28 @@ def infer_window_batch_encode(weights, intensities, seeds, *,
         return _ref.infer_window_batch_encode_ref(
             weights, intensities, seeds, n_steps, threshold, leak,
             t_total)
-    n, _ = weights.shape
-    b = intensities.shape[0]
-    bn = max(_block_n(max(8, n)), 8)
-    wp = _pad_state(weights, bn)
-    iw = _intensity_words(intensities, wp.shape[1])
+    n, w = weights.shape
+    b, n_in = intensities.shape
+    if n_in > w * 32:
+        raise ValueError(f"{n_in} intensities exceed the {w}-word spike "
+                         f"width ({w * 32} inputs)")
+    # the MXU kernel's layout: a neuron block of 256 (128 where n does
+    # not split into 256s), a sample block of 32 (one int8 tile of rows)
+    # and an input per lane.  Padded inputs have intensity 0 and padded
+    # samples window length 0, so neither fires.
+    bn = 256 if -(-n // _LANES) % 2 == 0 else _LANES
+    wp = _pad_to(_pad_to(weights, 1, _LANES), 0, bn)
+    x = _pad_to(_pad_to(jnp.asarray(intensities, jnp.int32), 1, _LANES),
+                0, _SAMPLE_BLOCK)
+    sd = jnp.broadcast_to(jnp.asarray(seeds, jnp.int32), (b,))
     tt = (jnp.full((b,), n_steps, jnp.int32) if t_total is None
           else jnp.asarray(t_total, jnp.int32))
     counts = _k.infer_window_batch_encode(
-        wp, iw, seeds, tt, n_steps=n_steps, threshold=threshold,
-        leak=leak, block_n=bn, t_chunk=t_chunk,
+        wp, x, _pad_to(sd, 0, _SAMPLE_BLOCK), _pad_to(tt, 0, _SAMPLE_BLOCK),
+        n_steps=n_steps, threshold=threshold, leak=leak,
+        block_b=_SAMPLE_BLOCK, block_n=bn, t_chunk=t_chunk,
         interpret=(backend == "interp"))
-    return counts[:, :n]
+    return counts[:b, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("threshold", "leak", "t_chunk",

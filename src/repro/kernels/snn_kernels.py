@@ -20,8 +20,10 @@ launch.
 
 Chunked spike streaming: every window kernel takes a ``t_chunk`` grid
 dimension (innermost, so per-(block, stream) state carries across
-chunks via revisited output blocks).  VMEM then holds ``T_chunk x W``
-spike words instead of ``T x W`` — unbounded T at bounded VMEM.  Chunk
+chunks via revisited output blocks; the MXU serving kernel puts the
+neuron block innermost and carries its state in VMEM scratch).  VMEM
+then holds ``T_chunk x W`` spike words instead of ``T x W`` —
+unbounded T at bounded VMEM.  Chunk
 boundaries are bit-exact with the unchunked kernel: membrane/weight/
 LFSR state is read back from the (still-resident) output block, and a
 ``t_total`` literal masks the zero-padded ragged tail so padded cycles
@@ -53,8 +55,14 @@ VMEM budget (per grid step, BN=128, padded words W<=2048):
   train encode:  the 4 MiB of state blocks + intensity words 8 * W * 4B
                  (64 KiB at W=2048) + the raster chunk — no spike slab
                  at ALL, so VMEM is independent of both T and T_chunk.
-  infer encode:  one weight block + intensity words + v/count rows
-                 — ~2.07 MiB; the T-dependent VMEM term vanishes.
+  infer encode:  the MXU kernel (BN=256, 32 samples a block): the int8
+                 spike tile 32 * T_chunk * K (K = n_in rounded up to 128;
+                 2.1 MiB at T_chunk=72, K=896, capped at 4 MiB by
+                 default), the unpacked int8 weight tile K * BN (224 KiB)
+                 and its 32-bit intermediate, one 512-row int32 matmul
+                 result (512 KiB), and v and the counts of every neuron
+                 block of the sample block (2 * 32 * n * 4B: 1.6 MiB at
+                 n=6,400) — ~5.6 MiB at the 6,400-neuron offline shape.
 
 The fused kernels are the TPU microarchitecture of the paper's
 coarse-granularity ``snn.step`` instruction: one pass through VMEM does
@@ -661,43 +669,6 @@ def _window_infer_enc_kernel(threshold, leak, t_chunk, t_total,
     vo_ref[...] = jax.lax.fori_loop(0, t_chunk, cycle, vo_ref[...])
 
 
-def _infer_window_enc_kernel(threshold, leak, t_chunk,
-                             seed_ref, tt_ref, w_ref, iw_ref,
-                             o_ref, vo_ref):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        vo_ref[...] = jnp.zeros_like(vo_ref)
-
-    j = pl.program_id(1)
-    w = w_ref[...]
-    iw = iw_ref[...]
-    seed = seed_ref[j].astype(jnp.uint32)
-    # per-SAMPLE window length from SMEM (not a literal): one launch
-    # serves a ragged batch, masking each stream past its own t_total
-    tt = tt_ref[j]
-    base = k * t_chunk
-
-    def cycle(t, carry):
-        v, acc = carry
-        pre = _encode_cycle(seed, (base + t).astype(jnp.uint32), iw)
-        v_int = v + _popcount_rows(jnp.bitwise_and(pre, w))
-        fired = v_int >= threshold
-        v_next = jnp.where(
-            fired, jnp.int32(0), jnp.maximum(v_int - leak, jnp.int32(0)))
-        active = base + t < tt
-        fired = jnp.logical_and(fired, active)
-        v_next = jnp.where(active, v_next, v)
-        return v_next, acc + fired.astype(jnp.int32)
-
-    v, acc = jax.lax.fori_loop(
-        0, t_chunk, cycle, (vo_ref[...], o_ref[...]))
-    o_ref[...] = acc
-    vo_ref[...] = v
-
-
 def _train_window_enc_kernel(threshold, leak, w_exp, gain, n_syn,
                              t_chunk, t_total,
                              lp_ref, seed_ref, w_ref, iw_ref, v_ref,
@@ -843,39 +814,163 @@ def fused_snn_window_encode(weights, intens_words, seed, v, lfsr_state,
     return w2[0], v2[0], fired[0], s2[0]
 
 
-def infer_window_batch_encode(weights, intens_words, seeds, t_totals, *,
-                              n_steps: int, threshold: int, leak: int,
-                              block_n=128, t_chunk: int | None = None,
-                              interpret=False):
-    """Serving kernel, intensity-resident: B windows generated in VMEM.
+# --- serving on the MXU: frozen weights make the input currents a matmul ----
+# A cycle's input count popcount(pre & w) does not depend on the membrane
+# once the weights are frozen, so the counts of a block of samples over a
+# block of cycles are one integer matmul (spikes[rows, K] @ w[K, BN], the
+# products 0/1 and the sums at most n_in, exact in int8 x int8 -> int32);
+# an elementwise LIF scan over the cycles follows.  The spike tile of a
+# (sample block, time chunk) is drawn once, at its first neuron block,
+# and reused from VMEM by every other neuron block.
 
-    intens_words u32[B, 8, w], seeds i32[B], t_totals i32[B] — the
-    per-sample window length is an SMEM scalar (NOT a literal), so one
-    launch serves a ragged batch: stream j's cycles at or past
-    ``t_totals[j]`` store no spikes and advance no state.  Zero-intensity
-    batch padding is silent by construction.  Bit-exact with
-    :func:`infer_window_batch` fed host-encoded (and zero-masked)
-    windows.  Returns spike counts int32[B, n].
+_MXU_ROWS = 512              # spike-tile rows (cycles x samples) per matmul
+_SPIKE_TILE_BYTES = 4 << 20  # VMEM cap of the int8 spike tile
+
+
+def _encode_tile(seed, cycle, inten):
+    """One cycle's spikes for a block of samples as a dense 0/1 tile.
+
+    seed u32[bb, 1], inten u32[bb, K] with input ``i`` in lane ``i``
+    (zero past n_in).  Entry (b, i) is 1 iff ``counter_hash(seed_b,
+    cycle, i) & 0xFF < inten[b, i]``: the draw of :func:`_encode_cycle`,
+    one input per lane instead of one per bit.
     """
-    n, w = weights.shape
-    b = intens_words.shape[0]
+    idx = jax.lax.broadcasted_iota(jnp.uint32, (1, inten.shape[1]), 1)
+    h = _counter_hash(seed, cycle, idx)
+    return (jnp.bitwise_and(h, jnp.uint32(0xFF)) < inten).astype(jnp.int8)
+
+
+def _unpack_weights(w, k):
+    """Packed u32[bn, Wp] weights -> 0/1 int8[k, bn], input ``i`` in row
+    ``i`` (bit i % 32 of word i // 32), ready as the matmul's right side."""
+    wt = w.T                                    # (Wp, bn): words on sublanes
+    bn = wt.shape[1]
+    shift = jax.lax.broadcasted_iota(jnp.uint32, (32, bn), 0)
+    rows = [jnp.bitwise_and(
+        jnp.right_shift(jnp.broadcast_to(wt[q:q + 1, :], (32, bn)), shift),
+        jnp.uint32(1)) for q in range(k // 32)]
+    return jnp.concatenate(rows, axis=0).astype(jnp.int8)
+
+
+def _sample_column(ref, start, bb):
+    """SMEM scalars ref[start : start + bb] as an i32[bb, 1] column."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bb, 128), 0)
+    col = jnp.zeros((bb, 128), jnp.int32)
+    for r in range(bb):
+        col = jnp.where(rows == r, ref[start + r], col)
+    return col[:, :1]
+
+
+def _infer_window_mxu_kernel(threshold, leak, t_chunk,
+                             seed_ref, tt_ref, w_ref, x_ref, o_ref,
+                             s_ref, v_ref, acc_ref):
+    i, k, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    bb, kk = x_ref.shape
+    bn = o_ref.shape[1]
+    base = k * t_chunk
+
+    @pl.when(j == 0)
+    def _draw():
+        seed = _sample_column(seed_ref, i * bb, bb).astype(jnp.uint32)
+        inten = x_ref[...].astype(jnp.uint32)
+
+        def cycle(t, carry):
+            row = pl.multiple_of(t * bb, bb)
+            s_ref[pl.ds(row, bb), :] = _encode_tile(
+                seed, (base + t).astype(jnp.uint32), inten)
+            return carry
+
+        jax.lax.fori_loop(0, t_chunk, cycle, 0)
+
+    @pl.when(k == 0)
+    def _reset():
+        v_ref[j] = jnp.zeros((bb, bn), jnp.int32)
+        acc_ref[j] = jnp.zeros((bb, bn), jnp.int32)
+
+    w = _unpack_weights(w_ref[...], kk)
+    # per-SAMPLE window length (a traced operand, not a literal): one
+    # launch serves a ragged batch, freezing each row past its t_total
+    tt = jnp.broadcast_to(_sample_column(tt_ref, i * bb, bb), (bb, bn))
+
+    def cycles(t0, n, carry):
+        """LIF over cycles t0 .. t0+n-1 of the chunk (n static)."""
+        v, acc = carry
+        cur = jnp.dot(s_ref[pl.ds(t0 * bb, n * bb), :], w,
+                      preferred_element_type=jnp.int32)
+        for u in range(n):
+            v_int = v + cur[u * bb:(u + 1) * bb]
+            fired = v_int >= threshold
+            v_next = jnp.where(fired, jnp.int32(0),
+                               jnp.maximum(v_int - leak, jnp.int32(0)))
+            active = base + t0 + u < tt
+            acc = acc + jnp.logical_and(fired, active).astype(jnp.int32)
+            v = jnp.where(active, v_next, v)
+        return v, acc
+
+    group = max(1, min(t_chunk, _MXU_ROWS // bb))
+    n_groups, tail = divmod(t_chunk, group)
+    carry = (v_ref[j], acc_ref[j])
+    if n_groups:
+        carry = jax.lax.fori_loop(
+            0, n_groups,
+            lambda g, c: cycles(pl.multiple_of(g * group, group), group, c),
+            carry)
+    if tail:
+        carry = cycles(n_groups * group, tail, carry)
+    v_ref[j], acc_ref[j] = carry
+    o_ref[...] = carry[1]
+
+
+def infer_window_batch_encode(weights, intensities, seeds, t_totals, *,
+                              n_steps: int, threshold: int, leak: int,
+                              block_b: int = 32, block_n: int = 128,
+                              t_chunk: int | None = None, interpret=False):
+    """Serving kernel, intensity-resident: B windows generated in VMEM,
+    their input currents computed on the MXU.
+
+    weights u32[n, Wp] packed; intensities i32[B, K] with input ``i`` in
+    column ``i`` (zero past n_in; K a multiple of 128 and at most 32 *
+    Wp); seeds, t_totals i32[B] — each sample's counter base and window
+    length, whole SMEM operands.  The length is a traced operand, NOT a
+    literal, so one launch serves a ragged batch: sample b's cycles at
+    or past ``t_totals[b]`` count no spikes and advance no state.
+    Requires B % block_b == 0 (block_b a multiple of 32) and n % block_n
+    == 0.
+
+    Grid (sample block, time chunk, neuron block), neuron block
+    innermost: each (sample block, chunk) draws its spike tile once, and
+    each sample block carries v and the counts of every neuron block
+    across chunks in VMEM.  ``t_chunk`` (default: the window, capped so
+    the spike tile stays under 4 MiB) bounds VMEM and changes no result.
+    Bit-exact with :func:`infer_window_batch` fed host-encoded (and
+    zero-masked) windows.  Returns spike counts int32[B, n].
+    """
+    n, wp = weights.shape
+    b, kk = intensities.shape
+    if t_chunk is None:
+        t_chunk = max(1, _SPIKE_TILE_BYTES // (block_b * kk))
     tc, t_pad = _t_grid(n_steps, t_chunk)
-    sd = jnp.broadcast_to(jnp.asarray(seeds, jnp.int32), (b,))
-    tt = jnp.broadcast_to(jnp.asarray(t_totals, jnp.int32), (b,))
-    kern = functools.partial(_infer_window_enc_kernel, int(threshold),
+    nb = n // block_n
+    kern = functools.partial(_infer_window_mxu_kernel, int(threshold),
                              int(leak), tc)
-    counts, _ = pl.pallas_call(
+    return pl.pallas_call(
         kern,
-        out_shape=(jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
-                   jax.ShapeDtypeStruct((b, 1, n), jnp.int32)),
-        grid=(n // block_n, b, t_pad // tc),
+        out_shape=jax.ShapeDtypeStruct((b, n), jnp.int32),
+        grid=(b // block_b, t_pad // tc, nb),
         in_specs=[
             _SMEM_WHOLE,
             _SMEM_WHOLE,
-            pl.BlockSpec((block_n, w), lambda i, j, k: (i, 0)),
-            _intens_block(w),
+            pl.BlockSpec((block_n, wp), lambda i, k, j: (j, 0)),
+            pl.BlockSpec((block_b, kk), lambda i, k, j: (i, 0)),
         ],
-        out_specs=(_row_block(block_n), _row_block(block_n)),
+        out_specs=pl.BlockSpec((block_b, block_n), lambda i, k, j: (i, j)),
+        scratch_shapes=[
+            pltpu.VMEM((tc * block_b, kk), jnp.int8),
+            pltpu.VMEM((nb, block_b, block_n), jnp.int32),
+            pltpu.VMEM((nb, block_b, block_n), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(sd, tt, weights, intens_words)
-    return counts[:, 0]
+        name="infer_window_batch_encode",
+    )(seeds, t_totals, weights, intensities)

@@ -154,6 +154,51 @@ def test_infer_batch_encode_ragged_matches_host_oracle(backend, t_chunk):
                                       np.asarray(want))
 
 
+# --- the serving kernel's MXU path against the integer oracle ---------------
+
+# (neurons, batch, inputs, window, t_chunk): 40 and 300 neurons (300 is
+# no multiple of the 128-neuron block), 512 (two blocks of 256), batches
+# of 3, 32 and 35 (two sample blocks), 784 and 100 inputs, the whole
+# window or chunks that leave a ragged tail
+_MXU_CASES = {
+    "n40-b3-in784": (40, 3, 784, 16, None),
+    "n40-b32-in784-chunk5": (40, 32, 784, 16, 5),
+    "n300-b3-in100-chunk5": (300, 3, 100, 12, 5),
+    "n300-b32-in100": (300, 32, 100, 9, None),
+    "n512-b35-in784-chunk3": (512, 35, 784, 8, 3),
+}
+
+
+@pytest.mark.parametrize("case", list(_MXU_CASES))
+def test_infer_batch_encode_mxu_matches_integer_oracle(case):
+    """Counts of the MXU serving kernel (interpret mode) equal the plain
+    integer oracle's: ragged lengths down to 1, intensity rows of 0
+    (silent) and 255, seeds near 2**31 - 1."""
+    from repro.kernels import ref
+
+    n, b, n_in, t_steps, t_chunk = _MXU_CASES[case]
+    rng = np.random.default_rng(n + b + n_in)
+    words = -(-n_in // 32)
+    weights = jnp.asarray(
+        rng.integers(0, 2**32, (n, words), dtype=np.uint32)
+        & rng.integers(0, 2**32, (n, words), dtype=np.uint32))
+    inten = rng.integers(0, 256, (b, n_in), dtype=np.uint8)
+    inten[0], inten[1] = 255, 0
+    seeds = jnp.asarray(2**31 - 1 - np.arange(b), jnp.int32)
+    tt = jnp.asarray([t_steps, 1] + [t_steps - i % t_steps
+                                     for i in range(b - 2)], jnp.int32)
+    lif = dict(threshold=192, leak=16) if n_in == 784 else \
+        dict(threshold=60, leak=4)
+    got = np.asarray(ops.infer_window_batch_encode(
+        weights, jnp.asarray(inten), seeds, n_steps=t_steps, t_total=tt,
+        t_chunk=t_chunk, backend="interp", **lif))
+    want = np.asarray(ref.infer_window_batch_encode_ref(
+        weights, jnp.asarray(inten), seeds, t_steps, t_total=tt, **lif))
+    np.testing.assert_array_equal(got, want)
+    assert got.shape == (b, n)
+    assert not got[1].any() and got[0].any()
+
+
 def test_encode_sharded_matches_unsharded_local_mesh():
     mesh = snn_mesh.snn_mesh()
     weights, inten, v, teach, st = _operands(6)

@@ -4,9 +4,10 @@ Interpret mode checks what a kernel computes, not whether the TPU
 compiler accepts its blocks: block tiling, SMEM operands and Mosaic's
 vector shape casts are only checked by compiling for the chip.  These
 tests compile the main path's window ops at the paper's width (784
-inputs, 40 neurons, T=72, B=32), the serving op at the 4,096-neuron
-ensemble, and one sharded infer on a described 2x2 mesh, each for a
-v5e that is described, not attached (nothing runs).
+inputs, 40 neurons, T=72, B=32), the packed serving op at the
+4,096-neuron ensemble, the encode serving op at the offline cell's
+shape (6,400 neurons, B=256), and one sharded infer on a described 2x2
+mesh, each for a v5e that is described, not attached (nothing runs).
 
 The topology is described inside module fixtures (never at import):
 only one process at a time may load the TPU library, and with several
@@ -17,6 +18,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -64,7 +66,7 @@ def one_chip(topo, no_persistent_cache):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _window_op_cases(sharding, n: int):
+def _window_op_cases(sharding, n: int, b: int = B):
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
 
@@ -78,8 +80,8 @@ def _window_op_cases(sharding, n: int):
             dict(LIF)),
         "infer_window_batch_encode": (
             ops.infer_window_batch_encode,
-            (s((n, w), u), s((B, 784), u8), s((B,), i)),
-            dict(n_steps=T, t_total=s((B,), i), **LIF)),
+            (s((n, w), u), s((b, 784), u8), s((b,), i)),
+            dict(n_steps=T, t_total=s((b,), i), **LIF)),
         "train_window_batch": (
             ops.train_window_batch,
             (s((B, n, w), u), s((B, T, w), u), s((B, n), i),
@@ -115,6 +117,18 @@ def test_window_op_compiles_for_v5e_at_paper_width(one_chip, name):
 def test_infer_window_batch_compiles_for_v5e_at_4096_neurons(one_chip):
     op, args, kw = _window_op_cases(one_chip, 4096)["infer_window_batch"]
     assert "tpu_custom_call" in _compiled_text(op, args, kw)
+
+
+def test_infer_encode_compiles_for_v5e_at_offline_shape(one_chip):
+    """The offline cell's launch (6,400 neurons, 256 windows, T=72): the
+    MXU kernel's tiles and scratch fit, and its operation keeps the name
+    the device trace finds it by."""
+    op, args, kw = _window_op_cases(one_chip, 6400, 256)[
+        "infer_window_batch_encode"]
+    text = _compiled_text(op, args, kw)
+    assert "tpu_custom_call" in text
+    assert re.search(r"%infer_window_batch_encode(\.\d+)? = \S+ "
+                     r"custom-call\(", text)
 
 
 def test_sharded_infer_compiles_on_described_2x2_mesh(topo,
