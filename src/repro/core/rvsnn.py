@@ -52,11 +52,17 @@ class SnnRegFile(NamedTuple):
     lfsr:    uint32[n, w]   PRNG lanes (LFSR register, vectorized)
     weights: uint32[n, w]   packed 1-bit synapse rows (synapse memory —
                             architecturally a register-addressed SRAM)
+    theta:   int32[n]       adaptive threshold offsets (None: none held).
+                            A plan with lateral inhibition or adaptive
+                            thresholds fires a neuron at threshold + θ and
+                            carries θ across samples, as weights and LFSR
+                            lanes; other plans leave it as it is.
     """
     spike: jnp.ndarray
     v: jnp.ndarray
     lfsr: jnp.ndarray
     weights: jnp.ndarray
+    theta: jnp.ndarray | None = None
 
 
 def snn_regfile(weights: jnp.ndarray, seed: int = 0x22A) -> SnnRegFile:
@@ -66,6 +72,7 @@ def snn_regfile(weights: jnp.ndarray, seed: int = 0x22A) -> SnnRegFile:
         v=jnp.zeros((n,), jnp.int32),
         lfsr=_lfsr.seed(seed, n * w).reshape(n, w),
         weights=weights,
+        theta=jnp.zeros((n,), jnp.int32),
     )
 
 
@@ -86,6 +93,7 @@ def snn_regfile_batch(weights: jnp.ndarray, seeds) -> SnnRegFile:
         lfsr=jnp.stack([_lfsr.seed(int(s), n * w).reshape(n, w)
                         for s in seeds]),
         weights=weights,
+        theta=jnp.zeros((b, n), jnp.int32),
     )
 
 

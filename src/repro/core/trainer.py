@@ -18,6 +18,13 @@ as a per-stream SMEM scalar operand, so block 0 trains at the base
 ``ltp_prob`` while blocks >= 1 keep the faster ``ltp_prob_active``
 schedule, exactly as in active mode.
 
+``train_mode="wta"`` is unsupervised: Diehl & Cook's single population,
+with no teacher current, learns by STDP while lateral inhibition
+(``w_inh``) and adaptive thresholds (``theta_plus``, θ carried from
+sample to sample) make its neurons compete and specialise; each neuron
+is then labelled with the class it answers most.  It needs
+``encode="kernel"``.
+
 Ingestion is intensity-resident when ``encode="kernel"``: the dataset
 is quantized ONCE to uint8[N, n_inputs] and stays that way — per-sample
 seeds come from the counter hash (:func:`encoder.sample_seeds`) and
@@ -74,6 +81,8 @@ class SNNTrainConfig:
                                  # (few, hard samples -> specialize)
     teach_pos: int = 64          # teacher current into the labeled neuron
     teach_neg: int = -1024       # inhibition into the others
+    w_inh: int = 0               # lateral inhibition per spike (wta)
+    theta_plus: int = 0          # threshold rise per spike (wta)
     epochs: int = 2
     seed: int = 0x22A
     cycle_backend: str = "window"   # "window" (time-resident) | "step"
@@ -82,6 +91,8 @@ class SNNTrainConfig:
     train_mode: str = "active"      # "active" (sequential blocks on the
                                     # error set) | "parallel" (batched
                                     # training grid, all blocks at once)
+                                    # | "wta" (one unsupervised
+                                    # population, inhibition and θ)
     window_chunk: int | None = None  # VMEM spike-slab size (None = T)
     encode: str = "host"             # dataset ingestion: "host" keeps
                                      # the legacy JAX-PRNG pre-encode;
@@ -228,6 +239,73 @@ def _train_blocks_parallel(cfg: SNNTrainConfig, key: jax.Array,
     return rfs.weights.reshape(b * cfg.n_classes, cfg.words)
 
 
+MIN_SPIKES = 5   # Diehl & Cook's least excitatory spikes a presentation
+
+
+def wta_chunk_step(cfg: SNNTrainConfig):
+    """The jitted chunk step of ``train_mode="wta"``: (rfs, intensities
+    uint8[N, n_inputs], seeds i32[N]) -> (rfs', spikes, silent).
+
+    ``rfs`` is one population's regfile with a leading stream axis of 1.
+    The N samples are presented one after another with no teacher
+    current (``train_stream_batch``); v resets between them, and the
+    weights, LFSR lanes and θ carry over.  ``spikes`` counts the
+    population's spikes over the chunk and ``silent`` the samples that
+    drew fewer than :data:`MIN_SPIKES`, both reduced on the device.
+    """
+    eng = SNNEngine(cfg.plan())
+
+    def chunk(rfs, intensities, seeds):
+        rfs, counts = _engine.train_stream_batch(
+            eng, rfs, intensities=intensities[None], seeds=seeds,
+            n_steps=cfg.n_steps)
+        per_sample = jnp.sum(counts[0], axis=-1)
+        return (rfs, jnp.sum(per_sample),
+                jnp.sum((per_sample < MIN_SPIKES).astype(jnp.int32)))
+
+    return jax.jit(chunk)
+
+
+def train_wta_chunk(step, rfs, intensities, seeds):
+    """One call of a :func:`wta_chunk_step`, in an ``snn.train`` span
+    with stats ``samples``, ``spikes`` and ``silent``; returns (rfs',
+    spikes, silent), the two counts read to the host."""
+    from repro.serving import spans
+
+    with spans.span("train", samples=int(intensities.shape[0])) as sp:
+        rfs, spikes, silent = step(rfs, intensities, seeds)
+        spikes, silent = int(spikes), int(silent)
+        if sp is not None:
+            sp.set_metadata(spikes=spikes, silent=silent)
+    return rfs, spikes, silent
+
+
+def _train_wta(cfg: SNNTrainConfig, key: jax.Array, labels: jnp.ndarray,
+               intensities: jnp.ndarray, sample_idx: jnp.ndarray
+               ) -> SNNModel:
+    """Unsupervised training of one population, then labels: each
+    neuron takes the class whose samples it answers most (the serving
+    path, which runs no inhibition)."""
+    wk, lk = jax.random.split(key)
+    w0 = init_weights(cfg.n_neurons, cfg.words,
+                      density_seed=_regfile_seed(wk), dense=False)
+    rfs = snn_regfile_batch(w0[None], [_regfile_seed(lk)])
+    step = wta_chunk_step(cfg)
+    for epoch in range(cfg.epochs):
+        rfs, _, _ = train_wta_chunk(
+            step, rfs, intensities,
+            sample_seeds_at(cfg.encode_seed, sample_idx, epoch))
+    weights = rfs.weights[0]
+    counts = SNNEngine(cfg.plan()).infer(
+        weights, intensities=intensities,
+        seeds=sample_seeds(cfg.encode_seed, intensities.shape[0]),
+        n_steps=cfg.n_steps)
+    onehot = jax.nn.one_hot(labels, cfg.n_classes, dtype=jnp.int32)
+    per_class = onehot.T @ counts                   # [n_classes, n]
+    return SNNModel(weights, jnp.argmax(per_class, axis=0).astype(
+        jnp.int32), cfg)
+
+
 def classify(model: SNNModel, spike_trains: jnp.ndarray | None = None,
              *, intensities: jnp.ndarray | None = None,
              seeds=None) -> jnp.ndarray:
@@ -273,9 +351,11 @@ def train(cfg: SNNTrainConfig, images: np.ndarray, labels: np.ndarray,
     the samples with fresh Poisson draws at zero memory cost, and epoch
     0 stays bit-exact with the historical seeds.
     """
-    if cfg.train_mode not in ("active", "parallel"):
-        raise ValueError(f"train_mode must be 'active' or 'parallel', "
-                         f"got {cfg.train_mode!r}")
+    if cfg.train_mode not in ("active", "parallel", "wta"):
+        raise ValueError(f"train_mode must be 'active', 'parallel' or "
+                         f"'wta', got {cfg.train_mode!r}")
+    if cfg.train_mode == "wta" and cfg.encode != "kernel":
+        raise ValueError("train_mode='wta' needs encode='kernel'")
     if key is None:
         key = jax.random.key(cfg.seed)
     key, ek = jax.random.split(key)
@@ -292,6 +372,8 @@ def train(cfg: SNNTrainConfig, images: np.ndarray, labels: np.ndarray,
             ek, jnp.asarray(images, jnp.float32), cfg.n_steps)
         intensities = seeds = sample_idx = None
 
+    if cfg.train_mode == "wta":
+        return _train_wta(cfg, key, labels_j, intensities, sample_idx)
     if cfg.train_mode == "parallel":
         key, bk = jax.random.split(key)
         weights = _train_blocks_parallel(

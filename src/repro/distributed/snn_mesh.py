@@ -199,12 +199,27 @@ def sharded_fused_snn_window(weights, spike_train, v, lfsr_state, teach, *,
     return w2[:n], v2[:n], fired[:, :n], s2[:n]
 
 
+def _refuse_coupling(w_inh: int, theta_plus: int, theta) -> None:
+    """The sharded train ops split a population's neurons over devices,
+    which a population coupled within a cycle cannot be."""
+    if w_inh:
+        raise ValueError(
+            "lateral inhibition (w_inh > 0) couples every neuron of a "
+            "population each cycle, so a neuron axis split over devices "
+            "would need a collective every cycle; one chip holds the "
+            "population: train it unsharded")
+    if theta_plus or theta is not None:
+        raise ValueError("adaptive thresholds (theta) are not carried by "
+                         "the sharded train ops: train unsharded")
+
+
 def sharded_train_window_batch(weights, spike_trains, v, lfsr_state,
                                teach, *, threshold: int, leak: int,
                                w_exp: int, gain: int, n_syn: int,
                                ltp_prob=1023, t_chunk: int | None = None,
                                backend: str = "ref",
-                               mesh: Mesh | None = None):
+                               mesh: Mesh | None = None, theta=None,
+                               w_inh: int = 0, theta_plus: int = 0):
     """:func:`ops.train_window_batch` over an SNN mesh.
 
     weights/lfsr u32[B, n, w], v/teach i32[B, n] shard on n AND on the
@@ -214,7 +229,10 @@ def sharded_train_window_batch(weights, spike_trains, v, lfsr_state,
     (data × neuron) mesh device (i, j) trains its B/dd streams × its
     n/nd rows; bit-exact with the single-device op for any
     factorization.  Returns (weights', v', fired bool[B, T, n], lfsr').
+    Refuses lateral inhibition and adaptive thresholds (``w_inh``,
+    ``theta_plus``, ``theta``).
     """
+    _refuse_coupling(w_inh, theta_plus, theta)
     mesh = snn_mesh() if mesh is None else mesh
     dd, nd = _dims(mesh)
     b, n, _ = weights.shape
@@ -331,7 +349,9 @@ def sharded_train_window_batch_encode(weights, intensities, seeds, v,
                                       ltp_prob=1023,
                                       t_chunk: int | None = None,
                                       backend: str = "ref",
-                                      mesh: Mesh | None = None):
+                                      mesh: Mesh | None = None,
+                                      theta=None, w_inh: int = 0,
+                                      theta_plus: int = 0):
     """:func:`ops.train_window_batch_encode` over an SNN mesh.
 
     Per-stream state shards on n and on "data"; intensities u8[B, n_in],
@@ -339,8 +359,11 @@ def sharded_train_window_batch_encode(weights, intensities, seeds, v,
     stream's n_in intensity bytes land exactly on the devices training
     that stream, so the 2-D mesh is the end-to-end intensity-resident
     placement: no spike window in HBM anywhere, no replicated dataset.
-    Bit-exact with the single-device op for any factorization.
+    Bit-exact with the single-device op for any factorization.  Refuses
+    lateral inhibition and adaptive thresholds, as
+    :func:`sharded_train_window_batch` does.
     """
+    _refuse_coupling(w_inh, theta_plus, theta)
     mesh = snn_mesh() if mesh is None else mesh
     dd, nd = _dims(mesh)
     b, n, _ = weights.shape

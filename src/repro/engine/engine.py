@@ -57,6 +57,14 @@ def reset_between_samples(rf: SnnRegFile) -> SnnRegFile:
     )
 
 
+def _with_theta(rf: SnnRegFile) -> SnnRegFile:
+    """``rf`` with a θ register: zeros (one per neuron) where it holds
+    none."""
+    if rf.theta is not None:
+        return rf
+    return rf._replace(theta=jnp.zeros_like(rf.v))
+
+
 def _teach_arr(teach, v) -> jnp.ndarray:
     return (jnp.zeros_like(v) if teach is None
             else teach.astype(jnp.int32))
@@ -180,6 +188,17 @@ class SNNEngine:
         """
         p = self.plan
         mesh = p.placement()
+        if p.wta:
+            # one stream of the batched verb, which steps θ
+            _one_of(window, intensities, n_steps, "train")
+            seed = p.encode_seed if seed is None else seed
+            rfs, counts, fired = self.train_batch(
+                jax.tree.map(lambda x: x[None], _with_theta(rf)),
+                teach=None if teach is None else teach[None],
+                intensities=intensities[None], seeds=seed,
+                n_steps=n_steps)
+            return SNNOutput(jax.tree.map(lambda x: x[0], rfs), counts[0],
+                             fired[0])
         if intensities is not None or window is None:
             _one_of(window, intensities, n_steps, "train")
             seed = p.encode_seed if seed is None else seed
@@ -263,30 +282,39 @@ class SNNEngine:
         lp = p.ltp_prob if ltp_prob is None else ltp_prob
         teach = _teach_arr(teach, rfs.v)
         mesh = p.placement()
+        if p.wta and intensities is None:
+            raise ValueError("train_batch: lateral inhibition and "
+                             "adaptive thresholds need intensities")
         if intensities is not None or windows is None:
             _one_of(windows, intensities, n_steps, "train_batch")
             seeds = self._seeds(seeds, intensities.shape[0])
             if p.encode == "kernel":
                 kwargs = {k: v for k, v in p.window_kwargs().items()
                           if k not in ("train", "ltp_prob")}
+                theta = rfs.theta
+                if p.wta:
+                    theta = _with_theta(rfs).theta
+                    kwargs.update(theta=theta, w_inh=p.w_inh,
+                                  theta_plus=p.theta_plus)
                 if mesh is not None:
                     from repro.distributed import snn_mesh
-                    w2, v2, fired, lf2 = \
-                        snn_mesh.sharded_train_window_batch_encode(
-                            rfs.weights, intensities, seeds, rfs.v,
-                            rfs.lfsr, teach.astype(jnp.int32),
-                            ltp_prob=lp, n_steps=n_steps,
-                            t_chunk=p.t_chunk,
-                            backend=p.kernel_backend, mesh=mesh,
-                            **kwargs)
+                    out = snn_mesh.sharded_train_window_batch_encode(
+                        rfs.weights, intensities, seeds, rfs.v,
+                        rfs.lfsr, teach.astype(jnp.int32),
+                        ltp_prob=lp, n_steps=n_steps,
+                        t_chunk=p.t_chunk, backend=p.kernel_backend,
+                        mesh=mesh, **kwargs)
                 else:
-                    w2, v2, fired, lf2 = ops.train_window_batch_encode(
+                    out = ops.train_window_batch_encode(
                         rfs.weights, intensities, seeds, rfs.v,
                         rfs.lfsr, teach.astype(jnp.int32), ltp_prob=lp,
                         n_steps=n_steps, t_chunk=p.t_chunk,
                         backend=p.kernel_backend, **kwargs)
+                w2, v2, fired, lf2 = out[:4]
+                if p.wta:
+                    theta = out[4]
                 rfs_out = rfs._replace(
-                    weights=w2, v=v2, lfsr=lf2,
+                    weights=w2, v=v2, lfsr=lf2, theta=theta,
                     spike=_last_cycle_spikes(seeds, intensities, n_steps,
                                              rfs.weights.shape[2]))
                 counts = jnp.sum(fired.astype(jnp.int32), axis=1)
@@ -354,6 +382,8 @@ def train_stream(engine: SNNEngine, rf: SnnRegFile,
     LFSR persist.  Returns (rf', spike_counts i32[N, n]).
     """
     _one_of(spike_trains, intensities, n_steps, "train_stream")
+    if engine.plan.wta:
+        rf = _with_theta(rf)
     if intensities is not None:
         seeds = engine._seeds(seeds, intensities.shape[0])
 
@@ -386,12 +416,17 @@ def train_stream_batch(engine: SNNEngine, rfs: SnnRegFile,
     Pass EITHER ``spike_trains`` uint32[B, N, T, w] OR uint8
     ``intensities`` [B, N, n_in] with ``n_steps`` and per-sample
     ``seeds`` i32[N] (shared by every stream, as broadcast spike trains
-    would be) or i32[B, N]; teach i32[B, N, n].  ``ltp_prob``
-    optionally carries the per-stream i32[B] schedule through every
-    launch.  Returns (rfs', spike_counts i32[B, N, n]).
+    would be) or i32[B, N]; teach i32[B, N, n] (None: no teacher
+    current).  ``ltp_prob`` optionally carries the per-stream i32[B]
+    schedule through every launch.  v resets before every sample; the
+    weights, LFSR lanes and, under a plan with lateral inhibition or
+    adaptive thresholds, θ carry over.  Returns (rfs', spike_counts
+    i32[B, N, n]).
     """
     _one_of(spike_trains, intensities, n_steps, "train_stream_batch")
-    teach_t = jnp.swapaxes(teach, 0, 1)
+    if engine.plan.wta:
+        rfs = _with_theta(rfs)
+    teach_t = None if teach is None else jnp.swapaxes(teach, 0, 1)
     if intensities is not None:
         b, n_samples = intensities.shape[:2]
         seeds = (engine._seeds(None, n_samples) if seeds is None

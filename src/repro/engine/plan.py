@@ -57,6 +57,13 @@ class SNNEnginePlan:
     gain: int = 4
     n_syn: int = 784
     ltp_prob: int = 16
+    # --- lateral inhibition and adaptive thresholds (training) ----------
+    # Each spike inhibits every other neuron of the population by w_inh
+    # the next cycle, and raises its own neuron's threshold offset θ by
+    # theta_plus (kernels/ref.py train_window_wta_ref); 0 and 0 leave
+    # the population uncoupled, with θ out of the step.
+    w_inh: int = 0
+    theta_plus: int = 0
     # --- dispatch -------------------------------------------------------
     cycle_backend: str = "window"    # "window" | "step"
     kernel_backend: str | None = None  # "ref" | "interp" | "tpu";
@@ -93,6 +100,13 @@ class SNNEnginePlan:
         if self.encode == "kernel" and self.cycle_backend != "window":
             raise ValueError("in-kernel encode requires the window "
                              "path; use cycle_backend='window'")
+        if self.w_inh < 0 or self.theta_plus < 0:
+            raise ValueError(f"w_inh and theta_plus must be >= 0, got "
+                             f"{self.w_inh} and {self.theta_plus}")
+        if self.wta and (self.encode != "kernel" or not self.learn):
+            raise ValueError("lateral inhibition and adaptive thresholds "
+                             "run in the in-kernel encode train kernel: "
+                             "use encode='kernel' and set w_exp")
         if self.t_chunk is not None and self.t_chunk < 1:
             raise ValueError(f"t_chunk must be >= 1, got {self.t_chunk}")
         if self.max_batch < 1:
@@ -128,6 +142,12 @@ class SNNEnginePlan:
             return None
         from repro.distributed.snn_mesh import snn_mesh2d
         return snn_mesh2d(*self.mesh_shape)
+
+    @property
+    def wta(self) -> bool:
+        """Whether training steps the population with lateral inhibition
+        or adaptive thresholds (θ in the step)."""
+        return bool(self.w_inh or self.theta_plus)
 
     @property
     def learn(self) -> bool:
@@ -174,5 +194,7 @@ def plan_from_config(cfg, block_idx: int = 0,
         kernel_backend=cfg.kernel_backend,
         t_chunk=cfg.window_chunk,
         encode=getattr(cfg, "encode", "host"),
-        encode_seed=getattr(cfg, "encode_seed", 0), mesh=mesh,
+        encode_seed=getattr(cfg, "encode_seed", 0),
+        w_inh=getattr(cfg, "w_inh", 0),
+        theta_plus=getattr(cfg, "theta_plus", 0), mesh=mesh,
         mesh_shape=None if mesh is not None else shape)

@@ -29,6 +29,9 @@ from repro.kernels import snn_kernels as _k
 
 _LANES = 128
 _SAMPLE_BLOCK = 32   # the MXU serving kernel's sample block: one int8 tile
+_WTA_ROWS = 128      # neurons a population with θ is stepped by
+_RASTER_BYTES = 2 << 20   # its raster chunk in VMEM
+_NEVER = 1 << 30     # the threshold offset of a padded neuron
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int, fill=0) -> jnp.ndarray:
@@ -283,37 +286,62 @@ def fused_snn_window_encode(weights, intensities, seed, v, lfsr_state,
 
 @functools.partial(jax.jit, static_argnames=(
     "n_steps", "threshold", "leak", "w_exp", "gain", "n_syn", "t_chunk",
-    "backend"))
+    "backend", "w_inh", "theta_plus"))
 def train_window_batch_encode(weights, intensities, seeds, v, lfsr_state,
                               teach, *, n_steps: int, threshold: int,
                               leak: int, w_exp: int, gain: int,
                               n_syn: int, ltp_prob=1023,
                               t_chunk: int | None = None,
-                              backend: str = "ref"):
+                              backend: str = "ref", theta=None,
+                              w_inh: int = 0, theta_plus: int = 0):
     """:func:`train_window_batch` with in-kernel encode.
 
     intensities uint8[B, n_in], seeds int | i32[B] (per-stream counter
     bases, an SMEM scalar operand like ``ltp_prob``).  Bit-exact with
     host-encoding each stream and running the pre-packed batch op.
-    Returns (weights', v', fired bool[B, T, n], lfsr').
+
+    ``theta`` i32[B, n] adds an adaptive threshold per neuron (fire at
+    ``threshold + θ``; ``θ += theta_plus`` on each spike) and, with
+    ``w_inh``, lateral inhibition within each stream's population
+    (:func:`repro.kernels.ref.train_window_wta_ref`); θ' is then a fifth
+    result.  ``w_inh`` and ``theta_plus`` need ``theta``.  With θ the
+    population is one neuron block stepped ``_WTA_ROWS`` rows at a time,
+    its window chunked so the raster block stays under ``_RASTER_BYTES``.
+    Returns (weights', v', fired bool[B, T, n], lfsr'[, theta']).
     """
+    if theta is None and (w_inh or theta_plus):
+        raise ValueError("w_inh and theta_plus need the theta operand")
     if backend == "ref":
         return _ref.train_window_batch_encode_ref(
             weights, intensities, seeds, v, lfsr_state, teach, n_steps,
-            threshold, leak, w_exp, gain, n_syn, ltp_prob)
+            threshold, leak, w_exp, gain, n_syn, ltp_prob, theta=theta,
+            w_inh=w_inh, theta_plus=theta_plus)
     b, n, w = weights.shape
     bn = max(_block_n(max(8, n)), 8)
+    rows = min(_WTA_ROWS, -(-n // 8) * 8)
+    if theta is not None:
+        bn = -(-n // rows) * rows       # one block: the whole population
+        cap = _RASTER_BYTES // (4 * bn) // 8 * 8
+        if t_chunk is None and n_steps > cap:
+            # whole sublane tiles of cycles, the fewest padded cycles
+            t_chunk = min(range(8, max(cap, 8) + 1, 8),
+                          key=lambda c: (-(-n_steps // c) * c, -c))
     wp = _pad_to(_pad_to(weights, 2, _LANES), 1, bn)
     iw = _intensity_words(intensities, wp.shape[2])
     vp = _pad_to(v, 1, bn)
     tp = _pad_to(teach, 1, bn)
     sp = _pad_to(_pad_to(lfsr_state, 2, _LANES, fill=1), 1, bn, fill=1)
-    w2, v2, f, s2 = _k.train_window_batch_encode(
+    # padded neurons never fire: their threshold is out of reach
+    thp = None if theta is None else _pad_to(theta, 1, bn, fill=_NEVER)
+    out = _k.train_window_batch_encode(
         wp, iw, seeds, vp, sp, tp, n_steps=n_steps, threshold=threshold,
         leak=leak, w_exp=w_exp, gain=gain, n_syn=n_syn,
-        ltp_prob=ltp_prob, block_n=bn, t_chunk=t_chunk,
+        ltp_prob=ltp_prob, block_n=bn, t_chunk=t_chunk, theta=thp,
+        w_inh=w_inh, theta_plus=theta_plus, rows=rows,
         interpret=(backend == "interp"))
-    return (w2[:, :n, :w], v2[:, :n], f[:, :n_steps, :n], s2[:, :n, :w])
+    w2, v2, f, s2 = out[:4]
+    res = (w2[:, :n, :w], v2[:, :n], f[:, :n_steps, :n], s2[:, :n, :w])
+    return res if theta is None else res + (out[4][:, :n],)
 
 
 @functools.partial(jax.jit, static_argnames=("n_steps", "threshold",

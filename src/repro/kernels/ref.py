@@ -125,15 +125,68 @@ def fused_snn_window_encode_ref(weights, intensities, seed, v, lfsr_state,
                                 ltp_prob, train)
 
 
+def train_window_wta_ref(weights, spike_train, v, lfsr_state, teach, theta,
+                         threshold: int, leak: int, w_exp: int, gain: int,
+                         n_syn: int, ltp_prob, w_inh: int,
+                         theta_plus: int):
+    """One population over T cycles with lateral inhibition and an
+    adaptive threshold per neuron (Diehl & Cook's excitatory layer, each
+    inhibitory neuron a one-cycle relay of its partner).  Per cycle t,
+    with f(t-1) the last cycle's spikes (0 before the first):
+
+        I_j   = popcount(pre(t) & w_j) + teach_j
+        inh_j = w_inh * (F(t-1) - f_j(t-1)),  F = sum_k f_k
+        u_j   = v_j + I_j - inh_j
+        f_j   = u_j >= threshold + θ_j
+        v_j   = 0 if f_j else max(u_j - leak, 0)
+        θ_j  += theta_plus * f_j
+        w, lfsr <- STDP(w, pre(t), f(t), lfsr)    (the unchanged rule)
+
+    With ``w_inh = 0`` and θ = 0 throughout (``theta_plus = 0``) this is
+    :func:`fused_snn_window_ref`.  Returns (weights', v', fired bool[T, n],
+    lfsr', theta').
+    """
+
+    def body(carry, pre):
+        w, vv, st, th, prev = carry
+        inh = w_inh * (jnp.sum(prev) - prev)
+        counts = spike_process_ref(pre, w) + teach - inh
+        v2, fired = _lif_step(vv, counts, LIFParams(threshold + th,
+                                                    jnp.int32(leak)))
+        spikes = fired.astype(jnp.int32)
+        w2, st2 = stdp_update_ref(w, pre, fired, st, w_exp, gain, n_syn,
+                                  ltp_prob)
+        return (w2, v2, st2, th + theta_plus * spikes, spikes), fired
+
+    zeros = jnp.zeros_like(v)
+    (w2, v2, st2, th2, _), fired = jax.lax.scan(
+        body, (weights, v, lfsr_state, theta, zeros), spike_train)
+    return w2, v2, fired, st2, th2
+
+
 def train_window_batch_encode_ref(weights, intensities, seeds, v,
                                   lfsr_state, teach, n_steps: int,
                                   threshold: int, leak: int, w_exp: int,
-                                  gain: int, n_syn: int, ltp_prob):
-    """Encode-fused batched training oracle."""
+                                  gain: int, n_syn: int, ltp_prob,
+                                  theta=None, w_inh: int = 0,
+                                  theta_plus: int = 0):
+    """Encode-fused batched training oracle; with ``theta`` i32[B, n],
+    each stream is one :func:`train_window_wta_ref` population and θ' is
+    a fifth result."""
     wins = _host_windows(seeds, intensities, n_steps, weights.shape[2])
-    return train_window_batch_ref(weights, wins, v, lfsr_state, teach,
-                                  threshold, leak, w_exp, gain, n_syn,
-                                  ltp_prob)
+    if theta is None:
+        return train_window_batch_ref(weights, wins, v, lfsr_state, teach,
+                                      threshold, leak, w_exp, gain, n_syn,
+                                      ltp_prob)
+    b = weights.shape[0]
+    lp = jnp.broadcast_to(jnp.asarray(ltp_prob, jnp.int32), (b,))
+
+    def one(w, s, vv, st, tc, th, lp_b):
+        return train_window_wta_ref(w, s, vv, st, tc, th, threshold, leak,
+                                    w_exp, gain, n_syn, lp_b, w_inh,
+                                    theta_plus)
+
+    return jax.vmap(one)(weights, wins, v, lfsr_state, teach, theta, lp)
 
 
 def infer_window_batch_encode_ref(weights, intensities, seeds,

@@ -55,6 +55,11 @@ VMEM budget (per grid step, BN=128, padded words W<=2048):
   train encode:  the 4 MiB of state blocks + intensity words 8 * W * 4B
                  (64 KiB at W=2048) + the raster chunk — no spike slab
                  at ALL, so VMEM is independent of both T and T_chunk.
+  train coupled: (lateral inhibition / adaptive thresholds) the whole
+                 population is one block: at 6,400 neurons 3.3 MB per
+                 state block, 26 MB for weights and LFSR in and out,
+                 double-buffered, plus a 32-cycle raster chunk (0.8 MB);
+                 the scoped VMEM limit is raised to 100 MiB.
   infer encode:  the MXU kernel (BN=256, 32 samples a block): the int8
                  spike tile 32 * T_chunk * K (K = n_in rounded up to 128;
                  2.1 MiB at T_chunk=72, K=896, capped at 4 MiB by
@@ -670,10 +675,16 @@ def _window_infer_enc_kernel(threshold, leak, t_chunk, t_total,
 
 
 def _train_window_enc_kernel(threshold, leak, w_exp, gain, n_syn,
-                             t_chunk, t_total,
+                             t_chunk, t_total, coupling,
                              lp_ref, seed_ref, w_ref, iw_ref, v_ref,
-                             st_ref, t_ref,
-                             wo_ref, vo_ref, f_ref, sto_ref):
+                             st_ref, t_ref, *refs):
+    if coupling is not None:    # (w_inh, theta_plus): the theta operand
+        _train_window_enc_coupled(
+            threshold, leak, w_exp, gain, n_syn, t_chunk, t_total,
+            *coupling, lp_ref, seed_ref, w_ref, iw_ref, v_ref, st_ref,
+            t_ref, *refs)
+        return
+    wo_ref, vo_ref, f_ref, sto_ref = refs
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -715,11 +726,96 @@ def _train_window_enc_kernel(threshold, leak, w_exp, gain, n_syn,
     sto_ref[...] = st
 
 
+def _train_window_enc_coupled(threshold, leak, w_exp, gain, n_syn,
+                              t_chunk, t_total, w_inh, theta_plus,
+                              lp_ref, seed_ref, w_ref, iw_ref, v_ref,
+                              st_ref, t_ref, th_ref, wo_ref, vo_ref, f_ref,
+                              sto_ref, tho_ref, fp_ref):
+    """The train body of a population with an adaptive threshold per
+    neuron and, where ``w_inh`` is set, lateral inhibition.
+
+    The block holds the whole population; its per-neuron rows (v, θ,
+    teach, the raster and the last cycle's spikes ``fp_ref``) are laid
+    out (groups, rows).  A cycle draws its spikes once, then steps one
+    group of ``rows`` neurons at a time against the state in the
+    (resident) output blocks: input current, inhibition ``w_inh * (F -
+    f_j)`` from the last cycle's spikes (F their sum, taken before any
+    group of this cycle is stepped), LIF against ``threshold + θ``, ``θ
+    += theta_plus`` on a spike, and STDP, which runs only for a group in
+    which a neuron fired (it commits nothing for the others, and their
+    LFSR lanes do not advance).
+    """
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _init():
+        wo_ref[...] = w_ref[...]
+        vo_ref[...] = v_ref[...]
+        sto_ref[...] = st_ref[...]
+        tho_ref[...] = th_ref[...]
+        fp_ref[...] = jnp.zeros_like(fp_ref)
+
+    j = pl.program_id(1)
+    ltp_prob = lp_ref[j]
+    seed = seed_ref[j].astype(jnp.uint32)
+    iw = iw_ref[...]
+    base = k * t_chunk
+    masked = t_total % t_chunk != 0
+    n_groups, rows = fp_ref.shape
+
+    def cycle(t, carry):
+        pre = _encode_cycle(seed, (base + t).astype(jnp.uint32), iw)
+        total = jnp.sum(fp_ref[...]) if w_inh else None
+
+        def group(g, c):
+            r = pl.ds(pl.multiple_of(g * rows, rows), rows)
+            gr = pl.ds(g, 1)
+            w = wo_ref[r, :]
+            v = vo_ref[gr, :]
+            theta = tho_ref[gr, :]
+            u = (v + _popcount_rows(jnp.bitwise_and(pre, w))[None, :]
+                 + t_ref[gr, :])
+            if w_inh:
+                u = u - jnp.int32(w_inh) * (total - fp_ref[gr, :])
+            fired = u >= threshold + theta
+            v_next = jnp.where(
+                fired, jnp.int32(0), jnp.maximum(u - leak, jnp.int32(0)))
+            if masked:
+                active = base + t < t_total
+                fired = jnp.logical_and(fired, active)
+                v_next = jnp.where(active, v_next, v)
+            spikes = fired.astype(jnp.int32)
+            vo_ref[gr, :] = v_next
+            fp_ref[gr, :] = spikes
+            if theta_plus:
+                tho_ref[gr, :] = theta + jnp.int32(theta_plus) * spikes
+            f_ref[t, gr, :] = fired
+
+            @pl.when(jnp.sum(spikes) > 0)
+            def _learn():
+                w2, st2 = _stdp_body(w, pre, spikes[0], sto_ref[r, :],
+                                     w_exp=w_exp, gain=gain, n_syn=n_syn,
+                                     ltp_prob=ltp_prob)
+                wo_ref[r, :] = w2
+                sto_ref[r, :] = st2
+
+            return c
+
+        return jax.lax.fori_loop(0, n_groups, group, carry)
+
+    jax.lax.fori_loop(0, t_chunk, cycle, 0)
+
+
+_THETA_VMEM_LIMIT = 100 << 20   # of the v5e core's 128 MiB of VMEM
+
+
 def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
                               teach, *, n_steps: int, threshold: int,
                               leak: int, w_exp: int, gain: int,
                               n_syn: int, ltp_prob, block_n=128,
-                              t_chunk: int | None = None, interpret=False):
+                              t_chunk: int | None = None, theta=None,
+                              w_inh: int = 0, theta_plus: int = 0,
+                              rows: int = 128, interpret=False):
     """B training streams whose spike windows are generated in VMEM.
 
     Same grid/carry scheme as :func:`train_window_batch`, but the spike
@@ -731,8 +827,14 @@ def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
     absolute cycle).  Bit-exact with :func:`train_window_batch` fed the
     ``encoder.encode_from_counter`` host windows.
 
-    Returns (weights', v', fired bool[B, T_pad, n], lfsr') with T_pad =
-    n_steps rounded up to the chunk (callers slice to n_steps).
+    ``theta`` i32[B, n] (adaptive thresholds) selects the body of
+    :func:`_train_window_enc_coupled`: one neuron block holds each
+    stream's whole population (``block_n`` is not used), stepped
+    ``rows`` neurons at a time (``rows`` divides n), with ``w_inh``
+    and ``theta_plus`` as trace-time literals; θ' is a fifth result.
+
+    Returns (weights', v', fired bool[B, T_pad, n], lfsr'[, theta']) with
+    T_pad = n_steps rounded up to the chunk (callers slice to n_steps).
     """
     b, n, w = weights.shape
     tc, t_pad = _t_grid(n_steps, t_chunk)
@@ -740,34 +842,70 @@ def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
     if lp.ndim == 0:
         lp = jnp.broadcast_to(lp, (b,))
     sd = jnp.broadcast_to(jnp.asarray(seeds, jnp.int32), (b,))
+    if theta is None:
+        if w_inh or theta_plus:
+            raise ValueError("w_inh and theta_plus need the theta operand")
+        kern = functools.partial(_train_window_enc_kernel, int(threshold),
+                                 int(leak), w_exp, gain, n_syn, tc,
+                                 int(n_steps), None)
+        w2, v2, fired, s2 = pl.pallas_call(
+            kern,
+            out_shape=(jax.ShapeDtypeStruct((b, n, w), jnp.uint32),
+                       jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
+                       jax.ShapeDtypeStruct((b, t_pad, n), jnp.bool_),
+                       jax.ShapeDtypeStruct((b, n, w), jnp.uint32)),
+            grid=(n // block_n, b, t_pad // tc),
+            in_specs=[
+                _SMEM_WHOLE,
+                _SMEM_WHOLE,
+                _state_block(block_n, w),
+                _intens_block(w),
+                _row_block(block_n),
+                _state_block(block_n, w),
+                _row_block(block_n),
+            ],
+            out_specs=(
+                _state_block(block_n, w),
+                _row_block(block_n),
+                _raster_block(tc, block_n),
+                _state_block(block_n, w),
+            ),
+            interpret=interpret,
+            name="train_window_batch_encode",
+        )(lp, sd, weights, intens_words, v[:, None], lfsr_state,
+          teach[:, None])
+        return w2, v2[:, 0], fired, s2
+    if n % rows:
+        raise ValueError(f"rows={rows} must divide the population n={n}")
+    g = n // rows
     kern = functools.partial(_train_window_enc_kernel, int(threshold),
                              int(leak), w_exp, gain, n_syn, tc,
-                             int(n_steps))
-    w2, v2, fired, s2 = pl.pallas_call(
+                             int(n_steps), (int(w_inh), int(theta_plus)))
+    state = pl.BlockSpec((None, n, w), lambda i, j, k: (j, 0, 0))
+    row = pl.BlockSpec((None, g, rows), lambda i, j, k: (j, 0, 0))
+    w2, v2, fired, s2, th2 = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((b, n, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
-                   jax.ShapeDtypeStruct((b, t_pad, n), jnp.bool_),
-                   jax.ShapeDtypeStruct((b, n, w), jnp.uint32)),
-        grid=(n // block_n, b, t_pad // tc),
-        in_specs=[
-            _SMEM_WHOLE,
-            _SMEM_WHOLE,
-            _state_block(block_n, w),
-            _intens_block(w),
-            _row_block(block_n),
-            _state_block(block_n, w),
-            _row_block(block_n),
-        ],
-        out_specs=(
-            _state_block(block_n, w),
-            _row_block(block_n),
-            _raster_block(tc, block_n),
-            _state_block(block_n, w),
-        ),
+                   jax.ShapeDtypeStruct((b, g, rows), jnp.int32),
+                   jax.ShapeDtypeStruct((b, t_pad, g, rows), jnp.bool_),
+                   jax.ShapeDtypeStruct((b, n, w), jnp.uint32),
+                   jax.ShapeDtypeStruct((b, g, rows), jnp.int32)),
+        grid=(1, b, t_pad // tc),
+        in_specs=[_SMEM_WHOLE, _SMEM_WHOLE, state, _intens_block(w), row,
+                  state, row, row],
+        out_specs=(state, row,
+                   pl.BlockSpec((None, tc, g, rows),
+                                lambda i, j, k: (j, k, 0, 0)),
+                   state, row),
+        scratch_shapes=[pltpu.VMEM((g, rows), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_THETA_VMEM_LIMIT),
         interpret=interpret,
-    )(lp, sd, weights, intens_words, v[:, None], lfsr_state, teach[:, None])
-    return w2, v2[:, 0], fired, s2
+        name="train_window_batch_encode",
+    )(lp, sd, weights, intens_words, v.reshape(b, g, rows), lfsr_state,
+      teach.reshape(b, g, rows), theta.reshape(b, g, rows))
+    return (w2, v2.reshape(b, n), fired.reshape(b, t_pad, n), s2,
+            th2.reshape(b, n))
 
 
 def fused_snn_window_encode(weights, intens_words, seed, v, lfsr_state,

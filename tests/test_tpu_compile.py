@@ -6,7 +6,9 @@ vector shape casts are only checked by compiling for the chip.  These
 tests compile the main path's window ops at the paper's width (784
 inputs, 40 neurons, T=72, B=32), the packed serving op at the
 4,096-neuron ensemble, the encode serving op at the offline cell's
-shape (6,400 neurons, B=256), and one sharded infer on a described 2x2
+shape (6,400 neurons, B=256), the encode train op at the unsupervised
+cell's shape (one stream of 6,400 neurons with lateral inhibition and
+adaptive thresholds, T=350), and one sharded infer on a described 2x2
 mesh, each for a v5e that is described, not attached (nothing runs).
 
 The topology is described inside module fixtures (never at import):
@@ -129,6 +131,25 @@ def test_infer_encode_compiles_for_v5e_at_offline_shape(one_chip):
     assert "tpu_custom_call" in text
     assert re.search(r"%infer_window_batch_encode(\.\d+)? = \S+ "
                      r"custom-call\(", text)
+
+
+def test_train_encode_compiles_for_v5e_at_the_wta_cell_shape(one_chip):
+    """The unsupervised cell's launch (one stream, 6,400 neurons coupled
+    by lateral inhibition, adaptive thresholds, T=350): the whole
+    population fits one block in VMEM, and the operation keeps the name
+    the device trace finds it by."""
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    u, i, n = jnp.uint32, jnp.int32, 6400
+    args = (s((1, n, N_IN_WORDS), u), s((1, 784), jnp.uint8), s((1,), i),
+            s((1, n), i), s((1, n, N_IN_WORDS), u), s((1, n), i))
+    kw = dict(n_steps=350, threshold=40, leak=0, w_exp=78, gain=4,
+              n_syn=784, ltp_prob=s((1,), i), theta=s((1, n), i),
+              w_inh=40, theta_plus=1)
+    text = _compiled_text(ops.train_window_batch_encode, args, kw)
+    assert "tpu_custom_call" in text
+    assert re.search(r"%train_window_batch_encode(\.\d+)? = \(", text)
 
 
 def test_sharded_infer_compiles_on_described_2x2_mesh(topo,
