@@ -244,39 +244,47 @@ MIN_SPIKES = 5   # Diehl & Cook's least excitatory spikes a presentation
 
 def wta_chunk_step(cfg: SNNTrainConfig):
     """The jitted chunk step of ``train_mode="wta"``: (rfs, intensities
-    uint8[N, n_inputs], seeds i32[N]) -> (rfs', spikes, silent).
+    uint8[N, n_inputs], seeds i32[N]) -> (rfs', spikes, silent,
+    recomputes).
 
     ``rfs`` is one population's regfile with a leading stream axis of 1.
     The N samples are presented one after another with no teacher
     current (``train_stream_batch``); v resets between them, and the
     weights, LFSR lanes and θ carry over.  ``spikes`` counts the
-    population's spikes over the chunk and ``silent`` the samples that
-    drew fewer than :data:`MIN_SPIKES`, both reduced on the device.
+    population's spikes over the chunk, ``silent`` the samples that
+    drew fewer than :data:`MIN_SPIKES` and ``recomputes`` the train
+    kernel's (cycle, 128-neuron group) pairs with a spike, each of
+    which recomputed that group's input currents; all three are reduced
+    on the device.
     """
     eng = SNNEngine(cfg.plan())
 
     def chunk(rfs, intensities, seeds):
-        rfs, counts = _engine.train_stream_batch(
+        rfs, counts, recomputes = _engine.train_stream_batch(
             eng, rfs, intensities=intensities[None], seeds=seeds,
-            n_steps=cfg.n_steps)
+            n_steps=cfg.n_steps, with_recomputes=True)
         per_sample = jnp.sum(counts[0], axis=-1)
         return (rfs, jnp.sum(per_sample),
-                jnp.sum((per_sample < MIN_SPIKES).astype(jnp.int32)))
+                jnp.sum((per_sample < MIN_SPIKES).astype(jnp.int32)),
+                jnp.sum(recomputes))
 
     return jax.jit(chunk)
 
 
 def train_wta_chunk(step, rfs, intensities, seeds):
     """One call of a :func:`wta_chunk_step`, in an ``snn.train`` span
-    with stats ``samples``, ``spikes`` and ``silent``; returns (rfs',
-    spikes, silent), the two counts read to the host."""
+    with stats ``samples``, ``spikes``, ``silent`` and ``recomputes``,
+    the three counters read to the host in one transfer; returns (rfs',
+    spikes, silent)."""
     from repro.serving import spans
 
     with spans.span("train", samples=int(intensities.shape[0])) as sp:
-        rfs, spikes, silent = step(rfs, intensities, seeds)
-        spikes, silent = int(spikes), int(silent)
+        rfs, *counters = step(rfs, intensities, seeds)
+        spikes, silent, recomputes = (int(c) for c in
+                                      jax.device_get(counters))
         if sp is not None:
-            sp.set_metadata(spikes=spikes, silent=silent)
+            sp.set_metadata(spikes=spikes, silent=silent,
+                            recomputes=recomputes)
     return rfs, spikes, silent
 
 

@@ -263,8 +263,8 @@ class SNNEngine:
                     windows: jnp.ndarray | None = None,
                     teach: jnp.ndarray | None = None, *, ltp_prob=None,
                     intensities: jnp.ndarray | None = None, seeds=None,
-                    n_steps: int | None = None
-                    ) -> tuple[SnnRegFile, jnp.ndarray, jnp.ndarray]:
+                    n_steps: int | None = None,
+                    with_recomputes: bool = False) -> tuple:
         """B independent streams, one launch: batched regfile (leading
         stream axis), windows uint32[B, T, w] OR intensities uint8
         [B, n_in] + ``n_steps`` (+ per-stream counter ``seeds`` i32[B]),
@@ -273,12 +273,19 @@ class SNNEngine:
         ``ltp_prob`` overrides the plan's shared value with a per-stream
         i32[B] vector (active-learning schedules per block).  Returns
         (rfs', spike_counts i32[B, n], fired bool[B, T, n]); stream b is
-        bit-exact with a :meth:`train` call on regfile b.
+        bit-exact with a :meth:`train` call on regfile b.  Under a plan
+        with lateral inhibition or adaptive thresholds,
+        ``with_recomputes`` adds a fourth result: the coupled kernel's
+        ``recomputes`` i32[B] (see
+        :func:`repro.kernels.ops.train_window_batch_encode`).
         """
         p = self.plan
         if not p.learn:
             raise ValueError("train_batch needs a learning plan "
                              "(w_exp is None)")
+        if with_recomputes and not p.wta:
+            raise ValueError("with_recomputes needs lateral inhibition "
+                             "or adaptive thresholds (w_inh, theta_plus)")
         lp = p.ltp_prob if ltp_prob is None else ltp_prob
         teach = _teach_arr(teach, rfs.v)
         mesh = p.placement()
@@ -318,6 +325,8 @@ class SNNEngine:
                     spike=_last_cycle_spikes(seeds, intensities, n_steps,
                                              rfs.weights.shape[2]))
                 counts = jnp.sum(fired.astype(jnp.int32), axis=1)
+                if with_recomputes:
+                    return rfs_out, counts, fired, out[5]
                 return rfs_out, counts, fired
             windows = encode_windows_host(seeds, intensities, n_steps,
                                     rfs.weights.shape[2])
@@ -408,8 +417,8 @@ def train_stream_batch(engine: SNNEngine, rfs: SnnRegFile,
                        teach: jnp.ndarray | None = None, *,
                        ltp_prob=None,
                        intensities: jnp.ndarray | None = None,
-                       seeds=None, n_steps: int | None = None
-                       ) -> tuple[SnnRegFile, jnp.ndarray]:
+                       seeds=None, n_steps: int | None = None,
+                       with_recomputes: bool = False) -> tuple:
     """B independent sample streams, one :meth:`SNNEngine.train_batch`
     launch per presented sample.
 
@@ -421,7 +430,8 @@ def train_stream_batch(engine: SNNEngine, rfs: SnnRegFile,
     schedule through every launch.  v resets before every sample; the
     weights, LFSR lanes and, under a plan with lateral inhibition or
     adaptive thresholds, θ carry over.  Returns (rfs', spike_counts
-    i32[B, N, n]).
+    i32[B, N, n]); ``with_recomputes`` (intensities under such a plan)
+    adds each launch's ``recomputes`` i32[B, N] as a third result.
     """
     _one_of(spike_trains, intensities, n_steps, "train_stream_batch")
     if engine.plan.wta:
@@ -438,14 +448,15 @@ def train_stream_batch(engine: SNNEngine, rfs: SnnRegFile,
         def body(carry: SnnRegFile, inp):
             x, s, tch = inp
             carry = carry._replace(v=jnp.zeros_like(carry.v))
-            rfs2, counts, _ = engine.train_batch(
+            out = engine.train_batch(
                 carry, teach=tch, ltp_prob=ltp_prob, intensities=x,
-                seeds=s, n_steps=n_steps)
-            return rfs2, counts
+                seeds=s, n_steps=n_steps, with_recomputes=with_recomputes)
+            return out[0], (out[1],) + out[3:]     # counts[, recomputes]
 
-        rfs_out, counts = jax.lax.scan(body, rfs,
-                                       (inten_t, seeds_t, teach_t))
-        return rfs_out, jnp.swapaxes(counts, 0, 1)
+        rfs_out, per_sample = jax.lax.scan(body, rfs,
+                                           (inten_t, seeds_t, teach_t))
+        return (rfs_out,) + tuple(jnp.swapaxes(y, 0, 1)
+                                  for y in per_sample)
 
     trains_t = jnp.swapaxes(spike_trains, 0, 1)
 

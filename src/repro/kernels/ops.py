@@ -29,8 +29,6 @@ from repro.kernels import snn_kernels as _k
 
 _LANES = 128
 _SAMPLE_BLOCK = 32   # the MXU serving kernel's sample block: one int8 tile
-_WTA_ROWS = 128      # neurons a population with θ is stepped by
-_RASTER_BYTES = 2 << 20   # its raster chunk in VMEM
 _NEVER = 1 << 30     # the threshold offset of a padded neuron
 
 
@@ -303,11 +301,19 @@ def train_window_batch_encode(weights, intensities, seeds, v, lfsr_state,
     ``theta`` i32[B, n] adds an adaptive threshold per neuron (fire at
     ``threshold + θ``; ``θ += theta_plus`` on each spike) and, with
     ``w_inh``, lateral inhibition within each stream's population
-    (:func:`repro.kernels.ref.train_window_wta_ref`); θ' is then a fifth
-    result.  ``w_inh`` and ``theta_plus`` need ``theta``.  With θ the
-    population is one neuron block stepped ``_WTA_ROWS`` rows at a time,
-    its window chunked so the raster block stays under ``_RASTER_BYTES``.
-    Returns (weights', v', fired bool[B, T, n], lfsr'[, theta']).
+    (:func:`repro.kernels.ref.train_window_wta_ref`); θ' and
+    ``recomputes`` i32[B] are then a fifth and a sixth result.
+    ``recomputes`` counts the (cycle, 128-neuron group) pairs with a
+    spike: each is one STDP update of the group's rows, after which the
+    kernel recomputes the group's input currents from the new weights.
+    ``w_inh`` and ``theta_plus`` need ``theta``.  With θ the population
+    is one neuron block (padded to 128s; a padded neuron's threshold is
+    out of reach), its currents computed on the MXU a block of cycles
+    at a time (:func:`repro.kernels.snn_kernels._train_window_enc_coupled`);
+    the kernel also takes the intensities one per lane, for its dense
+    spike tile.
+    Returns (weights', v', fired bool[B, T, n], lfsr'[, theta',
+    recomputes]).
     """
     if theta is None and (w_inh or theta_plus):
         raise ValueError("w_inh and theta_plus need the theta operand")
@@ -318,14 +324,8 @@ def train_window_batch_encode(weights, intensities, seeds, v, lfsr_state,
             w_inh=w_inh, theta_plus=theta_plus)
     b, n, w = weights.shape
     bn = max(_block_n(max(8, n)), 8)
-    rows = min(_WTA_ROWS, -(-n // 8) * 8)
     if theta is not None:
-        bn = -(-n // rows) * rows       # one block: the whole population
-        cap = _RASTER_BYTES // (4 * bn) // 8 * 8
-        if t_chunk is None and n_steps > cap:
-            # whole sublane tiles of cycles, the fewest padded cycles
-            t_chunk = min(range(8, max(cap, 8) + 1, 8),
-                          key=lambda c: (-(-n_steps // c) * c, -c))
+        bn = -(-n // _LANES) * _LANES   # one block: the whole population
     wp = _pad_to(_pad_to(weights, 2, _LANES), 1, bn)
     iw = _intensity_words(intensities, wp.shape[2])
     vp = _pad_to(v, 1, bn)
@@ -333,15 +333,17 @@ def train_window_batch_encode(weights, intensities, seeds, v, lfsr_state,
     sp = _pad_to(_pad_to(lfsr_state, 2, _LANES, fill=1), 1, bn, fill=1)
     # padded neurons never fire: their threshold is out of reach
     thp = None if theta is None else _pad_to(theta, 1, bn, fill=_NEVER)
+    x = (None if theta is None else
+         _pad_to(jnp.asarray(intensities, jnp.int32), 1, _LANES))
     out = _k.train_window_batch_encode(
         wp, iw, seeds, vp, sp, tp, n_steps=n_steps, threshold=threshold,
         leak=leak, w_exp=w_exp, gain=gain, n_syn=n_syn,
         ltp_prob=ltp_prob, block_n=bn, t_chunk=t_chunk, theta=thp,
-        w_inh=w_inh, theta_plus=theta_plus, rows=rows,
+        intensities=x, w_inh=w_inh, theta_plus=theta_plus,
         interpret=(backend == "interp"))
     w2, v2, f, s2 = out[:4]
     res = (w2[:, :n, :w], v2[:, :n], f[:, :n_steps, :n], s2[:, :n, :w])
-    return res if theta is None else res + (out[4][:, :n],)
+    return res if theta is None else res + (out[4][:, :n], out[5])
 
 
 @functools.partial(jax.jit, static_argnames=("n_steps", "threshold",

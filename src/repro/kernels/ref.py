@@ -171,8 +171,9 @@ def train_window_batch_encode_ref(weights, intensities, seeds, v,
                                   theta=None, w_inh: int = 0,
                                   theta_plus: int = 0):
     """Encode-fused batched training oracle; with ``theta`` i32[B, n],
-    each stream is one :func:`train_window_wta_ref` population and θ' is
-    a fifth result."""
+    each stream is one :func:`train_window_wta_ref` population, and θ'
+    and :func:`wta_recomputes_ref` of its raster are a fifth and a sixth
+    result."""
     wins = _host_windows(seeds, intensities, n_steps, weights.shape[2])
     if theta is None:
         return train_window_batch_ref(weights, wins, v, lfsr_state, teach,
@@ -186,7 +187,19 @@ def train_window_batch_encode_ref(weights, intensities, seeds, v,
                                     w_exp, gain, n_syn, lp_b, w_inh,
                                     theta_plus)
 
-    return jax.vmap(one)(weights, wins, v, lfsr_state, teach, theta, lp)
+    out = jax.vmap(one)(weights, wins, v, lfsr_state, teach, theta, lp)
+    return out + (wta_recomputes_ref(out[2]),)
+
+
+def wta_recomputes_ref(fired) -> jnp.ndarray:
+    """The (cycle, 128-neuron group) pairs with a spike in each stream's
+    raster bool[B, T, n]: i32[B], the coupled train kernel's count of
+    STDP updates, each followed by a recompute of the group's input
+    currents."""
+    b, t, n = fired.shape
+    f = jnp.pad(fired, ((0, 0), (0, 0), (0, (-n) % 128)))
+    return jnp.sum(jnp.any(f.reshape(b, t, -1, 128), axis=-1),
+                   axis=(1, 2), dtype=jnp.int32)
 
 
 def infer_window_batch_encode_ref(weights, intensities, seeds,

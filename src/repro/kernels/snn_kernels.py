@@ -58,8 +58,11 @@ VMEM budget (per grid step, BN=128, padded words W<=2048):
   train coupled: (lateral inhibition / adaptive thresholds) the whole
                  population is one block: at 6,400 neurons 3.3 MB per
                  state block, 26 MB for weights and LFSR in and out,
-                 double-buffered, plus a 32-cycle raster chunk (0.8 MB);
-                 the scoped VMEM limit is raised to 100 MiB.
+                 double-buffered, the resident int8 weights (5.7 MB at
+                 K=896), and per block of cycles the int32 currents,
+                 the int8 spike tile and the raster — 59 MB at the
+                 352-cycle block (``_coupled_vmem_bytes``); the scoped
+                 VMEM limit is raised to 100 MiB.
   infer encode:  the MXU kernel (BN=256, 32 samples a block): the int8
                  spike tile 32 * T_chunk * K (K = n_in rounded up to 128;
                  2.1 MiB at T_chunk=72, K=896, capped at 4 MiB by
@@ -675,16 +678,9 @@ def _window_infer_enc_kernel(threshold, leak, t_chunk, t_total,
 
 
 def _train_window_enc_kernel(threshold, leak, w_exp, gain, n_syn,
-                             t_chunk, t_total, coupling,
+                             t_chunk, t_total,
                              lp_ref, seed_ref, w_ref, iw_ref, v_ref,
-                             st_ref, t_ref, *refs):
-    if coupling is not None:    # (w_inh, theta_plus): the theta operand
-        _train_window_enc_coupled(
-            threshold, leak, w_exp, gain, n_syn, t_chunk, t_total,
-            *coupling, lp_ref, seed_ref, w_ref, iw_ref, v_ref, st_ref,
-            t_ref, *refs)
-        return
-    wo_ref, vo_ref, f_ref, sto_ref = refs
+                             st_ref, t_ref, wo_ref, vo_ref, f_ref, sto_ref):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -728,24 +724,56 @@ def _train_window_enc_kernel(threshold, leak, w_exp, gain, n_syn,
 
 def _train_window_enc_coupled(threshold, leak, w_exp, gain, n_syn,
                               t_chunk, t_total, w_inh, theta_plus,
-                              lp_ref, seed_ref, w_ref, iw_ref, v_ref,
+                              lp_ref, seed_ref, w_ref, iw_ref, x_ref, v_ref,
                               st_ref, t_ref, th_ref, wo_ref, vo_ref, f_ref,
-                              sto_ref, tho_ref, fp_ref):
+                              sto_ref, tho_ref, rc_ref, fp_ref, wu_ref,
+                              cur_ref, s_ref):
     """The train body of a population with an adaptive threshold per
     neuron and, where ``w_inh`` is set, lateral inhibition.
 
-    The block holds the whole population; its per-neuron rows (v, θ,
-    teach, the raster and the last cycle's spikes ``fp_ref``) are laid
-    out (groups, rows).  A cycle draws its spikes once, then steps one
-    group of ``rows`` neurons at a time against the state in the
-    (resident) output blocks: input current, inhibition ``w_inh * (F -
-    f_j)`` from the last cycle's spikes (F their sum, taken before any
-    group of this cycle is stepped), LIF against ``threshold + θ``, ``θ
-    += theta_plus`` on a spike, and STDP, which runs only for a group in
-    which a neuron fired (it commits nothing for the others, and their
-    LFSR lanes do not advance).
+    The block holds the whole population, in groups of 128 neurons; its
+    per-neuron rows (v, θ, teach, the raster and the last cycle's spikes
+    ``fp_ref``) are laid out (groups, 128), eight groups to a vreg.
+    Between two weight changes a neuron's input current ``popcount(pre
+    & w)`` does not depend on its membrane, so the currents of a block
+    of ``t_chunk`` cycles are computed on the MXU with the weights
+    frozen, and the LIF is then scanned over the block:
+
+    * at the launch's first block the packed weights are unpacked into
+      the resident int8 scratch ``wu_ref`` (group, input, neuron);
+    * each block draws its spikes as a dense 0/1 int8 tile ``s_ref``
+      (cycle, input) and computes, group by group, ``cur_ref`` (group x
+      cycle, neuron) = spikes @ weights, exact in int8 x int8 -> int32;
+    * each cycle steps the whole population from the cycle's row of
+      every group (one strided load): input current, inhibition ``w_inh
+      * (F - f_j)`` from the last cycle's spikes (F their sum), LIF
+      against ``threshold + θ``, ``θ += theta_plus`` on a spike;
+    * only in a cycle with a spike, for each group with a spike: STDP
+      on its packed rows and LFSR lanes (the others commit nothing and
+      their lanes do not advance) with the cycle's packed input row,
+      then the group's int8 columns are unpacked again and its currents
+      for the whole block recomputed from the new weights.  The rows up
+      to this cycle were already consumed, so every current used at
+      cycle t comes from the weights as they stand after cycle t - 1's
+      STDP, as in :func:`repro.kernels.ref.train_window_wta_ref`.
+
+    ``rc_ref`` counts the recomputes: (cycle, group) pairs with a spike.
     """
     k = pl.program_id(2)
+    n_groups, lanes = fp_ref.shape
+    kk = x_ref.shape[-1]
+    j = pl.program_id(1)
+    ltp_prob = lp_ref[j]
+    seed = seed_ref[j].astype(jnp.uint32)
+    base = k * t_chunk
+
+    def rows(g):
+        return pl.ds(pl.multiple_of(g * lanes, lanes), lanes)
+
+    def currents(g):
+        """The group's currents over the whole block from ``wu_ref``."""
+        cur_ref[pl.ds(pl.multiple_of(g * t_chunk, t_chunk), t_chunk), :] = \
+            jnp.dot(s_ref[...], wu_ref[g], preferred_element_type=jnp.int32)
 
     @pl.when(k == 0)
     def _init():
@@ -754,59 +782,117 @@ def _train_window_enc_coupled(threshold, leak, w_exp, gain, n_syn,
         sto_ref[...] = st_ref[...]
         tho_ref[...] = th_ref[...]
         fp_ref[...] = jnp.zeros_like(fp_ref)
+        rc_ref[...] = jnp.zeros_like(rc_ref)
 
-    j = pl.program_id(1)
-    ltp_prob = lp_ref[j]
-    seed = seed_ref[j].astype(jnp.uint32)
+        def unpack(g, c):
+            wu_ref[g] = _unpack_weights(w_ref[rows(g), :], kk)
+            return c
+
+        jax.lax.fori_loop(0, n_groups, unpack, 0)
+
+    # the block's spikes, one input per lane, `draw` cycles at a time
+    # (whole int8 tiles of 32 rows where the block allows)
+    inten = x_ref[...].astype(jnp.uint32)
+    draw = 32 if t_chunk % 32 == 0 else t_chunk
+
+    def spikes_at(r, c):
+        row = pl.multiple_of(r * draw, draw)
+        cyc = (base + row + jax.lax.broadcasted_iota(jnp.int32, (draw, 1), 0)
+               ).astype(jnp.uint32)
+        s_ref[pl.ds(row, draw), :] = _encode_tile(seed, cyc, inten)
+        return c
+
+    def block_currents(g, c):
+        currents(g)
+        return c
+
+    jax.lax.fori_loop(0, t_chunk // draw, spikes_at, 0)
+    jax.lax.fori_loop(0, n_groups, block_currents, 0)
+
     iw = iw_ref[...]
-    base = k * t_chunk
+    teach = t_ref[...]
     masked = t_total % t_chunk != 0
-    n_groups, rows = fp_ref.shape
 
-    def cycle(t, carry):
+    def learn(t):
         pre = _encode_cycle(seed, (base + t).astype(jnp.uint32), iw)
-        total = jnp.sum(fp_ref[...]) if w_inh else None
 
         def group(g, c):
-            r = pl.ds(pl.multiple_of(g * rows, rows), rows)
-            gr = pl.ds(g, 1)
-            w = wo_ref[r, :]
-            v = vo_ref[gr, :]
-            theta = tho_ref[gr, :]
-            u = (v + _popcount_rows(jnp.bitwise_and(pre, w))[None, :]
-                 + t_ref[gr, :])
-            if w_inh:
-                u = u - jnp.int32(w_inh) * (total - fp_ref[gr, :])
-            fired = u >= threshold + theta
-            v_next = jnp.where(
-                fired, jnp.int32(0), jnp.maximum(u - leak, jnp.int32(0)))
-            if masked:
-                active = base + t < t_total
-                fired = jnp.logical_and(fired, active)
-                v_next = jnp.where(active, v_next, v)
-            spikes = fired.astype(jnp.int32)
-            vo_ref[gr, :] = v_next
-            fp_ref[gr, :] = spikes
-            if theta_plus:
-                tho_ref[gr, :] = theta + jnp.int32(theta_plus) * spikes
-            f_ref[t, gr, :] = fired
+            fired = fp_ref[pl.ds(g, 1), :]
 
-            @pl.when(jnp.sum(spikes) > 0)
-            def _learn():
-                w2, st2 = _stdp_body(w, pre, spikes[0], sto_ref[r, :],
-                                     w_exp=w_exp, gain=gain, n_syn=n_syn,
-                                     ltp_prob=ltp_prob)
+            @pl.when(jnp.sum(fired) > 0)
+            def _stdp():
+                r = rows(g)
+                w2, st2 = _stdp_body(wo_ref[r, :], pre, fired[0],
+                                     sto_ref[r, :], w_exp=w_exp, gain=gain,
+                                     n_syn=n_syn, ltp_prob=ltp_prob)
                 wo_ref[r, :] = w2
                 sto_ref[r, :] = st2
+                wu_ref[g] = _unpack_weights(w2, kk)
+                currents(g)
+                rc_ref[...] += 1
 
             return c
 
-        return jax.lax.fori_loop(0, n_groups, group, carry)
+        jax.lax.fori_loop(0, n_groups, group, 0)
 
-    jax.lax.fori_loop(0, t_chunk, cycle, 0)
+    def cycle(t, carry):
+        v, theta, f, total = carry
+        u = v + cur_ref[pl.ds(t, n_groups, stride=t_chunk), :] + teach
+        if w_inh:
+            u = u - jnp.int32(w_inh) * (total - f)
+        fired = u >= threshold + theta
+        v_next = jnp.where(
+            fired, jnp.int32(0), jnp.maximum(u - leak, jnp.int32(0)))
+        if masked:
+            active = base + t < t_total
+            fired = jnp.logical_and(fired, active)
+            v_next = jnp.where(active, v_next, v)
+        spikes = fired.astype(jnp.int32)
+        f_ref[t] = fired
+        if theta_plus:
+            theta = theta + jnp.int32(theta_plus) * spikes
+        total = jnp.sum(spikes)
+
+        @pl.when(total > 0)
+        def _learn():
+            fp_ref[...] = spikes
+            learn(t)
+
+        return v_next, theta, spikes, total
+
+    f0 = fp_ref[...]
+    v, theta, f, _ = jax.lax.fori_loop(
+        0, t_chunk, cycle, (vo_ref[...], tho_ref[...], f0, jnp.sum(f0)))
+    vo_ref[...] = v
+    tho_ref[...] = theta
+    fp_ref[...] = f
 
 
+# VMEM of the coupled body (train_window_batch_encode with θ), bytes:
+# the packed weights and LFSR lanes in and out, double-buffered, the
+# resident int8 weights, the block's int32 currents and int8 spike tile,
+# the raster block (double-buffered; a bool takes 4 bytes in VMEM) and
+# one group's int32 matmul result.  At 784 x 6,400 (K 896, 128 words)
+# and 352 cycles: 26.2 + 5.7 + 9.0 + 0.3 + 18.0 + 0.2 MB = 59.4 MB.
 _THETA_VMEM_LIMIT = 100 << 20   # of the v5e core's 128 MiB of VMEM
+_THETA_VMEM_USE = _THETA_VMEM_LIMIT * 3 // 4   # room for the compiler's
+
+
+def _coupled_vmem_bytes(n: int, words: int, kk: int, t_chunk: int) -> int:
+    """The coupled body's VMEM at n neurons (a multiple of 128), ``words``
+    packed words, K = ``kk`` inputs and ``t_chunk``-cycle blocks."""
+    return (32 * n * words + kk * n + 4 * t_chunk * n + t_chunk * kk
+            + 8 * t_chunk * n + 4 * t_chunk * 128)
+
+
+def _coupled_chunk(n_steps: int, n: int, words: int, kk: int) -> int:
+    """The coupled body's default block: whole int8 tiles of 32 cycles,
+    within :data:`_THETA_VMEM_USE`, with the fewest padded cycles (then
+    the longest block, which loads each weight tile the fewest times)."""
+    top = -(-n_steps // 32) * 32
+    fits = [c for c in range(32, top + 1, 32)
+            if _coupled_vmem_bytes(n, words, kk, c) <= _THETA_VMEM_USE]
+    return min(fits or [32], key=lambda c: (-(-n_steps // c) * c, -c))
 
 
 def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
@@ -814,8 +900,8 @@ def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
                               leak: int, w_exp: int, gain: int,
                               n_syn: int, ltp_prob, block_n=128,
                               t_chunk: int | None = None, theta=None,
-                              w_inh: int = 0, theta_plus: int = 0,
-                              rows: int = 128, interpret=False):
+                              intensities=None, w_inh: int = 0,
+                              theta_plus: int = 0, interpret=False):
     """B training streams whose spike windows are generated in VMEM.
 
     Same grid/carry scheme as :func:`train_window_batch`, but the spike
@@ -827,17 +913,23 @@ def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
     absolute cycle).  Bit-exact with :func:`train_window_batch` fed the
     ``encoder.encode_from_counter`` host windows.
 
-    ``theta`` i32[B, n] (adaptive thresholds) selects the body of
-    :func:`_train_window_enc_coupled`: one neuron block holds each
-    stream's whole population (``block_n`` is not used), stepped
-    ``rows`` neurons at a time (``rows`` divides n), with ``w_inh``
-    and ``theta_plus`` as trace-time literals; θ' is a fifth result.
+    ``theta`` i32[B, n] (adaptive thresholds; n a multiple of 128)
+    selects the body of :func:`_train_window_enc_coupled`, with
+    ``w_inh`` and ``theta_plus`` as trace-time literals: one neuron
+    block holds each stream's whole population (``block_n`` is not
+    used), its currents computed on the MXU a block of ``t_chunk``
+    cycles at a time from a resident int8 copy of the weights, with
+    ``intensities`` i32[B, K] (input ``i`` in lane ``i``, zero past
+    n_in; K a multiple of 128) for the dense spike tile.  ``t_chunk``
+    defaults to :func:`_coupled_chunk`'s; on the chip it is a multiple
+    of 32 (the int8 tile's rows).  θ' and the recompute count i32[B]
+    are then a fifth and a sixth result.
 
-    Returns (weights', v', fired bool[B, T_pad, n], lfsr'[, theta']) with
-    T_pad = n_steps rounded up to the chunk (callers slice to n_steps).
+    Returns (weights', v', fired bool[B, T_pad, n], lfsr'[, theta',
+    recomputes]) with T_pad = n_steps rounded up to the chunk (callers
+    slice to n_steps).
     """
     b, n, w = weights.shape
-    tc, t_pad = _t_grid(n_steps, t_chunk)
     lp = jnp.asarray(ltp_prob, jnp.int32)
     if lp.ndim == 0:
         lp = jnp.broadcast_to(lp, (b,))
@@ -845,9 +937,10 @@ def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
     if theta is None:
         if w_inh or theta_plus:
             raise ValueError("w_inh and theta_plus need the theta operand")
+        tc, t_pad = _t_grid(n_steps, t_chunk)
         kern = functools.partial(_train_window_enc_kernel, int(threshold),
                                  int(leak), w_exp, gain, n_syn, tc,
-                                 int(n_steps), None)
+                                 int(n_steps))
         w2, v2, fired, s2 = pl.pallas_call(
             kern,
             out_shape=(jax.ShapeDtypeStruct((b, n, w), jnp.uint32),
@@ -875,37 +968,49 @@ def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
         )(lp, sd, weights, intens_words, v[:, None], lfsr_state,
           teach[:, None])
         return w2, v2[:, 0], fired, s2
-    if n % rows:
-        raise ValueError(f"rows={rows} must divide the population n={n}")
-    g = n // rows
-    kern = functools.partial(_train_window_enc_kernel, int(threshold),
+    lanes = 128
+    if n % lanes:
+        raise ValueError(f"the population n={n} must be a multiple of "
+                         f"{lanes}")
+    g = n // lanes
+    kk = intensities.shape[-1]
+    tc = _coupled_chunk(n_steps, n, w, kk) if t_chunk is None else t_chunk
+    t_pad = -(-n_steps // tc) * tc
+    kern = functools.partial(_train_window_enc_coupled, int(threshold),
                              int(leak), w_exp, gain, n_syn, tc,
-                             int(n_steps), (int(w_inh), int(theta_plus)))
+                             int(n_steps), int(w_inh), int(theta_plus))
     state = pl.BlockSpec((None, n, w), lambda i, j, k: (j, 0, 0))
-    row = pl.BlockSpec((None, g, rows), lambda i, j, k: (j, 0, 0))
-    w2, v2, fired, s2, th2 = pl.pallas_call(
+    row = pl.BlockSpec((None, g, lanes), lambda i, j, k: (j, 0, 0))
+    one = pl.BlockSpec((None, 1, kk), lambda i, j, k: (j, 0, 0))
+    count = pl.BlockSpec((None, 1, lanes), lambda i, j, k: (j, 0, 0))
+    w2, v2, fired, s2, th2, rc = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((b, n, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((b, g, rows), jnp.int32),
-                   jax.ShapeDtypeStruct((b, t_pad, g, rows), jnp.bool_),
+                   jax.ShapeDtypeStruct((b, g, lanes), jnp.int32),
+                   jax.ShapeDtypeStruct((b, t_pad, g, lanes), jnp.bool_),
                    jax.ShapeDtypeStruct((b, n, w), jnp.uint32),
-                   jax.ShapeDtypeStruct((b, g, rows), jnp.int32)),
+                   jax.ShapeDtypeStruct((b, g, lanes), jnp.int32),
+                   jax.ShapeDtypeStruct((b, 1, lanes), jnp.int32)),
         grid=(1, b, t_pad // tc),
-        in_specs=[_SMEM_WHOLE, _SMEM_WHOLE, state, _intens_block(w), row,
-                  state, row, row],
+        in_specs=[_SMEM_WHOLE, _SMEM_WHOLE, state, _intens_block(w), one,
+                  row, state, row, row],
         out_specs=(state, row,
-                   pl.BlockSpec((None, tc, g, rows),
+                   pl.BlockSpec((None, tc, g, lanes),
                                 lambda i, j, k: (j, k, 0, 0)),
-                   state, row),
-        scratch_shapes=[pltpu.VMEM((g, rows), jnp.int32)],
+                   state, row, count),
+        scratch_shapes=[pltpu.VMEM((g, lanes), jnp.int32),
+                        pltpu.VMEM((g, kk, lanes), jnp.int8),
+                        pltpu.VMEM((g * tc, lanes), jnp.int32),
+                        pltpu.VMEM((tc, kk), jnp.int8)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_THETA_VMEM_LIMIT),
         interpret=interpret,
         name="train_window_batch_encode",
-    )(lp, sd, weights, intens_words, v.reshape(b, g, rows), lfsr_state,
-      teach.reshape(b, g, rows), theta.reshape(b, g, rows))
+    )(lp, sd, weights, intens_words, intensities[:, None],
+      v.reshape(b, g, lanes), lfsr_state, teach.reshape(b, g, lanes),
+      theta.reshape(b, g, lanes))
     return (w2, v2.reshape(b, n), fired.reshape(b, t_pad, n), s2,
-            th2.reshape(b, n))
+            th2.reshape(b, n), rc[:, 0, 0])
 
 
 def fused_snn_window_encode(weights, intens_words, seed, v, lfsr_state,

@@ -34,17 +34,53 @@ def _state(b, n, n_in, seed):
 
 
 # (n, n_in, T, streams, samples, w_inh, theta_plus, t_chunk, threshold);
-# neither inhibition nor θ: the w22a-shape test below
+# neither inhibition nor θ: the w22a-shape test below.  The kernel's
+# t_chunk (None: its own, a multiple of 32) is the block of cycles whose
+# currents it computes with the weights frozen.
 CASES = {
     "inhibition-theta-ragged-chunk": (40, 100, 11, 2, 3, 3, 1, 4, 6),
     "inhibition-no-theta": (256, 784, 7, 1, 2, 4, 0, None, 16),
     "theta-only-off-the-block": (200, 100, 6, 3, 2, 0, 2, None, 6),
+    "same-group-consecutive-cycles": (128, 256, 24, 1, 2, 0, 0, None, 10),
+    "several-groups-one-cycle": (384, 200, 16, 1, 2, 1, 1, None, 8),
+    "spike-on-last-real-cycle-then-padding": (128, 256, 31, 1, 2, 0, 0,
+                                              None, 10),
+    "inputs-not-a-multiple-of-128": (128, 700, 12, 1, 2, 2, 1, None, 30),
+    "last-group-partly-padding": (300, 256, 20, 2, 2, 2, 1, None, 10),
+    "several-blocks-a-window": (256, 784, 70, 1, 2, 2, 1, 32, 40),
+}
+
+
+def _group_spikes(fired):
+    """bool[B, T, n] raster -> bool[B, T, groups of 128]: a spike in the
+    group at the cycle."""
+    b, t, n = fired.shape
+    f = np.pad(np.asarray(fired), ((0, 0), (0, 0), (0, (-n) % 128)))
+    return f.reshape(b, t, -1, 128).any(axis=-1)
+
+
+# what each case has to drive, on the reference's raster of some sample
+DRIVES = {
+    "same-group-consecutive-cycles":
+        lambda f, tc: (_group_spikes(f)[:, 1:]
+                       & _group_spikes(f)[:, :-1]).any(),
+    "several-groups-one-cycle":
+        lambda f, tc: (_group_spikes(f).sum(axis=-1) >= 2).any(),
+    "spike-on-last-real-cycle-then-padding":
+        lambda f, tc: f.shape[1] % 32 != 0 and f[:, -1].any(),
+    "last-group-partly-padding":
+        lambda f, tc: f[:, :, 256:].any(),
+    "several-blocks-a-window":
+        lambda f, tc: len({c // tc for c in np.nonzero(f.any(axis=(0, 2)))[0]
+                           }) >= 2,
 }
 
 
 @pytest.mark.parametrize("case", CASES.values(), ids=CASES.keys())
-def test_kernel_matches_reference_bit_for_bit(case):
+def test_kernel_matches_reference_bit_for_bit(case, request):
     n, n_in, t, b, samples, w_inh, tp, tc, thr = case
+    drive = DRIVES.get(request.node.callspec.id)
+    driven = drive is None
     weights, lf, rng = _state(b, n, n_in, 5 + n)
     theta = jnp.asarray(rng.integers(0, 4, (b, n), dtype=np.int32))
     teach = jnp.zeros((b, n), jnp.int32)
@@ -66,7 +102,29 @@ def test_kernel_matches_reference_bit_for_bit(case):
         for g, r in zip(outs["interp"], outs["ref"]):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
         fired_any += int(outs["ref"][2].sum())
+        driven = driven or bool(drive(np.asarray(outs["ref"][2]), tc))
     assert fired_any > 0
+    assert driven
+
+
+def test_recomputes_count_the_groups_with_a_spike_each_cycle():
+    """The kernel's ``recomputes`` is the number of (cycle, 128-neuron
+    group) pairs with a spike in the raster it returns: 300 neurons (the
+    last group partly padding), two blocks of 32 cycles."""
+    b, n, n_in, t = 2, 300, 200, 40
+    weights, lf, rng = _state(b, n, n_in, 17)
+    x = jnp.asarray(rng.integers(0, 64, (b, n_in), dtype=np.uint8))
+    seeds = jnp.asarray(rng.integers(0, 2**30, (b,), dtype=np.int32))
+    zeros = jnp.zeros((b, n), jnp.int32)
+    out = ops.train_window_batch_encode(
+        weights, x, seeds, zeros, lf, zeros, n_steps=t, threshold=10,
+        leak=1, w_exp=24, gain=4, n_syn=n_in, ltp_prob=300, theta=zeros,
+        w_inh=1, theta_plus=1, t_chunk=32, backend="interp")
+    fired = np.asarray(out[2])
+    want = [sum(fired[s, c, g:g + 128].any() for c in range(t)
+                for g in range(0, n, 128)) for s in range(b)]
+    assert min(want) > 0
+    np.testing.assert_array_equal(np.asarray(out[5]), want)
 
 
 def test_without_inhibition_or_theta_it_is_todays_kernel():
@@ -122,7 +180,7 @@ def test_theta_carries_across_samples_and_calls_and_v_resets():
     # each sample starts from v = 0: sample by sample through the op
     w, st, th = rfs0.weights, rfs0.lfsr, rfs0.theta
     for s in range(samples):
-        w, v, fired, st, th = ops.train_window_batch_encode(
+        w, v, fired, st, th, _ = ops.train_window_batch_encode(
             w, x[:, s], seeds[s], jnp.zeros((1, n), jnp.int32), st,
             jnp.zeros((1, n), jnp.int32), n_steps=t, threshold=10, leak=1,
             w_exp=24, gain=4, n_syn=100, ltp_prob=300, theta=th, w_inh=3,
@@ -198,12 +256,20 @@ def test_wta_chunk_step_counts_spikes_and_silent_samples():
     rfs = snn_regfile_batch(weights, [7])
     x = jnp.asarray(rng.integers(0, 64, (samples, n_in), np.uint8))
     seeds = jnp.arange(samples, dtype=jnp.int32)
-    got, spikes, silent = wta_chunk_step(cfg)(rfs, x, seeds)
-    want, counts = train_stream_batch(SNNEngine(cfg.plan()), rfs,
-                                      intensities=x[None], seeds=seeds,
-                                      n_steps=t)
+    got, spikes, silent, recomputes = wta_chunk_step(cfg)(rfs, x, seeds)
+    eng = SNNEngine(cfg.plan())
+    want, counts = train_stream_batch(eng, rfs, intensities=x[None],
+                                      seeds=seeds, n_steps=t)
     per_sample = np.asarray(counts[0]).sum(axis=-1)
     assert int(spikes) == per_sample.sum() > 0
     assert int(silent) == (per_sample < MIN_SPIKES).sum()
+    # 40 neurons are one group: a recompute in each cycle with a spike
+    cycles, state = 0, rfs
+    for s in range(samples):
+        state, _, fired = eng.train_batch(
+            state._replace(v=jnp.zeros_like(state.v)), intensities=x[None, s],
+            seeds=seeds[s], n_steps=t)
+        cycles += int(np.asarray(fired[0]).any(axis=-1).sum())
+    assert int(recomputes) == cycles > 0
     np.testing.assert_array_equal(np.asarray(got.theta),
                                   np.asarray(want.theta))
