@@ -17,11 +17,11 @@ import numpy as np
 
 from benchmarks.common import digits_dataset, emit
 from repro.configs.wenquxing_snn import WENQUXING_22A
-from repro.core import network
 from repro.core.bitpack import unpack
 from repro.core.encoder import poisson_encode_batch
 from repro.core.energy import EnergyConstants, count_events, energy
 from repro.core.trainer import train
+from repro.engine import SNNEngine
 
 PAPER_RATIO = 25.949 / 5.055  # 5.13x
 
@@ -34,7 +34,7 @@ def run() -> dict:
                               cfg.n_steps)
     # real spike statistics over the test presentations
     in_spikes = int(unpack(st.reshape(-1, st.shape[-1]), 784).sum())
-    counts = np.asarray(network.infer_batch(model.weights, st, cfg.lif()))
+    counts = np.asarray(SNNEngine(cfg.plan()).infer(model.weights, st))
     post = int(counts.sum())
     n_samples = st.shape[0]
     k = EnergyConstants()

@@ -51,6 +51,13 @@ def _operands(n, w, seed=0):
     return weights, pre, v, st, teach
 
 
+def _window(weights, spk, v, st, teach, **kw):
+    """One stream's window: the B=1 launch of ``ops.train_window_batch``."""
+    out = ops.train_window_batch(weights[None], spk[None], v[None],
+                                 st[None], teach[None], **kw)
+    return tuple(x[0] for x in out)
+
+
 def _mesh_bench(argv: list[str], marker: str) -> dict | None:
     """Run ``repro.distributed.snn_mesh --bench`` in a child process on
     a forced 8-device CPU mesh (the forced mesh would split this
@@ -138,8 +145,7 @@ def run() -> dict:
         spk = jnp.asarray(
             rng.integers(0, 2**32, (t_steps, w), dtype=np.uint32))
 
-        window = jax.jit(lambda *a: ops.fused_snn_window(
-            *a, n_syn=n_syn, **KW))
+        window = jax.jit(lambda *a: _window(*a, n_syn=n_syn, **KW))
 
         # the per-step path is T SEPARATE launches (one dispatch per
         # cycle, state round-tripping host-visible buffers between
@@ -187,8 +193,7 @@ def run() -> dict:
 
         batched = jax.jit(lambda *a: ops.train_window_batch(
             *a, n_syn=n_syn, **KW))
-        window = jax.jit(lambda *a: ops.fused_snn_window(
-            *a, n_syn=n_syn, **KW))
+        window = jax.jit(lambda *a: _window(*a, n_syn=n_syn, **KW))
 
         # the sequential baseline is B SEPARATE window launches — one
         # per training stream, exactly what the pre-batch trainer did
@@ -368,8 +373,8 @@ def run() -> dict:
     weights, _, v, st, teach = _operands(n, w, seed=3)
     spk = jnp.asarray(rng.integers(0, 2**32, (t_steps, w), dtype=np.uint32))
     t_i = time_fn(
-        lambda *a: ops.fused_snn_window(*a, n_syn=w * 32, backend="interp",
-                                        t_chunk=4, **KW),
+        lambda *a: _window(*a, n_syn=w * 32, backend="interp",
+                           t_chunk=4, **KW),
         weights, spk, v, st, teach, reps=3, warmup=1)
     emit(f"kernels/window-interp-{n}x{w * 32}xT{t_steps}c4", t_i,
          "backend=interp")
@@ -408,8 +413,7 @@ def run() -> dict:
         rng_l.integers(0, 2**32, (n, w), dtype=np.uint32))
     s_inten = rng_l.integers(0, 256, (n_req, w * 32), dtype=np.uint8)
     plan_l = SNNEnginePlan(threshold=192, leak=16, n_syn=w * 32,
-                           encode="kernel", cycle_backend="window",
-                           max_batch=8, t_chunk=8)
+                           encode="kernel", max_batch=8, t_chunk=8)
 
     def _latency_reqs(base):
         return [SNNRequest(rid=base + i, intensities=s_inten[i],

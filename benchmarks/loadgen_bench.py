@@ -64,8 +64,7 @@ def _engine(workload, clock, *, overload=None, injector=None):
 
     plan = SNNEnginePlan(threshold=192, leak=16,
                          n_syn=workload.n_inputs, encode="kernel",
-                         cycle_backend="window", max_batch=32,
-                         t_chunk=8)
+                         max_batch=32, t_chunk=8)
     weights = init_weights(64, workload.words, density_seed=0)
     del np  # weights helper owns the arrays
     policy = SNNServingPolicy(max_queue=4096, deadline_ms=200.0)

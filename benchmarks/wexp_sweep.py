@@ -16,10 +16,10 @@ import numpy as np
 
 from benchmarks.common import digits_dataset, emit
 from repro.configs.wenquxing_snn import WENQUXING_22A
-from repro.core import network
 from repro.core.bitpack import unpack
 from repro.core.encoder import poisson_encode_batch
 from repro.core.trainer import train
+from repro.engine import SNNEngine
 
 
 def run() -> dict:
@@ -31,8 +31,8 @@ def run() -> dict:
         cfg = dataclasses.replace(WENQUXING_22A, w_exp=wexp, n_neurons=40)
         t0 = time.time()
         model = train(cfg, tr, tr_lab)
-        counts = np.asarray(network.infer_batch(model.weights, st,
-                                                cfg.lif()))
+        counts = np.asarray(SNNEngine(cfg.plan()).infer(model.weights,
+                                                        st))
         pred = np.asarray(model.neuron_class)[counts.argmax(1)]
         acc = float((pred == te_lab).mean())
         # dead neuron = never the argmax winner on the test set

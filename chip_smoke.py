@@ -140,12 +140,9 @@ def _window_ops(o):
          (o["wts_b"], o["inten"], o["seeds"], o["v_b"], o["lfsr_b"],
           o["teach_b"]),
          dict(n_steps=T, ltp_prob=o["ltp_prob"], **train_kw)),
-        ("fused_snn_window[train]", ops.fused_snn_window,
-         (o["wts_b"][0], o["windows"][0], o["v_b"][0], o["lfsr_b"][0],
-          o["teach_b"][0]), dict(ltp_prob=16, train=True, **train_kw)),
-        ("fused_snn_window[infer]", ops.fused_snn_window,
-         (o["wts_b"][0], o["windows"][0], o["v_b"][0], o["lfsr_b"][0],
-          o["teach_b"][0]), dict(ltp_prob=16, train=False, **train_kw)),
+        ("train_window_batch[B=1]", ops.train_window_batch,
+         (o["wts_b"][:1], o["windows"][:1], o["v_b"][:1], o["lfsr_b"][:1],
+          o["teach_b"][:1]), dict(ltp_prob=16, **train_kw)),
     ]
 
 

@@ -6,13 +6,11 @@ STDP training with active learning -> test-set classification.
 
 Run:  PYTHONPATH=src python examples/mnist_stdp.py \
           [--neurons 40] [--wexp 128] [--train 2000] [--test 1000] \
-          [--cycle-backend window|step] [--kernel-backend ref|interp|tpu] \
-          [--train-mode active|parallel] [--window-chunk T_CHUNK] \
-          [--encode host|kernel] [--mesh-shape D,N]
+          [--kernel-backend ref|interp|tpu] [--train-mode active|parallel] \
+          [--window-chunk T_CHUNK] [--encode host|kernel] [--mesh-shape D,N]
 
 The backend/batching flags become one frozen ``SNNEnginePlan``
-(``--cycle-backend window`` is the time-resident window kernel,
-``--train-mode parallel`` the batched training grid, ``--window-chunk``
+(``--train-mode parallel`` the batched training grid, ``--window-chunk``
 the bounded-VMEM chunked spike streaming, ``--encode kernel`` the
 intensity-resident ingestion where the dataset stays uint8 and spikes
 are drawn in VMEM, ``--mesh-shape D,N`` the 2-D data × neuron
@@ -51,10 +49,6 @@ def main() -> None:
     ap.add_argument("--test", type=int, default=1000)
     ap.add_argument("--epochs", type=int, default=2)
     ap.add_argument("--seed", type=int, default=1)
-    ap.add_argument("--cycle-backend", default="window",
-                    choices=["window", "step"],
-                    help="window = time-resident fused kernel, "
-                         "step = per-cycle scan")
     ap.add_argument("--kernel-backend", default="ref",
                     choices=["ref", "interp", "tpu"],
                     help="window-kernel implementation (interp runs the "
@@ -88,7 +82,6 @@ def main() -> None:
 
     cfg = dataclasses.replace(WENQUXING_22A, n_neurons=args.neurons,
                               w_exp=args.wexp, epochs=args.epochs,
-                              cycle_backend=args.cycle_backend,
                               kernel_backend=args.kernel_backend,
                               train_mode=args.train_mode,
                               window_chunk=args.window_chunk,
@@ -96,7 +89,7 @@ def main() -> None:
                               mesh_shape=mesh_shape)
     print(f"training 784-{args.neurons} (w_exp={args.wexp}, "
           f"{args.epochs} epochs, {args.train} samples, "
-          f"{args.train_mode}/{args.cycle_backend}/"
+          f"{args.train_mode}/"
           f"{args.kernel_backend}/{args.encode}"
           + (f"/mesh{mesh_shape}" if mesh_shape else "") + ") ...")
     t0 = time.time()

@@ -5,8 +5,8 @@ Layers (bottom-up):
   lif / stdp              streamlined LIF (C2), binary stochastic STDP (C3)
   rvsnn                   RV-SNN V1.0 instruction semantics (C1)
   encoder / preprocess    Poisson rate coding, deskew + soft threshold
-  network / trainer       scan-based execution, supervised STDP + active
-                          learning (C4)
+  trainer                 supervised STDP + active learning (C4) on the
+                          ``repro.engine`` verbs
   energy                  event-driven energy/footprint model (Fig.4/Tab.2)
 """
 
@@ -16,7 +16,6 @@ from repro.core.encoder import (encode_from_counter,
                                 poisson_encode_batch, quantize_intensities,
                                 spike_rate)
 from repro.core.lif import LIFParams, lif_params, lif_reset, lif_step
-from repro.core.network import SNNOutput, infer_batch, run_sample, train_stream
 from repro.core.preprocess import deskew, preprocess, preprocess_batch, soft_threshold
 from repro.core.rvsnn import SnnRegFile, snn_ls, snn_nu, snn_regfile, snn_sp, snn_step, snn_su
 from repro.core.stdp import STDPParams, init_weights, ltd_prob_from_wexp, stdp_params, stdp_update

@@ -59,9 +59,8 @@ from repro.core.bitpack import n_words
 from repro.core.encoder import (poisson_encode_batch,
                                 quantize_intensities, sample_seeds,
                                 sample_seeds_at)
-from repro.core.lif import LIFParams, lif_params
 from repro.core.rvsnn import snn_regfile, snn_regfile_batch
-from repro.core.stdp import STDPParams, init_weights, stdp_params
+from repro.core.stdp import init_weights
 from repro.engine import SNNEngine, plan_from_config
 from repro.engine import engine as _engine
 
@@ -85,7 +84,6 @@ class SNNTrainConfig:
     theta_plus: int = 0          # threshold rise per spike (wta)
     epochs: int = 2
     seed: int = 0x22A
-    cycle_backend: str = "window"   # "window" (time-resident) | "step"
     kernel_backend: str | None = None  # "ref" | "interp" | "tpu"; None
                                        # = the platform's (see plan.py)
     train_mode: str = "active"      # "active" (sequential blocks on the
@@ -110,13 +108,6 @@ class SNNTrainConfig:
     @property
     def words(self) -> int:
         return n_words(self.n_inputs)
-
-    def lif(self) -> LIFParams:
-        return lif_params(self.threshold, self.leak)
-
-    def stdp(self, block_idx: int = 0) -> STDPParams:
-        lp = self.ltp_prob if block_idx == 0 else self.ltp_prob_active
-        return stdp_params(self.n_inputs, self.w_exp, self.gain, lp)
 
     def plan(self, block_idx: int = 0, mesh=None):
         """The engine execution plan this config describes."""
@@ -195,7 +186,7 @@ def _train_blocks_parallel(cfg: SNNTrainConfig, key: jax.Array,
     LFSR seeds AND their LTP schedule — ``ltp_prob`` is a per-stream
     SMEM scalar operand, so block 0 trains at the base ``ltp_prob`` and
     blocks >= 1 at ``ltp_prob_active``, matching active mode's
-    ``cfg.stdp(block_idx)`` schedule.  With ``cfg.mesh_shape`` the
+    ``cfg.plan(block_idx)`` schedule.  With ``cfg.mesh_shape`` the
     launch shards block streams over the "data" axis and neuron rows
     over "neurons" — the 2-D data-parallel training sweep.  The sample
     stream is pre-encoded windows OR uint8 intensities + their dataset

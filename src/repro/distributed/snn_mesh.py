@@ -162,43 +162,6 @@ def sharded_infer_window_batch(weights, spike_trains, *, threshold: int,
     return fn(wp, tp)[:b, :n]
 
 
-def sharded_fused_snn_window(weights, spike_train, v, lfsr_state, teach, *,
-                             threshold: int, leak: int, w_exp: int,
-                             gain: int, n_syn: int, ltp_prob: int = 1023,
-                             train: bool = True,
-                             t_chunk: int | None = None,
-                             backend: str = "ref",
-                             mesh: Mesh | None = None):
-    """:func:`ops.fused_snn_window` over an SNN mesh.
-
-    weights/lfsr u32[n, w], v/teach i32[n] shard on n; the spike window
-    replicates (incl. over the "data" axis of a 2-D mesh — one sample
-    has no batch axis to split); the fired raster bool[T, n] comes back
-    n-sharded.  Each shard's LFSR lanes travel with its rows, so
-    training stays bit-exact with the single-device op (incl. the LFSR
-    sequence).  Returns (weights', v', fired bool[T, n], lfsr').
-    """
-    mesh = snn_mesh() if mesh is None else mesh
-    _, nd = _dims(mesh)
-    n = weights.shape[0]
-    wp = _pad_rows(weights, nd)
-    vp = _pad_rows(v, nd)
-    tp = _pad_rows(teach, nd)
-    sp = _pad_rows(lfsr_state, nd, fill=1)
-    row, vec, rep2, ras = _specs(
-        mesh, ("neurons", "syn_words"), ("neurons",),
-        (None, "syn_words"), (None, "neurons"))
-    fn = jax.shard_map(
-        functools.partial(ops.fused_snn_window, threshold=threshold,
-                          leak=leak, w_exp=w_exp, gain=gain, n_syn=n_syn,
-                          ltp_prob=ltp_prob, train=train, t_chunk=t_chunk,
-                          backend=backend),
-        mesh=mesh, in_specs=(row, rep2, vec, row, vec),
-        out_specs=(row, vec, ras, row), check_vma=False)
-    w2, v2, fired, s2 = fn(wp, spike_train, vp, sp, tp)
-    return w2[:n], v2[:n], fired[:, :n], s2[:n]
-
-
 def _refuse_coupling(w_inh: int, theta_plus: int, theta) -> None:
     """The sharded train ops split a population's neurons over devices,
     which a population coupled within a cycle cannot be."""
@@ -300,48 +263,6 @@ def sharded_infer_window_batch_encode(weights, intensities, seeds, *,
     return fn(wp, xp, sd, tt)[:b, :n]
 
 
-def sharded_fused_snn_window_encode(weights, intensities, seed, v,
-                                    lfsr_state, teach, *, n_steps: int,
-                                    threshold: int, leak: int, w_exp: int,
-                                    gain: int, n_syn: int,
-                                    ltp_prob: int = 1023,
-                                    train: bool = True,
-                                    t_chunk: int | None = None,
-                                    backend: str = "ref",
-                                    mesh: Mesh | None = None):
-    """:func:`ops.fused_snn_window_encode` over an SNN mesh.
-
-    State shards on n as in :func:`sharded_fused_snn_window`; the uint8
-    intensities replicate (n_in bytes instead of a T*w*4-byte window,
-    incl. over the "data" axis — one sample has no batch axis) and the
-    scalar counter seed closes over the call.  Bit-exact with the
-    single-device op, incl. each shard's LFSR sequence.
-    """
-    mesh = snn_mesh() if mesh is None else mesh
-    _, nd = _dims(mesh)
-    n = weights.shape[0]
-    wp = _pad_rows(weights, nd)
-    vp = _pad_rows(v, nd)
-    tp = _pad_rows(teach, nd)
-    sp = _pad_rows(lfsr_state, nd, fill=1)
-    row, vec, rep1, ras = _specs(
-        mesh, ("neurons", "syn_words"), ("neurons",), (None,),
-        (None, "neurons"))
-
-    def call(w, x, vv, st, tc):
-        return ops.fused_snn_window_encode(
-            w, x, seed, vv, st, tc, n_steps=n_steps, threshold=threshold,
-            leak=leak, w_exp=w_exp, gain=gain, n_syn=n_syn,
-            ltp_prob=ltp_prob, train=train, t_chunk=t_chunk,
-            backend=backend)
-
-    fn = jax.shard_map(call, mesh=mesh,
-                       in_specs=(row, rep1, vec, row, vec),
-                       out_specs=(row, vec, ras, row), check_vma=False)
-    w2, v2, fired, s2 = fn(wp, intensities, vp, sp, tp)
-    return w2[:n], v2[:n], fired[:, :n], s2[:n]
-
-
 def sharded_train_window_batch_encode(weights, intensities, seeds, v,
                                       lfsr_state, teach, *, n_steps: int,
                                       threshold: int, leak: int,
@@ -425,10 +346,7 @@ def _check(args) -> int:
     weights = jnp.asarray(rng.integers(0, 2**32, (n, w), dtype=np.uint32))
     trains = jnp.asarray(
         rng.integers(0, 2**32, (b, t, w), dtype=np.uint32))
-    v = jnp.zeros((n,), jnp.int32)
-    teach = jnp.asarray(rng.integers(-50, 50, (n,), dtype=np.int32))
     from repro.core import lfsr
-    st = lfsr.seed(7, n * w).reshape(n, w)
     kw = dict(threshold=60, leak=4, w_exp=64, gain=4, n_syn=w * 32,
               ltp_prob=200)
     inten = jnp.asarray(rng.integers(0, 256, (b, w * 32), dtype=np.uint8))
@@ -453,19 +371,6 @@ def _check(args) -> int:
         print(f"infer_window_batch: sharded({label}) == single-device "
               f"[B={b}, n={n}]")
 
-        for train in (True, False):
-            got = sharded_fused_snn_window(
-                weights, trains[0], v, st, teach, train=train,
-                backend=args.backend, mesh=mesh, **kw)
-            want = ops.fused_snn_window(weights, trains[0], v, st, teach,
-                                        train=train,
-                                        backend=args.backend, **kw)
-            for g, r in zip(got, want):
-                np.testing.assert_array_equal(np.asarray(g),
-                                              np.asarray(r))
-            print(f"fused_snn_window(train={train}): sharded({label}) "
-                  f"== single-device [n={n}, T={t}]")
-
         got = sharded_train_window_batch(
             wts_b, trains, vb, stb, tb, backend=args.backend, mesh=mesh,
             **kw)
@@ -487,19 +392,6 @@ def _check(args) -> int:
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         print(f"infer_window_batch_encode: sharded({label}) == "
               f"single-device [B={b}, ragged T]")
-
-        for train in (True, False):
-            got = sharded_fused_snn_window_encode(
-                weights, inten[0], 7, v, st, teach, n_steps=t,
-                train=train, backend=args.backend, mesh=mesh, **kw)
-            want = ops.fused_snn_window_encode(
-                weights, inten[0], 7, v, st, teach, n_steps=t,
-                train=train, backend=args.backend, **kw)
-            for g, r in zip(got, want):
-                np.testing.assert_array_equal(np.asarray(g),
-                                              np.asarray(r))
-            print(f"fused_snn_window_encode(train={train}): "
-                  f"sharded({label}) == single-device")
 
         got = sharded_train_window_batch_encode(
             wts_b, inten, seeds, vb, stb, tb, n_steps=t,

@@ -4,9 +4,8 @@ One :class:`SNNEnginePlan` (frozen) + one :class:`SNNEngine` (three
 verbs) replace the ~10 scattered SNN entrypoints that each re-accepted
 ``threshold``/``leak``/``ltp_prob``/``backend``/``t_chunk``/``mesh``
 kwargs.  The engine owns kernel-path dispatch (``ref``/``interp``/
-``tpu`` × ``step``/``window``) and neuron-mesh placement; consumers
-(``repro.core.network``, ``repro.core.trainer``,
-``repro.serving.snn``) are thin shims over it.
+``tpu``) and neuron-mesh placement; consumers (``repro.core.trainer``,
+``repro.serving.snn``) drive its verbs.
 
 Migration table (old call -> plan verb)
 ---------------------------------------
@@ -14,27 +13,20 @@ Migration table (old call -> plan verb)
 ===========================================================  ==========================================================
 old call                                                     engine equivalent
 ===========================================================  ==========================================================
-``network.run_sample(rf, win, lif, stdp, teach, **kw)``      ``SNNEngine(plan).train(rf, win, teach)``
-``network.run_sample(rf, win, lif, None, **kw)``             ``SNNEngine(replace(plan, w_exp=None)).train(rf, win)``
-``network.infer_batch(w, wins, lif, **kw)``                  ``SNNEngine(plan).infer(w, wins)``
-``network.train_stream(rf, wins, teach, lif, stdp, **kw)``   ``engine.train_stream(SNNEngine(plan), rf, wins, teach)``
-``network.train_stream_batch(rfs, wins, teach, ...)``        ``engine.train_stream_batch(SNNEngine(plan), rfs, ...)``
 ``snn_mesh.sharded_infer_window_batch(..., mesh=m)``         ``SNNEngine(replace(plan, mesh=m)).infer(w, wins)``
-``snn_mesh.sharded_fused_snn_window(..., mesh=m)``           ``SNNEngine(replace(plan, mesh=m)).train(rf, win)``
 ``snn_mesh.sharded_train_window_batch(..., mesh=m2d)``       ``SNNEngine(replace(plan, mesh_shape=(d, n))).train_batch``
-``trainer kwargs (cycle_backend/kernel_backend/...)``        ``SNNEnginePlan`` fields / ``plan_from_config(cfg)``
+``trainer kwargs (kernel_backend/window_chunk/...)``         ``SNNEnginePlan`` fields / ``plan_from_config(cfg)``
 ===========================================================  ==========================================================
 
 where ``plan = SNNEnginePlan(threshold=..., leak=..., w_exp=...,
-gain=..., n_syn=..., ltp_prob=..., cycle_backend=...,
-kernel_backend=..., t_chunk=...)`` is built once (or via
-:func:`plan_from_config` from an ``SNNTrainConfig``), and ``replace`` is
-``dataclasses.replace``.  Placement is an explicit ``mesh`` or the
-declarative ``mesh_shape=(data, neurons)`` — the 2-D grid shards batch
-axes over "data" and regfiles over "neurons"; the verbs dispatch 1-D
-vs 2-D automatically and every factorization is bit-exact with the
-unsharded path.  The legacy entrypoints remain as deprecation wrappers
-with byte-identical outputs.
+gain=..., n_syn=..., ltp_prob=..., kernel_backend=..., t_chunk=...)``
+is built once (or via :func:`plan_from_config` from an
+``SNNTrainConfig``), and ``replace`` is ``dataclasses.replace``.
+Placement is an explicit ``mesh`` or the declarative
+``mesh_shape=(data, neurons)`` — the 2-D grid shards batch axes over
+"data" and regfiles over "neurons"; the verbs dispatch 1-D vs 2-D
+automatically and every factorization is bit-exact with the unsharded
+path.
 """
 
 from repro.engine.engine import (SNNEngine, SNNOutput, refresh_weights,

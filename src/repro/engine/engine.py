@@ -6,24 +6,26 @@ placement decisions for the SNN stack.  Callers build one
 
 ``infer(weights, windows)``
     Spike counts i32[B, n] for B presentation windows, weights frozen,
-    membrane reset per sample — the serving path.  One
-    ``infer_window_batch`` launch (sharded over the plan's neuron mesh
-    when present), or a vmap of per-cycle scans on the step path.
-
-``train(rf, window, teach)``
-    Present one window to one register file with online STDP (SU idle
-    for inference-only plans).  One ``fused_snn_window`` launch, or a
-    per-cycle ``snn_step`` scan on the step path.
+    membrane reset per sample — the serving path.  Intensities under
+    ``encode="kernel"`` take the MXU encode kernel
+    (``infer_window_batch_encode``); everything else, the packed kernel
+    (``infer_window_batch``) on host-encoded windows.  Either is sharded
+    over the plan's mesh when it has one.
 
 ``train_batch(rfs, windows, teach)``
     B independent training streams in ONE launch (the batched training
-    grid), with optional per-stream ``ltp_prob`` — the SMEM scalar
-    operand keeps each stream's active-learning schedule.
+    grid: ``train_window_batch``, or ``train_window_batch_encode`` for
+    intensities under ``encode="kernel"``), with optional per-stream
+    ``ltp_prob`` — the SMEM scalar operand keeps each stream's
+    active-learning schedule.
 
-The module-level :func:`train_stream` / :func:`train_stream_batch`
-helpers compose the verbs over a sample stream (reset between samples,
-scan over the sample axis) — they are what ``repro.core.network`` and
-``repro.core.trainer`` now shim to.
+``train(rf, window, teach)``
+    One window to one register file: ``train_batch`` at B=1.
+
+The module-level :func:`train_stream_batch` presents a stream of
+samples to B streams, one ``train_batch`` launch a sample (v reset
+between samples, a scan over the sample axis); :func:`train_stream` is
+its one-stream case.  ``repro.core.trainer`` drives both.
 """
 
 from __future__ import annotations
@@ -34,9 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.encoder import encode_from_counter, encode_windows_host
-from repro.core.rvsnn import (SnnRegFile, snn_regfile, snn_regfile_batch,
-                              snn_step)
-from repro.core.stdp import STDPParams
+from repro.core.rvsnn import SnnRegFile, snn_regfile_batch
 from repro.engine.plan import SNNEnginePlan
 from repro.kernels import ops
 
@@ -65,27 +65,26 @@ def _with_theta(rf: SnnRegFile) -> SnnRegFile:
     return rf._replace(theta=jnp.zeros_like(rf.v))
 
 
-def _teach_arr(teach, v) -> jnp.ndarray:
-    return (jnp.zeros_like(v) if teach is None
-            else teach.astype(jnp.int32))
-
-
 def _last_cycle_spikes(seeds, intensities, n_steps: int, words: int
                        ) -> jnp.ndarray:
     """Packed words of the window's final cycle (the spike register
     after a presentation), regenerated in isolation from the counter."""
     sd = jnp.asarray(seeds, jnp.uint32)
-    if intensities.ndim == 1:
-        rows = encode_from_counter(sd, intensities, 1, t0=n_steps - 1)[0]
-    else:
-        rows = jax.vmap(
-            lambda s, x: encode_from_counter(s, x, 1, t0=n_steps - 1)[0]
-        )(jnp.broadcast_to(sd, intensities.shape[:1]), intensities)
-    pad = words - rows.shape[-1]
-    if pad:
-        widths = [(0, 0)] * (rows.ndim - 1) + [(0, pad)]
-        rows = jnp.pad(rows, widths)
-    return rows
+    rows = jax.vmap(
+        lambda s, x: encode_from_counter(s, x, 1, t0=n_steps - 1)[0]
+    )(jnp.broadcast_to(sd, intensities.shape[:1]), intensities)
+    return jnp.pad(rows, ((0, 0), (0, words - rows.shape[-1])))
+
+
+def _one(tree):
+    """``tree`` with a leading stream axis of 1 on every leaf (None
+    stays None)."""
+    return jax.tree.map(lambda x: x[None], tree)
+
+
+def _only(tree):
+    """The one stream of ``tree``: every leaf's leading axis dropped."""
+    return jax.tree.map(lambda x: x[0], tree)
 
 
 def _one_of(windows, intensities, n_steps, what: str) -> None:
@@ -148,29 +147,14 @@ class SNNEngine:
                     t_chunk=p.t_chunk, backend=p.kernel_backend)
             windows = encode_windows_host(seeds, intensities, n_steps,
                                     weights.shape[1], t_total)
-        if p.cycle_backend == "window":
-            if mesh is not None:
-                from repro.distributed import snn_mesh
-                return snn_mesh.sharded_infer_window_batch(
-                    weights, windows, threshold=p.threshold, leak=p.leak,
-                    t_chunk=p.t_chunk, backend=p.kernel_backend,
-                    mesh=mesh)
-            return ops.infer_window_batch(
+        if mesh is not None:
+            from repro.distributed import snn_mesh
+            return snn_mesh.sharded_infer_window_batch(
                 weights, windows, threshold=p.threshold, leak=p.leak,
-                t_chunk=p.t_chunk, backend=p.kernel_backend)
-
-        lif = p.lif()
-        rf0 = snn_regfile(weights)
-
-        def one(window):
-            def body(carry, words):
-                carry, fired = snn_step(carry, words, lif, None)
-                return carry, fired
-
-            _, fired = jax.lax.scan(body, rf0, window)
-            return jnp.sum(fired.astype(jnp.int32), axis=0)
-
-        return jax.vmap(one)(windows)
+                t_chunk=p.t_chunk, backend=p.kernel_backend, mesh=mesh)
+        return ops.infer_window_batch(
+            weights, windows, threshold=p.threshold, leak=p.leak,
+            t_chunk=p.t_chunk, backend=p.kernel_backend)
 
     # --- train -----------------------------------------------------------
 
@@ -178,84 +162,17 @@ class SNNEngine:
               teach: jnp.ndarray | None = None, *,
               intensities: jnp.ndarray | None = None, seed=None,
               n_steps: int | None = None) -> SNNOutput:
-        """Present one window to one regfile.
+        """Present one window to one regfile: the one-stream case of
+        :meth:`train_batch`.
 
         Pass EITHER a packed uint32[T, w] ``window`` OR uint8
         ``intensities`` [n_in] with ``n_steps`` (+ optional counter
-        ``seed``; default: the plan's).  Online STDP when the plan
-        learns (``w_exp`` set); SU idle otherwise.  Returns
-        :class:`SNNOutput`.
+        ``seed``; default: the plan's).  Needs a learning plan, as
+        :meth:`train_batch` does.  Returns :class:`SNNOutput`.
         """
-        p = self.plan
-        mesh = p.placement()
-        if p.wta:
-            # one stream of the batched verb, which steps θ
-            _one_of(window, intensities, n_steps, "train")
-            seed = p.encode_seed if seed is None else seed
-            rfs, counts, fired = self.train_batch(
-                jax.tree.map(lambda x: x[None], _with_theta(rf)),
-                teach=None if teach is None else teach[None],
-                intensities=intensities[None], seeds=seed,
-                n_steps=n_steps)
-            return SNNOutput(jax.tree.map(lambda x: x[0], rfs), counts[0],
-                             fired[0])
-        if intensities is not None or window is None:
-            _one_of(window, intensities, n_steps, "train")
-            seed = p.encode_seed if seed is None else seed
-            if p.encode == "kernel":
-                teach_arr = _teach_arr(teach, rf.v)
-                kwargs = p.window_kwargs()
-                if mesh is not None:
-                    from repro.distributed import snn_mesh
-                    w2, v2, fired, lf2 = \
-                        snn_mesh.sharded_fused_snn_window_encode(
-                            rf.weights, intensities, seed, rf.v, rf.lfsr,
-                            teach_arr, n_steps=n_steps,
-                            t_chunk=p.t_chunk,
-                            backend=p.kernel_backend, mesh=mesh,
-                            **kwargs)
-                else:
-                    w2, v2, fired, lf2 = ops.fused_snn_window_encode(
-                        rf.weights, intensities, seed, rf.v, rf.lfsr,
-                        teach_arr, n_steps=n_steps, t_chunk=p.t_chunk,
-                        backend=p.kernel_backend, **kwargs)
-                rf_out = rf._replace(
-                    weights=w2, v=v2, lfsr=lf2,
-                    spike=_last_cycle_spikes(seed, intensities, n_steps,
-                                             rf.weights.shape[1]))
-                counts = jnp.sum(fired.astype(jnp.int32), axis=0)
-                return SNNOutput(rf_out, counts, fired)
-            window = encode_windows_host(seed, intensities[None], n_steps,
-                                   rf.weights.shape[1])[0]
-        if p.cycle_backend == "window":
-            teach_arr = _teach_arr(teach, rf.v)
-            kwargs = p.window_kwargs()
-            if mesh is not None:
-                from repro.distributed import snn_mesh
-                w2, v2, fired, lf2 = snn_mesh.sharded_fused_snn_window(
-                    rf.weights, window, rf.v, rf.lfsr, teach_arr,
-                    t_chunk=p.t_chunk, backend=p.kernel_backend,
-                    mesh=mesh, **kwargs)
-            else:
-                w2, v2, fired, lf2 = ops.fused_snn_window(
-                    rf.weights, window, rf.v, rf.lfsr, teach_arr,
-                    t_chunk=p.t_chunk, backend=p.kernel_backend,
-                    **kwargs)
-            rf_out = rf._replace(
-                weights=w2, v=v2, lfsr=lf2,
-                spike=window[-1].astype(jnp.uint32))
-            counts = jnp.sum(fired.astype(jnp.int32), axis=0)
-            return SNNOutput(rf_out, counts, fired)
-
-        lif, stdp = p.lif(), p.stdp()
-
-        def body(carry: SnnRegFile, words: jnp.ndarray):
-            carry, fired = snn_step(carry, words, lif, stdp, teach)
-            return carry, fired
-
-        rf_out, fired = jax.lax.scan(body, rf, window)
-        counts = jnp.sum(fired.astype(jnp.int32), axis=0)
-        return SNNOutput(rf_out, counts, fired)
+        return SNNOutput(*_only(self.train_batch(
+            _one(rf), _one(window), _one(teach),
+            intensities=_one(intensities), seeds=seed, n_steps=n_steps)))
 
     # --- train_batch -----------------------------------------------------
 
@@ -273,7 +190,7 @@ class SNNEngine:
         ``ltp_prob`` overrides the plan's shared value with a per-stream
         i32[B] vector (active-learning schedules per block).  Returns
         (rfs', spike_counts i32[B, n], fired bool[B, T, n]); stream b is
-        bit-exact with a :meth:`train` call on regfile b.  Under a plan
+        bit-exact with a launch of regfile b alone.  Under a plan
         with lateral inhibition or adaptive thresholds,
         ``with_recomputes`` adds a fourth result: the coupled kernel's
         ``recomputes`` i32[B] (see
@@ -287,7 +204,8 @@ class SNNEngine:
             raise ValueError("with_recomputes needs lateral inhibition "
                              "or adaptive thresholds (w_inh, theta_plus)")
         lp = p.ltp_prob if ltp_prob is None else ltp_prob
-        teach = _teach_arr(teach, rfs.v)
+        teach = (jnp.zeros_like(rfs.v) if teach is None
+                 else teach.astype(jnp.int32))
         mesh = p.placement()
         if p.wta and intensities is None:
             raise ValueError("train_batch: lateral inhibition and "
@@ -296,8 +214,7 @@ class SNNEngine:
             _one_of(windows, intensities, n_steps, "train_batch")
             seeds = self._seeds(seeds, intensities.shape[0])
             if p.encode == "kernel":
-                kwargs = {k: v for k, v in p.window_kwargs().items()
-                          if k not in ("train", "ltp_prob")}
+                kwargs = p.window_kwargs()
                 theta = rfs.theta
                 if p.wta:
                     theta = _with_theta(rfs).theta
@@ -307,14 +224,13 @@ class SNNEngine:
                     from repro.distributed import snn_mesh
                     out = snn_mesh.sharded_train_window_batch_encode(
                         rfs.weights, intensities, seeds, rfs.v,
-                        rfs.lfsr, teach.astype(jnp.int32),
-                        ltp_prob=lp, n_steps=n_steps,
+                        rfs.lfsr, teach, ltp_prob=lp, n_steps=n_steps,
                         t_chunk=p.t_chunk, backend=p.kernel_backend,
                         mesh=mesh, **kwargs)
                 else:
                     out = ops.train_window_batch_encode(
                         rfs.weights, intensities, seeds, rfs.v,
-                        rfs.lfsr, teach.astype(jnp.int32), ltp_prob=lp,
+                        rfs.lfsr, teach, ltp_prob=lp,
                         n_steps=n_steps, t_chunk=p.t_chunk,
                         backend=p.kernel_backend, **kwargs)
                 w2, v2, fired, lf2 = out[:4]
@@ -330,43 +246,20 @@ class SNNEngine:
                 return rfs_out, counts, fired
             windows = encode_windows_host(seeds, intensities, n_steps,
                                     rfs.weights.shape[2])
-        if p.cycle_backend == "window":
-            kwargs = {k: v for k, v in p.window_kwargs().items()
-                      if k not in ("train", "ltp_prob")}
-            if mesh is not None:
-                from repro.distributed import snn_mesh
-                w2, v2, fired, lf2 = snn_mesh.sharded_train_window_batch(
-                    rfs.weights, windows, rfs.v, rfs.lfsr,
-                    teach.astype(jnp.int32), ltp_prob=lp,
-                    t_chunk=p.t_chunk, backend=p.kernel_backend,
-                    mesh=mesh, **kwargs)
-            else:
-                w2, v2, fired, lf2 = ops.train_window_batch(
-                    rfs.weights, windows, rfs.v, rfs.lfsr,
-                    teach.astype(jnp.int32), ltp_prob=lp,
-                    t_chunk=p.t_chunk, backend=p.kernel_backend,
-                    **kwargs)
-            rfs_out = rfs._replace(
-                weights=w2, v=v2, lfsr=lf2,
-                spike=windows[:, -1].astype(jnp.uint32))
-            counts = jnp.sum(fired.astype(jnp.int32), axis=1)
-            return rfs_out, counts, fired
-
-        b = rfs.v.shape[0]
-        lif = p.lif()
-        lp_arr = jnp.broadcast_to(jnp.asarray(lp, jnp.int32), (b,))
-
-        def one(rf_b, window_b, teach_b, lp_b):
-            stdp = STDPParams(jnp.int32(p.w_exp), jnp.int32(p.gain),
-                              jnp.int32(p.n_syn), jnp.uint32(lp_b))
-
-            def body(carry, words):
-                carry, fired = snn_step(carry, words, lif, stdp, teach_b)
-                return carry, fired
-
-            return jax.lax.scan(body, rf_b, window_b)
-
-        rfs_out, fired = jax.vmap(one)(rfs, windows, teach, lp_arr)
+        kwargs = p.window_kwargs()
+        if mesh is not None:
+            from repro.distributed import snn_mesh
+            w2, v2, fired, lf2 = snn_mesh.sharded_train_window_batch(
+                rfs.weights, windows, rfs.v, rfs.lfsr, teach, ltp_prob=lp,
+                t_chunk=p.t_chunk, backend=p.kernel_backend, mesh=mesh,
+                **kwargs)
+        else:
+            w2, v2, fired, lf2 = ops.train_window_batch(
+                rfs.weights, windows, rfs.v, rfs.lfsr, teach, ltp_prob=lp,
+                t_chunk=p.t_chunk, backend=p.kernel_backend, **kwargs)
+        rfs_out = rfs._replace(
+            weights=w2, v=v2, lfsr=lf2,
+            spike=windows[:, -1].astype(jnp.uint32))
         counts = jnp.sum(fired.astype(jnp.int32), axis=1)
         return rfs_out, counts, fired
 
@@ -379,37 +272,18 @@ def train_stream(engine: SNNEngine, rf: SnnRegFile,
                  intensities: jnp.ndarray | None = None, seeds=None,
                  n_steps: int | None = None
                  ) -> tuple[SnnRegFile, jnp.ndarray]:
-    """Online STDP over a stream of samples (sequential, as in hardware).
+    """Online STDP over a stream of samples (sequential, as in
+    hardware): the one-stream case of :func:`train_stream_batch`.
 
     Pass EITHER pre-packed ``spike_trains`` uint32[N, T, w] OR uint8
     ``intensities`` [N, n_in] with ``n_steps`` and per-sample counter
-    ``seeds`` i32[N] (default: the engine's seed chain) — the
-    intensity-resident form never materializes the N×T×w spike tensor;
-    each presentation draws its window from the counter hash inside the
-    kernel (``encode="kernel"``) or per-sample on the host.  teach
-    i32[N, n].  Neuron state resets between presentations; weights and
-    LFSR persist.  Returns (rf', spike_counts i32[N, n]).
+    ``seeds`` i32[N] (default: the engine's seed chain); teach
+    i32[N, n].  v resets between presentations; weights and LFSR
+    persist.  Returns (rf', spike_counts i32[N, n]).
     """
-    _one_of(spike_trains, intensities, n_steps, "train_stream")
-    if engine.plan.wta:
-        rf = _with_theta(rf)
-    if intensities is not None:
-        seeds = engine._seeds(seeds, intensities.shape[0])
-
-        def body(carry: SnnRegFile, inp):
-            x, s, tch = inp
-            out = engine.train(reset_between_samples(carry), teach=tch,
-                               intensities=x, seed=s, n_steps=n_steps)
-            return out.regfile, out.spike_counts
-
-        return jax.lax.scan(body, rf, (intensities, seeds, teach))
-
-    def body(carry: SnnRegFile, inp):
-        window, tch = inp
-        out = engine.train(reset_between_samples(carry), window, tch)
-        return out.regfile, out.spike_counts
-
-    return jax.lax.scan(body, rf, (spike_trains, teach))
+    return _only(train_stream_batch(
+        engine, _one(rf), _one(spike_trains), _one(teach),
+        intensities=_one(intensities), seeds=seeds, n_steps=n_steps))
 
 
 def train_stream_batch(engine: SNNEngine, rfs: SnnRegFile,

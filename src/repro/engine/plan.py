@@ -2,14 +2,12 @@
 
 An :class:`SNNEnginePlan` owns every decision that used to be threaded
 through call sites as kwargs (``threshold``/``leak``/``ltp_prob``/
-``backend``/``t_chunk``/``mesh`` across ``ops.py``, ``network.py``,
-``trainer.py`` and ``snn_mesh.py``): LIF/STDP parameters, the kernel
-backend, the cycle path, VMEM chunking, serving batch size and the
-optional neuron-mesh placement.  Plans are frozen dataclasses of plain
-Python scalars (plus an optional :class:`jax.sharding.Mesh`), so the
-parameters stay concrete at trace time and lower as window-kernel
-literals — the engine never hits the traced-parameter fallback the
-legacy ``network.run_sample`` path needed.
+``backend``/``t_chunk``/``mesh`` across ``ops.py``, ``trainer.py`` and
+``snn_mesh.py``): LIF/STDP parameters, the kernel backend, VMEM
+chunking, serving batch size and the optional neuron-mesh placement.
+Plans are frozen dataclasses of plain Python scalars (plus an optional
+:class:`jax.sharding.Mesh`), so the parameters stay concrete at trace
+time and lower as window-kernel literals.
 """
 
 from __future__ import annotations
@@ -22,7 +20,6 @@ from jax.sharding import Mesh
 from repro.core.lif import LIFParams, lif_params
 from repro.core.stdp import STDPParams, stdp_params
 
-_CYCLE_BACKENDS = ("window", "step")
 _KERNEL_BACKENDS = ("ref", "interp", "tpu")
 _ENCODE_BACKENDS = ("host", "kernel")
 
@@ -38,22 +35,20 @@ def default_kernel_backend() -> str:
 class SNNEnginePlan:
     """Everything the engine needs to place and dispatch SNN work.
 
-    ``w_exp=None`` marks an inference-only plan (SU idle): ``train``
-    presents windows without learning, exactly the legacy
-    ``run_sample(stdp=None)`` semantics.  Placement is either an
-    explicit ``mesh`` (any 1-D neuron or 2-D data × neuron Mesh) or the
+    ``w_exp=None`` marks an inference-only plan: ``infer`` serves it,
+    and the train verbs refuse it.  Placement is either an explicit
+    ``mesh`` (any 1-D neuron or 2-D data × neuron Mesh) or the
     declarative ``mesh_shape=(data, neurons)``, which builds the 2-D
-    host mesh on first use — both shard_map the window ops (window path
-    only — the step path is a plain XLA scan).  Batch axes shard over
-    "data", weights/v/LFSR regfiles over "neurons"; per-stream
-    counter-hash seeds are device-independent, so every ``(data,
-    neurons)`` factorization is bit-exact with the 1-D and unsharded
-    paths.
+    host mesh on first use — both shard_map the window ops.  Batch axes
+    shard over "data", weights/v/LFSR regfiles over "neurons";
+    per-stream counter-hash seeds are device-independent, so every
+    ``(data, neurons)`` factorization is bit-exact with the 1-D and
+    unsharded paths.
     """
     # --- LIF / STDP parameters (lower as kernel literals) ---------------
     threshold: int = 192
     leak: int = 16
-    w_exp: int | None = 128     # None => SU idle (inference-only plan)
+    w_exp: int | None = 128     # None => inference-only plan
     gain: int = 4
     n_syn: int = 784
     ltp_prob: int = 16
@@ -65,7 +60,6 @@ class SNNEnginePlan:
     w_inh: int = 0
     theta_plus: int = 0
     # --- dispatch -------------------------------------------------------
-    cycle_backend: str = "window"    # "window" | "step"
     kernel_backend: str | None = None  # "ref" | "interp" | "tpu";
                                        # None = default_kernel_backend()
     t_chunk: int | None = None       # VMEM spike-slab cycles (None = T)
@@ -86,10 +80,6 @@ class SNNEnginePlan:
         if self.kernel_backend is None:
             object.__setattr__(self, "kernel_backend",
                                default_kernel_backend())
-        if self.cycle_backend not in _CYCLE_BACKENDS:
-            raise ValueError(f"cycle_backend must be one of "
-                             f"{_CYCLE_BACKENDS}, got "
-                             f"{self.cycle_backend!r}")
         if self.kernel_backend not in _KERNEL_BACKENDS:
             raise ValueError(f"kernel_backend must be one of "
                              f"{_KERNEL_BACKENDS}, got "
@@ -97,9 +87,6 @@ class SNNEnginePlan:
         if self.encode not in _ENCODE_BACKENDS:
             raise ValueError(f"encode must be one of {_ENCODE_BACKENDS}, "
                              f"got {self.encode!r}")
-        if self.encode == "kernel" and self.cycle_backend != "window":
-            raise ValueError("in-kernel encode requires the window "
-                             "path; use cycle_backend='window'")
         if self.w_inh < 0 or self.theta_plus < 0:
             raise ValueError(f"w_inh and theta_plus must be >= 0, got "
                              f"{self.w_inh} and {self.theta_plus}")
@@ -124,10 +111,6 @@ class SNNEnginePlan:
             if self.mesh is not None:
                 raise ValueError("pass either an explicit mesh or a "
                                  "mesh_shape, not both")
-        if ((self.mesh is not None or self.mesh_shape is not None)
-                and self.cycle_backend != "window"):
-            raise ValueError("mesh placement applies to the window "
-                             "path; use cycle_backend='window'")
 
     # --- derived views ---------------------------------------------------
 
@@ -151,7 +134,7 @@ class SNNEnginePlan:
 
     @property
     def learn(self) -> bool:
-        """Whether the train verb runs the SU (STDP) at all."""
+        """Whether the plan trains (STDP) at all: ``w_exp`` is set."""
         return self.w_exp is not None
 
     def lif(self) -> LIFParams:
@@ -164,16 +147,10 @@ class SNNEnginePlan:
                            self.ltp_prob)
 
     def window_kwargs(self) -> dict:
-        """Static literals for the window kernels (ops.fused_snn_window
-        signature); inference-only plans hand the SU zeroed literals +
-        train=False, matching the legacy ``_window_params`` encoding."""
-        if not self.learn:
-            return dict(threshold=self.threshold, leak=self.leak,
-                        w_exp=0, gain=0, n_syn=1, ltp_prob=0,
-                        train=False)
+        """Static literals of the train kernels (``ops.train_window_batch``
+        and its encode form; ``ltp_prob`` goes in as an operand)."""
         return dict(threshold=self.threshold, leak=self.leak,
-                    w_exp=self.w_exp, gain=self.gain, n_syn=self.n_syn,
-                    ltp_prob=self.ltp_prob, train=True)
+                    w_exp=self.w_exp, gain=self.gain, n_syn=self.n_syn)
 
 
 def plan_from_config(cfg, block_idx: int = 0,
@@ -190,7 +167,6 @@ def plan_from_config(cfg, block_idx: int = 0,
     return SNNEnginePlan(
         threshold=cfg.threshold, leak=cfg.leak, w_exp=cfg.w_exp,
         gain=cfg.gain, n_syn=cfg.n_inputs, ltp_prob=lp,
-        cycle_backend=cfg.cycle_backend,
         kernel_backend=cfg.kernel_backend,
         t_chunk=cfg.window_chunk,
         encode=getattr(cfg, "encode", "host"),

@@ -8,13 +8,14 @@ neuron axis -> multiple of the block size) and backend dispatch:
                     executed in Python — correctness validation)
   backend="tpu"     compiled pl.pallas_call (the deployment target)
 
-The SNN training loop (repro.core.network) calls the *window* ops
-(``fused_snn_window`` / ``infer_window_batch``): one launch covers the
-whole T-cycle presentation window with weights/LFSR resident in VMEM,
-instead of T per-cycle launches that round-trip state through HBM.  The
-ref path of those ops is the same scan-of-steps XLA program the old
-per-cycle path produced, so CPU behavior is unchanged; on TPU the
-``backend="tpu"`` window kernel is the deployment target.
+The engine (repro.engine) calls the *window* ops: ``train_window_batch``
+and ``train_window_batch_encode`` to train, ``infer_window_batch`` and
+``infer_window_batch_encode`` to serve.  One launch covers the whole
+T-cycle presentation window of B streams with weights/LFSR resident in
+VMEM, instead of T per-cycle launches that round-trip state through
+HBM.  Their ref path is a scan of per-cycle steps in XLA
+(``kernels/ref.py``); on TPU the ``backend="tpu"`` window kernels are
+the deployment target.
 """
 
 from __future__ import annotations
@@ -147,42 +148,6 @@ def fused_snn_step(weights, pre_spikes, v, lfsr_state, teach, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "threshold", "leak", "w_exp", "gain", "n_syn", "ltp_prob", "train",
-    "t_chunk", "backend"))
-def fused_snn_window(weights, spike_train, v, lfsr_state, teach, *,
-                     threshold: int, leak: int, w_exp: int, gain: int,
-                     n_syn: int, ltp_prob: int = 1023, train: bool = True,
-                     t_chunk: int | None = None, backend: str = "ref"):
-    """T ``snn.step`` cycles with weights/v/LFSR resident in VMEM.
-
-    spike_train: uint32[T, w].  Bit-exact with T sequential
-    :func:`fused_snn_step` calls (including the LFSR sequence).
-    ``t_chunk`` streams the window through VMEM in t_chunk-cycle slabs
-    (ragged tails are zero-padded and masked) — same results, bounded
-    VMEM for arbitrarily long windows.
-    Returns (weights', v', fired bool[T, n], lfsr').
-    """
-    if backend == "ref":
-        return _ref.fused_snn_window_ref(
-            weights, spike_train, v, lfsr_state, teach, threshold, leak,
-            w_exp, gain, n_syn, ltp_prob, train)
-    n, w = weights.shape
-    t_steps = spike_train.shape[0]
-    bn = max(_block_n(max(8, n)), 8)
-    wp = _pad_state(weights, bn)
-    stp, tc = _pad_window(_pad_to(spike_train, 1, _LANES), t_chunk)
-    vp = _pad_to(v, 0, bn)
-    tp = _pad_to(teach, 0, bn)
-    sp = _pad_state(lfsr_state, bn, fill=1)
-    w2, v2, f, s2 = _k.fused_snn_window(
-        wp, stp, vp, sp, tp, threshold=threshold, leak=leak, w_exp=w_exp,
-        gain=gain, n_syn=n_syn, ltp_prob=ltp_prob, train=train,
-        block_n=bn, t_chunk=tc, t_total=t_steps,
-        interpret=(backend == "interp"))
-    return w2[:n, :w], v2[:n], f[:t_steps, :n], s2[:n, :w]
-
-
-@functools.partial(jax.jit, static_argnames=(
     "threshold", "leak", "w_exp", "gain", "n_syn", "t_chunk", "backend"))
 def train_window_batch(weights, spike_trains, v, lfsr_state, teach, *,
                        threshold: int, leak: int, w_exp: int, gain: int,
@@ -195,8 +160,9 @@ def train_window_batch(weights, spike_trains, v, lfsr_state, teach, *,
     (neuron-block major, batch, time-chunk minor).  ``ltp_prob`` is a
     shared int or a per-stream i32[B] vector (an SMEM scalar operand of
     the kernel, so each stream can keep its own active-learning
-    schedule).  Bit-exact with B sequential :func:`fused_snn_window`
-    runs, including each stream's LFSR sequence.
+    schedule).  Bit-exact with B sequential one-stream windows
+    (:func:`repro.kernels.ref.fused_snn_window_ref`), including each
+    stream's LFSR sequence.  B=1 is one stream's window.
     Returns (weights', v', fired bool[B, T, n], lfsr').
     """
     if backend == "ref":
@@ -242,44 +208,6 @@ def _intensity_words(intensities: jnp.ndarray, words: int) -> jnp.ndarray:
          | jnp.left_shift(x[..., 2], jnp.uint32(16))
          | jnp.left_shift(x[..., 3], jnp.uint32(24)))
     return jnp.swapaxes(w, -1, -2)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "n_steps", "threshold", "leak", "w_exp", "gain", "n_syn", "ltp_prob",
-    "train", "t_chunk", "backend"))
-def fused_snn_window_encode(weights, intensities, seed, v, lfsr_state,
-                            teach, *, n_steps: int, threshold: int,
-                            leak: int, w_exp: int, gain: int, n_syn: int,
-                            ltp_prob: int = 1023, train: bool = True,
-                            t_chunk: int | None = None,
-                            backend: str = "ref"):
-    """:func:`fused_snn_window` with the Poisson encode fused in-kernel.
-
-    intensities: uint8[n_in] (n_in <= w*32), seed: counter base (int or
-    i32 scalar).  The spike window never exists in HBM — each cycle's
-    packed row is drawn in VMEM from ``lfsr.counter_hash`` — and the
-    result is bit-exact with host-encoding
-    ``encoder.encode_from_counter(seed, intensities, n_steps)`` and
-    running the pre-packed window op, for every backend and chunking.
-    Returns (weights', v', fired bool[T, n], lfsr').
-    """
-    if backend == "ref":
-        return _ref.fused_snn_window_encode_ref(
-            weights, intensities, seed, v, lfsr_state, teach, n_steps,
-            threshold, leak, w_exp, gain, n_syn, ltp_prob, train)
-    n, w = weights.shape
-    bn = max(_block_n(max(8, n)), 8)
-    wp = _pad_state(weights, bn)
-    iw = _intensity_words(intensities, wp.shape[1])
-    vp = _pad_to(v, 0, bn)
-    tp = _pad_to(teach, 0, bn)
-    sp = _pad_state(lfsr_state, bn, fill=1)
-    w2, v2, f, s2 = _k.fused_snn_window_encode(
-        wp, iw, jnp.asarray(seed, jnp.int32), vp, sp, tp,
-        n_steps=n_steps, threshold=threshold, leak=leak, w_exp=w_exp,
-        gain=gain, n_syn=n_syn, ltp_prob=ltp_prob, train=train,
-        block_n=bn, t_chunk=t_chunk, interpret=(backend == "interp"))
-    return w2[:n, :w], v2[:n], f[:n_steps, :n], s2[:n, :w]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -398,7 +326,7 @@ def infer_window_batch(weights, spike_trains, *, threshold: int, leak: int,
 
     spike_trains: uint32[B, T, w]; weights frozen, membrane reset per
     sample (``reset_between_samples`` semantics).  ``t_chunk`` bounds
-    the VMEM spike slab as in :func:`fused_snn_window`.
+    the VMEM spike slab as in :func:`train_window_batch`.
     """
     if backend == "ref":
         return _ref.infer_window_batch_ref(weights, spike_trains,

@@ -112,19 +112,6 @@ def _host_windows(seeds, intensities, n_steps: int, words: int,
                                t_total)
 
 
-def fused_snn_window_encode_ref(weights, intensities, seed, v, lfsr_state,
-                                teach, n_steps: int, threshold: int,
-                                leak: int, w_exp: int, gain: int,
-                                n_syn: int, ltp_prob: int,
-                                train: bool = True):
-    """Encode-fused window oracle: host-encode, then the window oracle."""
-    win = _host_windows(seed, intensities[None], n_steps,
-                        weights.shape[1])[0]
-    return fused_snn_window_ref(weights, win, v, lfsr_state, teach,
-                                threshold, leak, w_exp, gain, n_syn,
-                                ltp_prob, train)
-
-
 def train_window_wta_ref(weights, spike_train, v, lfsr_state, teach, theta,
                          threshold: int, leak: int, w_exp: int, gain: int,
                          n_syn: int, ltp_prob, w_inh: int,

@@ -9,14 +9,15 @@ zero pre-word is a no-op and LTD can only clear).  The neuron axis is
 blocked by ``BN`` (multiple of 8, the sublane width).
 
 Time axis (window kernels): **state is VMEM-resident, time is
-streamed**.  ``fused_snn_window`` loads the weight block, LFSR block and
-membrane block once, then a ``fori_loop`` over the T presentation cycles
-reads one (small) packed spike row per cycle and stores one fired row
-into the raster — weights/LFSR cross HBM once per *window*, not once per
-*cycle*.  The batch kernels order the grid (neuron-block major, batch
-minor) so a shared weight block (inference) stays resident across all B
-samples of a serving batch, and B independent training streams share one
-launch.
+streamed**.  ``train_window_batch`` loads each stream's weight block,
+LFSR block and membrane block once, then a ``fori_loop`` over the T
+presentation cycles reads one (small) packed spike row per cycle and
+stores one fired row into the raster — weights/LFSR cross HBM once per
+*window*, not once per *cycle*.  One launch trains B independent
+streams (one stream is B=1).  The packed serving kernel
+``infer_window_batch`` orders its grid (neuron-block major, batch
+minor) so a shared weight block stays resident across all B samples of
+a serving batch.
 
 Chunked spike streaming: every window kernel takes a ``t_chunk`` grid
 dimension (innermost, so per-(block, stream) state carries across
@@ -72,12 +73,13 @@ VMEM budget (per grid step, BN=128, padded words W<=2048):
                  block of the sample block (2 * 32 * n * 4B: 1.6 MiB at
                  n=6,400) — ~5.6 MiB at the 6,400-neuron offline shape.
 
-The fused kernels are the TPU microarchitecture of the paper's
+The fused step is the TPU microarchitecture of the paper's
 coarse-granularity ``snn.step`` instruction: one pass through VMEM does
 spike-process + LIF + STDP, where the unfused path round-trips HBM
-between the three stages — and the window kernels extend the same
-argument across the time axis and the batch/stream axis
-(benchmarks/kernels_bench.py measures all three levels).
+between the three stages.  The window kernels extend the same argument
+across the time axis and the stream axis, and the encode kernels drop
+the spike window from HBM altogether (benchmarks/kernels_bench.py
+measures the step, window and batch levels).
 """
 
 from __future__ import annotations
@@ -423,8 +425,8 @@ def train_window_batch(weights, spike_trains, v, lfsr_state, teach, *,
     neuron-block major, batch next, chunk minor, so each stream's state
     block stays VMEM-resident across all its chunks (the chunk axis
     revisits the same output block; state is carried by reading it
-    back).  Per stream this is bit-exact with :func:`fused_snn_window`
-    (including the LFSR sequence).
+    back).  Per stream this is bit-exact with T sequential
+    :func:`fused_snn_step` cycles (including the LFSR sequence).
 
     ``ltp_prob`` is an int shared by every stream or an i32[B] vector —
     it enters the kernel as a whole-array SMEM operand indexed by the
@@ -477,92 +479,6 @@ def train_window_batch(weights, spike_trains, v, lfsr_state, teach, *,
         interpret=interpret,
     )(lp, weights, spike_trains, v[:, None], lfsr_state, teach[:, None])
     return w2, v2[:, 0], fired, s2
-
-
-# --- time-resident fused window (T cycles per launch) -------------------------
-
-def _window_infer_kernel(threshold, leak, t_chunk, t_total,
-                         w_ref, s_ref, v_ref, t_ref, vo_ref, f_ref):
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        vo_ref[...] = v_ref[...]
-
-    w = w_ref[...]
-    teach = t_ref[...]
-    base = k * t_chunk
-    masked = t_total % t_chunk != 0
-
-    def cycle(t, v):
-        pre = s_ref[pl.ds(t, 1), :]                     # (1, W)
-        v_int = v + _popcount_rows(jnp.bitwise_and(pre, w)) + teach
-        fired = v_int >= threshold
-        v_next = jnp.where(
-            fired, jnp.int32(0), jnp.maximum(v_int - leak, jnp.int32(0)))
-        if masked:
-            active = base + t < t_total
-            fired = jnp.logical_and(fired, active)
-            v_next = jnp.where(active, v_next, v)
-        f_ref[pl.ds(t, 1), :] = fired[None, :]
-        return v_next
-
-    vo_ref[...] = jax.lax.fori_loop(0, t_chunk, cycle, vo_ref[...])
-
-
-def fused_snn_window(weights, spike_train, v, lfsr_state, teach, *,
-                     threshold: int, leak: int, w_exp: int, gain: int,
-                     n_syn: int, ltp_prob: int, train: bool = True,
-                     block_n=128, t_chunk: int | None = None,
-                     t_total: int | None = None, interpret=False):
-    """T fused SNNU cycles with VMEM-resident state (one stream).
-
-    spike_train: uint32[T, w] — the presentation window, streamed one
-    row per inner-loop cycle while weights/v/LFSR stay resident; with
-    ``t_chunk`` set, VMEM holds one t_chunk-row slab of the window at a
-    time (see :func:`train_window_batch` for the carry/masking scheme).
-    Per cycle this is bit-exact with :func:`fused_snn_step` (the LFSR
-    advances through the identical sequence).
-
-    ``train=True`` is the B=1 case of :func:`train_window_batch`.
-    ``train=False`` (SU idle) dispatches to a read-only variant whose
-    launch declares no weight/LFSR outputs — those arrays cross HBM
-    once inbound and the originals are passed through — so the
-    inference window pays none of the state write-back traffic.
-
-    Returns (weights', v', fired bool[T, n], lfsr').
-    """
-    n, w = weights.shape
-    t_steps = spike_train.shape[0]
-    tc = t_steps if t_chunk is None else min(t_chunk, t_steps)
-    if t_steps % tc != 0:
-        raise ValueError(f"T={t_steps} not a multiple of t_chunk={tc}; "
-                         "pad the window (ops.py does)")
-    tt = t_steps if t_total is None else t_total
-    if not train:
-        v2, fired = pl.pallas_call(
-            functools.partial(_window_infer_kernel, int(threshold),
-                              int(leak), tc, tt),
-            out_shape=(jax.ShapeDtypeStruct((n,), jnp.int32),
-                       jax.ShapeDtypeStruct((t_steps, n), jnp.bool_)),
-            grid=(n // block_n, t_steps // tc),
-            in_specs=[
-                pl.BlockSpec((block_n, w), lambda i, k: (i, 0)),
-                pl.BlockSpec((tc, w), lambda i, k: (k, 0)),
-                pl.BlockSpec((block_n,), lambda i, k: (i,)),
-                pl.BlockSpec((block_n,), lambda i, k: (i,)),
-            ],
-            out_specs=(pl.BlockSpec((block_n,), lambda i, k: (i,)),
-                       pl.BlockSpec((tc, block_n), lambda i, k: (k, i))),
-            interpret=interpret,
-        )(weights, spike_train, v, teach)
-        return weights, v2, fired, lfsr_state
-    w2, v2, fired, s2 = train_window_batch(
-        weights[None], spike_train[None], v[None], lfsr_state[None],
-        teach[None], threshold=threshold, leak=leak, w_exp=w_exp,
-        gain=gain, n_syn=n_syn, ltp_prob=ltp_prob, block_n=block_n,
-        t_chunk=tc, t_total=tt, interpret=interpret)
-    return w2[0], v2[0], fired[0], s2[0]
 
 
 # --- batched inference window (serving path) ----------------------------------
@@ -643,38 +559,6 @@ def _t_grid(n_steps: int, t_chunk: int | None) -> tuple[int, int]:
     """(effective chunk, padded cycle count) for an encode-path launch."""
     tc = n_steps if t_chunk is None else max(1, min(t_chunk, n_steps))
     return tc, -(-n_steps // tc) * tc
-
-
-def _window_infer_enc_kernel(threshold, leak, t_chunk, t_total,
-                             seed_ref, w_ref, iw_ref, v_ref, t_ref,
-                             vo_ref, f_ref):
-    k = pl.program_id(1)
-
-    @pl.when(k == 0)
-    def _init():
-        vo_ref[...] = v_ref[...]
-
-    w = w_ref[...]
-    iw = iw_ref[...]
-    teach = t_ref[...]
-    seed = seed_ref[0].astype(jnp.uint32)
-    base = k * t_chunk
-    masked = t_total % t_chunk != 0
-
-    def cycle(t, v):
-        pre = _encode_cycle(seed, (base + t).astype(jnp.uint32), iw)
-        v_int = v + _popcount_rows(jnp.bitwise_and(pre, w)) + teach
-        fired = v_int >= threshold
-        v_next = jnp.where(
-            fired, jnp.int32(0), jnp.maximum(v_int - leak, jnp.int32(0)))
-        if masked:
-            active = base + t < t_total
-            fired = jnp.logical_and(fired, active)
-            v_next = jnp.where(active, v_next, v)
-        f_ref[pl.ds(t, 1), :] = fired[None, :]
-        return v_next
-
-    vo_ref[...] = jax.lax.fori_loop(0, t_chunk, cycle, vo_ref[...])
 
 
 def _train_window_enc_kernel(threshold, leak, w_exp, gain, n_syn,
@@ -1011,50 +895,6 @@ def train_window_batch_encode(weights, intens_words, seeds, v, lfsr_state,
       theta.reshape(b, g, lanes))
     return (w2, v2.reshape(b, n), fired.reshape(b, t_pad, n), s2,
             th2.reshape(b, n), rc[:, 0, 0])
-
-
-def fused_snn_window_encode(weights, intens_words, seed, v, lfsr_state,
-                            teach, *, n_steps: int, threshold: int,
-                            leak: int, w_exp: int, gain: int, n_syn: int,
-                            ltp_prob: int, train: bool = True,
-                            block_n=128, t_chunk: int | None = None,
-                            interpret=False):
-    """One stream, T cycles, spikes generated in VMEM (B=1 of the
-    batched encode grid; ``train=False`` uses a read-only variant as in
-    :func:`fused_snn_window`).
-
-    intens_words u32[8, w], seed i32 scalar.  Returns
-    (weights', v', fired bool[T_pad, n], lfsr').
-    """
-    n, w = weights.shape
-    tc, t_pad = _t_grid(n_steps, t_chunk)
-    if not train:
-        sd = jnp.reshape(jnp.asarray(seed, jnp.int32), (1,))
-        v2, fired = pl.pallas_call(
-            functools.partial(_window_infer_enc_kernel, int(threshold),
-                              int(leak), tc, int(n_steps)),
-            out_shape=(jax.ShapeDtypeStruct((n,), jnp.int32),
-                       jax.ShapeDtypeStruct((t_pad, n), jnp.bool_)),
-            grid=(n // block_n, t_pad // tc),
-            in_specs=[
-                _SMEM_WHOLE,
-                pl.BlockSpec((block_n, w), lambda i, k: (i, 0)),
-                pl.BlockSpec((8, w), lambda i, k: (0, 0)),
-                pl.BlockSpec((block_n,), lambda i, k: (i,)),
-                pl.BlockSpec((block_n,), lambda i, k: (i,)),
-            ],
-            out_specs=(pl.BlockSpec((block_n,), lambda i, k: (i,)),
-                       pl.BlockSpec((tc, block_n), lambda i, k: (k, i))),
-            interpret=interpret,
-        )(sd, weights, intens_words, v, teach)
-        return weights, v2, fired, lfsr_state
-    w2, v2, fired, s2 = train_window_batch_encode(
-        weights[None], intens_words[None], jnp.asarray(seed, jnp.int32),
-        v[None], lfsr_state[None], teach[None], n_steps=n_steps,
-        threshold=threshold, leak=leak, w_exp=w_exp, gain=gain,
-        n_syn=n_syn, ltp_prob=ltp_prob, block_n=block_n, t_chunk=tc,
-        interpret=interpret)
-    return w2[0], v2[0], fired[0], s2[0]
 
 
 # --- serving on the MXU: frozen weights make the input currents a matmul ----

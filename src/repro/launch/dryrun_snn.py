@@ -27,7 +27,8 @@ import jax.numpy as jnp  # noqa: E402
 from repro.configs.wenquxing_snn import WENQUXING_22A  # noqa: E402
 from repro.core.bitpack import n_words  # noqa: E402
 from repro.core.lif import LIFParams  # noqa: E402
-from repro.core.stdp import STDPParams  # noqa: E402
+from repro.core.rvsnn import SnnRegFile  # noqa: E402
+from repro.engine import SNNEngine, train_stream  # noqa: E402
 from repro.launch.dryrun import load_results, save_results  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.roofline import analyze  # noqa: E402
@@ -40,29 +41,24 @@ STREAM = 8  # online-training samples per lowered step
 
 LIF = LIFParams(jnp.int32(WENQUXING_22A.threshold),
                 jnp.int32(WENQUXING_22A.leak))
-STDP = STDPParams(jnp.int32(WENQUXING_22A.w_exp),
-                  jnp.int32(WENQUXING_22A.gain), jnp.int32(N_INPUTS),
-                  jnp.uint32(WENQUXING_22A.ltp_prob))
 
 
 # --- packed (paper-faithful) ----------------------------------------------------
 
 def infer_packed(weights, spike_trains):
     """weights u32[N, W]; spike_trains u32[B, T, W] -> counts i32[B, N]."""
-    from repro.core.network import infer_batch
-    return infer_batch(weights, spike_trains, LIF)
+    return SNNEngine(WENQUXING_22A.plan()).infer(weights, spike_trains)
 
 
 def train_packed(weights, lfsr_state, spike_trains, teach):
     """Online STDP over a sample stream (sequential, as in hardware).
 
     spike_trains u32[S, T, W]; teach i32[S, N]."""
-    from repro.core.rvsnn import SnnRegFile
-    from repro.core.network import train_stream
     rf = SnnRegFile(spike=jnp.zeros((weights.shape[1],), jnp.uint32),
                     v=jnp.zeros((weights.shape[0],), jnp.int32),
                     lfsr=lfsr_state, weights=weights)
-    rf2, counts = train_stream(rf, spike_trains, teach, LIF, STDP)
+    rf2, counts = train_stream(SNNEngine(WENQUXING_22A.plan()), rf,
+                               spike_trains, teach)
     return rf2.weights, rf2.lfsr, counts
 
 

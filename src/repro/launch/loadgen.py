@@ -75,7 +75,6 @@ def _make_engine(args, workload, mode: str):
 
     plan = SNNEnginePlan(threshold=args.threshold, leak=args.leak,
                          n_syn=workload.n_inputs, encode="kernel",
-                         cycle_backend="window",
                          max_batch=args.max_batch, t_chunk=args.t_chunk)
     weights = init_weights(args.neurons, workload.words, density_seed=0)
     policy = SNNServingPolicy(max_queue=args.max_queue,

@@ -355,8 +355,7 @@ def _overload_storm_snn(args) -> None:
     def run_once(scale: float):
         plan = SNNEnginePlan(threshold=192, leak=16,
                              n_syn=workload.n_inputs, encode="kernel",
-                             cycle_backend="window", max_batch=32,
-                             t_chunk=8)
+                             max_batch=32, t_chunk=8)
         weights = init_weights(64, workload.words, density_seed=0)
         eng = SNNServingEngine(
             weights, plan,

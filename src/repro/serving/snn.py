@@ -980,8 +980,7 @@ class SNNServingEngine:
                     "t_lens": [self._t_len(r) for r in batch]})
             plan = eng.plan
             intensity_only = all(r.window is None for r in batch)
-            if (intensity_only and plan.encode == "kernel"
-                    and plan.cycle_backend == "window"):
+            if intensity_only and plan.encode == "kernel":
                 counts = self._serve_intensities(eng, batch, t_pad)
             else:
                 counts = self._serve_windows(eng, batch, t_pad)

@@ -5,15 +5,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import network
 from repro.core.bitpack import pack, unpack
 from repro.core.encoder import poisson_encode, poisson_encode_batch
 from repro.core.lif import lif_params
 from repro.core.preprocess import deskew, preprocess, soft_threshold
-from repro.core.rvsnn import snn_regfile
+from repro.core.rvsnn import snn_regfile, snn_step
 from repro.core.stdp import init_weights, stdp_params
 from repro.core.trainer import SNNTrainConfig, accuracy, train
 from repro.data.digits import make_digits
+from repro.engine import (SNNEngine, SNNEnginePlan, reset_between_samples,
+                          train_stream)
 
 
 def test_poisson_rate_matches_intensity():
@@ -62,9 +63,9 @@ def test_inference_counts_bounded_and_deterministic():
     key = jax.random.key(3)
     trains = poisson_encode_batch(
         key, jax.random.uniform(key, (4, n_in)), T)
-    lif = lif_params(threshold=16, leak=1)
-    c1 = network.infer_batch(w, trains, lif)
-    c2 = network.infer_batch(w, trains, lif)
+    eng = SNNEngine(SNNEnginePlan(threshold=16, leak=1, w_exp=None))
+    c1 = eng.infer(w, trains)
+    c2 = eng.infer(w, trains)
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
     assert (np.asarray(c1) >= 0).all() and (np.asarray(c1) <= T).all()
 
@@ -78,9 +79,9 @@ def test_training_changes_weights_only_for_fired_rows():
     # teacher: drive neuron 0, inhibit the rest hard
     teach = jnp.tile(jnp.array([[100, -10000, -10000, -10000]], jnp.int32),
                      (2, 1))
-    lif = lif_params(threshold=8, leak=0)
-    stdp = stdp_params(60, w_exp=16)
-    rf2, counts = network.train_stream(rf, trains, teach, lif, stdp)
+    eng = SNNEngine(SNNEnginePlan(threshold=8, leak=0, w_exp=16, gain=4,
+                                  n_syn=60, ltp_prob=1023))
+    rf2, counts = train_stream(eng, rf, trains, teach)
     w2 = np.asarray(rf2.weights)
     assert (w2[0] != np.asarray(w0)[0]).any()          # learned
     np.testing.assert_array_equal(w2[1:], np.asarray(w0)[1:])  # inhibited
@@ -110,7 +111,9 @@ def test_end_to_end_learning_beats_chance():
 
 
 def test_window_and_step_paths_bit_exact():
-    """cycle_backend="window" == the per-cycle scan, full regfile."""
+    """The engine's window kernels == a per-cycle scan of
+    ``rvsnn.snn_step``, full regfile: a training stream, then inference
+    on the trained weights."""
     n, words, T, B = 12, 3, 20, 4
     w0 = init_weights(n, words, dense=False)
     lif = lif_params(40, 3)
@@ -121,33 +124,27 @@ def test_window_and_step_paths_bit_exact():
     teach = jnp.asarray(
         np.random.default_rng(2).integers(-50, 50, (B, n), dtype=np.int32))
     rf = snn_regfile(w0)
-    rf_w, c_w = network.train_stream(rf, trains, teach, lif, stdp,
-                                     cycle_backend="window")
-    rf_s, c_s = network.train_stream(rf, trains, teach, lif, stdp,
-                                     cycle_backend="step")
+    eng = SNNEngine(SNNEnginePlan(threshold=40, leak=3, w_exp=30, gain=4,
+                                  n_syn=words * 32, ltp_prob=500))
+    rf_w, c_w = train_stream(eng, rf, trains, teach)
+
+    def sample(carry, inp):
+        window, tch = inp
+        carry, fired = jax.lax.scan(
+            lambda c, x: snn_step(c, x, lif, stdp, tch),
+            reset_between_samples(carry), window)
+        return carry, jnp.sum(fired.astype(jnp.int32), axis=0)
+
+    rf_s, c_s = jax.lax.scan(sample, rf, (trains, teach))
     for a, b in [(rf_w.weights, rf_s.weights), (rf_w.v, rf_s.v),
                  (rf_w.lfsr, rf_s.lfsr), (rf_w.spike, rf_s.spike),
                  (c_w, c_s)]:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    i_w = network.infer_batch(rf_w.weights, trains, lif,
-                              cycle_backend="window")
-    i_s = network.infer_batch(rf_w.weights, trains, lif,
-                              cycle_backend="step")
+    i_w = eng.infer(rf_w.weights, trains)
+    i_s = jax.vmap(lambda window: jnp.sum(jax.lax.scan(
+        lambda c, x: snn_step(c, x, lif, None), snn_regfile(rf_w.weights),
+        window)[1].astype(jnp.int32), axis=0))(trains)
     np.testing.assert_array_equal(np.asarray(i_w), np.asarray(i_s))
-
-
-def test_window_path_falls_back_under_traced_params():
-    """jit with LIFParams as runtime args must still work (step path)."""
-    n, words, T = 8, 2, 10
-    w0 = init_weights(n, words, dense=True)
-    lif = lif_params(16, 1)
-    trains = poisson_encode_batch(
-        jax.random.key(3), jax.random.uniform(jax.random.key(4),
-                                              (2, words * 32)), T)
-    jitted = jax.jit(network.infer_batch)
-    got = jitted(w0, trains, lif)
-    want = network.infer_batch(w0, trains, lif)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_reset_between_samples_clears_state():
@@ -155,7 +152,7 @@ def test_reset_between_samples_clears_state():
     rf = snn_regfile(w)
     rf = rf._replace(v=jnp.array([5, 3, 1], jnp.int32),
                      spike=jnp.array([7, 7], jnp.uint32))
-    rf2 = network.reset_between_samples(rf)
+    rf2 = reset_between_samples(rf)
     assert (np.asarray(rf2.v) == 0).all()
     assert (np.asarray(rf2.spike) == 0).all()
     np.testing.assert_array_equal(np.asarray(rf2.weights), np.asarray(w))

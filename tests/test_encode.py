@@ -15,7 +15,7 @@ from repro.core.bitpack import unpack
 from repro.core.encoder import (encode_from_counter,
                                 encode_from_counter_batch,
                                 quantize_intensities, spike_rate)
-from repro.core.rvsnn import snn_regfile, snn_regfile_batch
+from repro.core.rvsnn import SnnRegFile, snn_regfile, snn_regfile_batch
 from repro.distributed import snn_mesh
 from repro.engine import SNNEngine, SNNEnginePlan
 from repro.kernels import ops
@@ -99,15 +99,35 @@ def test_spike_rate_popcount_per_time_slice():
 @pytest.mark.parametrize("train", [True, False])
 @pytest.mark.parametrize("t_chunk", [None, 4, 2])
 def test_fused_window_encode_matches_host_oracle(backend, train, t_chunk):
+    """One window drawn in the kernel == the host-encoded window: to
+    learn, ``SNNEngine.train`` with ``encode="kernel"`` against the
+    pre-packed train op; with the SU idle, ``SNNEngine.infer`` against
+    the pre-packed serving op."""
     weights, inten, v, teach, st = _operands(3)
-    got = ops.fused_snn_window_encode(
-        weights, inten[0], 7, v, st, teach, n_steps=T, train=train,
-        t_chunk=t_chunk, backend=backend, **KW)
-    want = ops.fused_snn_window(
-        weights, _host_window(7, inten[0], T), v, st, teach, train=train,
+    win = _host_window(7, inten[0], T)
+    plan = SNNEnginePlan(encode="kernel", kernel_backend=backend,
+                         t_chunk=t_chunk, **KW)
+    if not train:
+        got = SNNEngine(plan).infer(weights, intensities=inten[:1],
+                                    seeds=7, n_steps=T)
+        want = ops.infer_window_batch(
+            weights, win[None], threshold=KW["threshold"],
+            leak=KW["leak"], t_chunk=t_chunk, backend=backend)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        return
+    rf = SnnRegFile(spike=jnp.zeros((W,), jnp.uint32), v=v, lfsr=st,
+                    weights=weights)
+    out = SNNEngine(plan).train(rf, intensities=inten[0], seed=7,
+                                teach=teach, n_steps=T)
+    got = (out.regfile.weights, out.regfile.v, out.fired,
+           out.regfile.lfsr)
+    want = ops.train_window_batch(
+        weights[None], win[None], v[None], st[None], teach[None],
         t_chunk=t_chunk, backend=backend, **KW)
     for g, r in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r[0]))
+    np.testing.assert_array_equal(np.asarray(out.regfile.spike),
+                                  np.asarray(win[-1]))
 
 
 @pytest.mark.parametrize("backend", ["ref", "interp"])
@@ -209,15 +229,12 @@ def test_encode_sharded_matches_unsharded_local_mesh():
     want = ops.infer_window_batch_encode(
         weights, inten, seeds, n_steps=T, threshold=60, leak=4)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    for train in (True, False):
-        got = snn_mesh.sharded_fused_snn_window_encode(
-            weights, inten[0], 7, v, st, teach, n_steps=T, train=train,
-            mesh=mesh, **KW)
-        want = ops.fused_snn_window_encode(
-            weights, inten[0], 7, v, st, teach, n_steps=T, train=train,
-            **KW)
-        for g, r in zip(got, want):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+    one = (weights[None], inten[:1], 7, v[None], st[None], teach[None])
+    got = snn_mesh.sharded_train_window_batch_encode(
+        *one, n_steps=T, mesh=mesh, **KW)
+    want = ops.train_window_batch_encode(*one, n_steps=T, **KW)
+    for g, r in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
 # --- engine verbs: encode placement is invisible to results ------------------
@@ -297,8 +314,6 @@ def test_engine_rejects_ambiguous_inputs():
 def test_plan_encode_validation():
     with pytest.raises(ValueError):
         SNNEnginePlan(encode="vmem")
-    with pytest.raises(ValueError):
-        SNNEnginePlan(encode="kernel", cycle_backend="step")
     assert SNNEnginePlan(encode="kernel").encode_seed == 0
     cfg_plan = dataclasses.replace(SNNEnginePlan(), encode_seed=7)
     assert cfg_plan.encode == "host"

@@ -1,6 +1,6 @@
-"""Engine-vs-legacy bit-exactness parity across all four dispatch paths
-(step / window / batch / sharded), plan validation, and the per-stream
-``ltp_prob`` schedule."""
+"""Engine bit-exactness: the verbs against a per-cycle scan of
+``rvsnn.snn_step`` and against each other (one stream / batch /
+sharded), plan validation, and the per-stream ``ltp_prob`` schedule."""
 
 import dataclasses
 
@@ -9,10 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import lfsr, network
-from repro.core.lif import lif_params
-from repro.core.rvsnn import snn_regfile, snn_regfile_batch
-from repro.core.stdp import stdp_params
+from repro.core import lfsr
+from repro.core.rvsnn import snn_regfile, snn_regfile_batch, snn_step
 from repro.core.trainer import SNNTrainConfig, train
 from repro.data.digits import make_digits
 from repro.distributed import snn_mesh
@@ -43,19 +41,36 @@ def _assert_rf_equal(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+def _scan_window(plan, rf, window, teach=None):
+    """One window as a per-cycle scan of ``rvsnn.snn_step`` (the
+    architectural reference): (rf', counts i32[n], fired bool[T, n])."""
+    rf2, fired = jax.lax.scan(
+        lambda c, x: snn_step(c, x, plan.lif(), plan.stdp(), teach),
+        rf, window)
+    return rf2, jnp.sum(fired.astype(jnp.int32), axis=0), fired
+
+
+def _scan_stream(plan, rf, trains, teach):
+    """A sample stream of :func:`_scan_window`, v and the spike register
+    reset before each sample: (rf', counts i32[N, n])."""
+    def sample(carry, inp):
+        carry = carry._replace(v=jnp.zeros_like(carry.v),
+                               spike=jnp.zeros_like(carry.spike))
+        carry, counts, _ = _scan_window(plan, carry, *inp)
+        return carry, counts
+
+    return jax.lax.scan(sample, rf, (trains, teach))
+
+
 # --- plan validation ---------------------------------------------------------
 
 def test_plan_validation():
-    with pytest.raises(ValueError):
-        _plan(cycle_backend="windw")
     with pytest.raises(ValueError):
         _plan(kernel_backend="cuda")
     with pytest.raises(ValueError):
         _plan(t_chunk=0)
     with pytest.raises(ValueError):
         _plan(max_batch=0)
-    with pytest.raises(ValueError):
-        _plan(cycle_backend="step", mesh=snn_mesh.snn_mesh())
     assert not _plan(w_exp=None).learn
     assert _plan().learn
 
@@ -67,60 +82,49 @@ def test_plan_from_config_active_schedule():
     assert plan_from_config(cfg).n_syn == cfg.n_inputs
 
 
-# --- infer: window / step / interp / legacy ---------------------------------
+# --- infer: ref / interp / inference-only plan vs the per-cycle scan -------
 
 def test_infer_parity_all_paths():
     weights, windows, _ = _operands()
-    lif = lif_params(KW["threshold"], KW["leak"])
-    want = network.infer_batch(weights, windows, lif,
-                               cycle_backend="step")
-    for plan in (_plan(), _plan(cycle_backend="step"),
+    rf0 = snn_regfile(weights)
+    want = jax.vmap(
+        lambda win: _scan_window(_plan(w_exp=None), rf0, win)[1])(windows)
+    for plan in (_plan(), _plan(w_exp=None),
                  _plan(kernel_backend="interp", t_chunk=5)):
         got = SNNEngine(plan).infer(weights, windows)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # legacy window entrypoint agrees too
-    np.testing.assert_array_equal(
-        np.asarray(network.infer_batch(weights, windows, lif)),
-        np.asarray(want))
 
 
-# --- train: window / step / SU-idle / legacy --------------------------------
+# --- train: ref / interp vs the per-cycle scan; inference-only plans -------
 
 def test_train_parity_window_vs_step_vs_legacy():
     weights, windows, teach = _operands(1)
-    lif = lif_params(KW["threshold"], KW["leak"])
-    stdp = stdp_params(KW["n_syn"], KW["w_exp"], KW["gain"],
-                       KW["ltp_prob"])
     rf = snn_regfile(weights, seed=9)
-    out_w = SNNEngine(_plan()).train(rf, windows[0], teach)
-    out_s = SNNEngine(_plan(cycle_backend="step")).train(
-        rf, windows[0], teach)
-    leg_w = network.run_sample(rf, windows[0], lif, stdp, teach)
-    leg_s = network.run_sample(rf, windows[0], lif, stdp, teach,
-                               cycle_backend="step")
-    for other in (out_s, leg_w, leg_s):
-        _assert_rf_equal(out_w.regfile, other.regfile)
-        np.testing.assert_array_equal(np.asarray(out_w.spike_counts),
-                                      np.asarray(other.spike_counts))
-        np.testing.assert_array_equal(np.asarray(out_w.fired),
-                                      np.asarray(other.fired))
+    want_rf, want_c, want_f = _scan_window(_plan(), rf, windows[0], teach)
+    for plan in (_plan(), _plan(kernel_backend="interp", t_chunk=5)):
+        out = SNNEngine(plan).train(rf, windows[0], teach)
+        _assert_rf_equal(out.regfile, want_rf)
+        np.testing.assert_array_equal(np.asarray(out.spike_counts),
+                                      np.asarray(want_c))
+        np.testing.assert_array_equal(np.asarray(out.fired),
+                                      np.asarray(want_f))
 
 
 def test_train_su_idle_matches_legacy_inference():
+    """An inference-only plan (``w_exp=None``) no longer trains with the
+    SU idle: ``train`` refuses it as ``train_batch`` does, and ``infer``
+    gives the counts of the SU-idle per-cycle scan."""
     weights, windows, _ = _operands(2)
-    lif = lif_params(KW["threshold"], KW["leak"])
+    plan = _plan(w_exp=None)
     rf = snn_regfile(weights, seed=4)
-    got = SNNEngine(_plan(w_exp=None)).train(rf, windows[0])
-    want = network.run_sample(rf, windows[0], lif, None)
-    _assert_rf_equal(got.regfile, want.regfile)
-    np.testing.assert_array_equal(np.asarray(got.fired),
-                                  np.asarray(want.fired))
-    # SU idle: weights and LFSR untouched
-    np.testing.assert_array_equal(np.asarray(got.regfile.weights),
-                                  np.asarray(weights))
+    with pytest.raises(ValueError, match="learning plan"):
+        SNNEngine(plan).train(rf, windows[0])
+    _, want, _ = _scan_window(plan, rf, windows[0])
+    got = SNNEngine(plan).infer(weights, windows[:1])
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want))
 
 
-# --- train_batch: batched grid vs sequential / step / legacy ----------------
+# --- train_batch: batched grid vs one-stream launches / the scan -----------
 
 def test_train_batch_parity_sequential_and_step():
     weights, windows, _ = _operands(3)
@@ -144,9 +148,10 @@ def test_train_batch_parity_sequential_and_step():
                                       np.asarray(out.spike_counts))
         np.testing.assert_array_equal(np.asarray(fired[i]),
                                       np.asarray(out.fired))
-    # step fallback is bit-exact with the batched window grid
-    rfs3, counts3, fired3 = SNNEngine(
-        _plan(cycle_backend="step")).train_batch(rfs, windows, teach_b)
+    # ... and == the per-cycle scan of every stream
+    rfs3, counts3, fired3 = jax.vmap(
+        lambda rf, win, tch: _scan_window(_plan(), rf, win, tch))(
+            rfs, windows, teach_b)
     _assert_rf_equal(rfs2, rfs3)
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(counts3))
     np.testing.assert_array_equal(np.asarray(fired), np.asarray(fired3))
@@ -162,12 +167,10 @@ def test_train_batch_rejects_inference_plan():
 
 
 def test_stream_helpers_match_legacy_network():
-    """engine.train_stream / train_stream_batch == network legacy
-    entrypoints (same params threaded the old way)."""
+    """engine.train_stream / train_stream_batch == a stream of per-cycle
+    scans of ``rvsnn.snn_step`` (v and the spike register reset before
+    every sample), stream by stream."""
     weights, windows, _ = _operands(5)
-    lif = lif_params(KW["threshold"], KW["leak"])
-    stdp = stdp_params(KW["n_syn"], KW["w_exp"], KW["gain"],
-                       KW["ltp_prob"])
     n_samples = 3
     rng = np.random.default_rng(13)
     trains = jnp.asarray(rng.integers(0, 2**32, (n_samples, T, W),
@@ -177,7 +180,7 @@ def test_stream_helpers_match_legacy_network():
     eng = SNNEngine(_plan())
     rf = snn_regfile(weights, seed=21)
     got_rf, got_c = train_stream(eng, rf, trains, teach)
-    want_rf, want_c = network.train_stream(rf, trains, teach, lif, stdp)
+    want_rf, want_c = _scan_stream(_plan(), rf, trains, teach)
     _assert_rf_equal(got_rf, want_rf)
     np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
 
@@ -186,11 +189,63 @@ def test_stream_helpers_match_legacy_network():
     trains_b = jnp.broadcast_to(trains, (B,) + trains.shape)
     teach_b = jnp.broadcast_to(teach, (B,) + teach.shape)
     got_rfs, got_cb = train_stream_batch(eng, rfs, trains_b, teach_b)
-    want_rfs, want_cb = network.train_stream_batch(rfs, trains_b,
-                                                   teach_b, lif, stdp)
+    want_rfs, want_cb = jax.vmap(
+        lambda r, x, t: _scan_stream(_plan(), r, x, t))(rfs, trains_b,
+                                                        teach_b)
     _assert_rf_equal(got_rfs, want_rfs)
     np.testing.assert_array_equal(np.asarray(got_cb),
                                   np.asarray(want_cb))
+
+
+_ONE_STREAM_CASES = {
+    "packed-local": (False, "host", None),
+    "packed-mesh": (False, "host", (1, 1)),
+    "intensities-host-local": (True, "host", None),
+    "intensities-kernel-local": (True, "kernel", None),
+    "intensities-host-mesh": (True, "host", (1, 1)),
+    "intensities-kernel-mesh": (True, "kernel", (1, 1)),
+}
+
+
+@pytest.mark.parametrize("case", list(_ONE_STREAM_CASES))
+def test_train_is_one_stream_of_train_batch(case):
+    """``train`` and ``train_stream`` are the B=1 cases of
+    ``train_batch`` and ``train_stream_batch``: identical regfiles,
+    counts and rasters for both input forms, both encodes and a
+    ``mesh_shape=(1, 1)`` plan."""
+    inten_form, encode, mesh_shape = _ONE_STREAM_CASES[case]
+    weights, windows, teach = _operands(41)
+    rng = np.random.default_rng(43)
+    inten = jnp.asarray(rng.integers(0, 256, (B, W * 32), dtype=np.uint8))
+    eng = SNNEngine(_plan(encode=encode, mesh_shape=mesh_shape,
+                          encode_seed=3))
+    rf = snn_regfile(weights, seed=45)
+    rfs = jax.tree.map(lambda x: x[None], rf)
+    if inten_form:
+        one = dict(intensities=inten[0], seed=7, n_steps=T)
+        many = dict(intensities=inten[:1], seeds=7, n_steps=T)
+        stream = dict(intensities=inten, seeds=jnp.arange(B), n_steps=T)
+        stream_b = dict(intensities=inten[None], seeds=jnp.arange(B),
+                        n_steps=T)
+    else:
+        one, many = dict(window=windows[0]), dict(windows=windows[:1])
+        stream = dict(spike_trains=windows)
+        stream_b = dict(spike_trains=windows[None])
+    out = eng.train(rf, teach=teach, **one)
+    rfs2, counts, fired = eng.train_batch(rfs, teach=teach[None], **many)
+    _assert_rf_equal(out.regfile, jax.tree.map(lambda x: x[0], rfs2))
+    np.testing.assert_array_equal(np.asarray(out.spike_counts),
+                                  np.asarray(counts[0]))
+    np.testing.assert_array_equal(np.asarray(out.fired),
+                                  np.asarray(fired[0]))
+
+    teach_s = jnp.broadcast_to(teach, (B, N))
+    rf_s, counts_s = train_stream(eng, rf, teach=teach_s, **stream)
+    rfs_s, counts_b = train_stream_batch(eng, rfs, teach=teach_s[None],
+                                         **stream_b)
+    _assert_rf_equal(rf_s, jax.tree.map(lambda x: x[0], rfs_s))
+    np.testing.assert_array_equal(np.asarray(counts_s),
+                                  np.asarray(counts_b[0]))
 
 
 # --- sharded dispatch (plan placement) ---------------------------------------
@@ -317,8 +372,6 @@ def test_plan_mesh_shape_validation_and_roundtrip():
     for bad in ((0, 2), (2,), (1, 2, 3), ("2", "4")):
         with pytest.raises(ValueError):
             _plan(mesh_shape=bad)
-    with pytest.raises(ValueError):
-        _plan(mesh_shape=(1, 1), cycle_backend="step")
     with pytest.raises(ValueError):
         _plan(mesh_shape=(1, 1), mesh=snn_mesh.snn_mesh())
 

@@ -9,6 +9,8 @@ import numpy as np
 import pytest
 
 from repro.core import lfsr
+from repro.core.rvsnn import SnnRegFile
+from repro.engine import SNNEngine, SNNEnginePlan
 from repro.kernels import ops, ref
 
 SHAPES = [(8, 1), (10, 25), (40, 25), (128, 32), (256, 130), (33, 7)]
@@ -91,54 +93,83 @@ def _window_operands(n, w, t_steps, seed=0):
     return weights, spk, v, teach, st
 
 
+def _train_one(weights, spk, v, st, teach, *, t_chunk=None, **kw):
+    """One stream's window through ``SNNEngine.train`` (the B=1 launch of
+    ``train_window_batch``) in interpret mode: (weights', v', fired, lfsr')."""
+    plan = SNNEnginePlan(kernel_backend="interp", t_chunk=t_chunk, **kw)
+    rf = SnnRegFile(spike=jnp.zeros(spk.shape[1:], jnp.uint32), v=v,
+                    lfsr=st, weights=weights)
+    out = SNNEngine(plan).train(rf, spk, teach)
+    return out.regfile.weights, out.regfile.v, out.fired, out.regfile.lfsr
+
+
+def _infer_counts(weights, spk, *, t_chunk=None, threshold, leak, **_):
+    """Counts of one window served by ``infer_window_batch`` (interp)."""
+    return ops.infer_window_batch(weights, spk[None], threshold=threshold,
+                                  leak=leak, t_chunk=t_chunk,
+                                  backend="interp")[0]
+
+
 @pytest.mark.parametrize("n,w", [(8, 1), (10, 25), (33, 7), (128, 32)])
 @pytest.mark.parametrize("train", [True, False])
 def test_fused_window_equals_sequential_steps(n, w, train):
-    """Window kernel == T sequential fused steps, bit-exact incl. LFSR."""
+    """Window kernel == T sequential fused steps, bit-exact incl. LFSR:
+    learning through ``SNNEngine.train``; with the SU idle, the counts
+    of the serving kernel from a reset membrane and no teacher."""
     t_steps = 9
     weights, spk, v, teach, st = _window_operands(n, w, t_steps)
     kw = dict(threshold=60, leak=4, w_exp=64, gain=4, n_syn=w * 32,
               ltp_prob=200)
-    got = ops.fused_snn_window(weights, spk, v, st, teach, train=train,
-                               backend="interp", **kw)
+    if not train:
+        v, teach = jnp.zeros_like(v), jnp.zeros_like(teach)
     wq, vq, sq = weights, v, st
     raster = []
     for t in range(t_steps):
         wq, vq, f, sq = ops.fused_snn_step(
             wq, spk[t], vq, sq, teach, train=train, backend="ref", **kw)
         raster.append(np.asarray(f))
+    if not train:
+        got = _infer_counts(weights, spk, **kw)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.stack(raster).sum(axis=0))
+        return
+    got = _train_one(weights, spk, v, st, teach, **kw)
     want = (wq, vq, np.stack(raster), sq)
     for g, r in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
+def _one_stream(*operands):
+    return tuple(x[None] for x in operands)
+
+
 def test_fused_window_zero_teach_matches_no_teach_ref():
-    """teach=0 through the kernel == teach-free sequential reference."""
+    """teach=0 through the B=1 batched kernel == teach-free sequential
+    reference."""
     n, w, t_steps = 10, 3, 7
     weights, spk, v, _, st = _window_operands(n, w, t_steps, seed=2)
     kw = dict(threshold=30, leak=2, w_exp=32, gain=4, n_syn=w * 32,
               ltp_prob=1023)
-    got = ops.fused_snn_window(weights, spk, v, st,
-                               jnp.zeros((n,), jnp.int32),
-                               backend="interp", **kw)
+    got = ops.train_window_batch(
+        *_one_stream(weights, spk, v, st, jnp.zeros((n,), jnp.int32)),
+        backend="interp", **kw)
     want = ref.fused_snn_window_ref(weights, spk, v, st, None,
                                     kw["threshold"], kw["leak"],
                                     kw["w_exp"], kw["gain"], kw["n_syn"],
                                     kw["ltp_prob"])
     for g, r in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+        np.testing.assert_array_equal(np.asarray(g[0]), np.asarray(r))
 
 
 def test_fused_window_ref_matches_interp():
-    """ops dispatch: ref and interp backends agree on the window op."""
+    """ops dispatch: ref and interp backends agree on the B=1 window."""
     n, w, t_steps = 40, 25, 12
     weights, spk, v, teach, st = _window_operands(n, w, t_steps, seed=4)
     kw = dict(threshold=50, leak=4, w_exp=128, gain=4, n_syn=w * 32,
               ltp_prob=16)
-    a = ops.fused_snn_window(weights, spk, v, st, teach,
-                             backend="ref", **kw)
-    b = ops.fused_snn_window(weights, spk, v, st, teach,
-                             backend="interp", **kw)
+    operands = _one_stream(weights, spk, v, st, teach)
+    a = ops.train_window_batch(*operands, backend="ref", **kw)
+    b = ops.train_window_batch(*operands, backend="interp", **kw)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
@@ -153,13 +184,12 @@ def test_infer_window_batch_bit_exact(n, w, b):
                                  backend="interp")
     want = ref.infer_window_batch_ref(weights, trains, 40, 3)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # and each batch row == the single-sample window op (inference mode)
+    # and each batch row == the one-window oracle with the SU idle
     for i in range(b):
-        _, _, fired, _ = ops.fused_snn_window(
+        _, _, fired, _ = ref.fused_snn_window_ref(
             weights, trains[i], jnp.zeros((n,), jnp.int32),
-            jnp.ones((n, w), jnp.uint32), jnp.zeros((n,), jnp.int32),
-            threshold=40, leak=3, w_exp=0, gain=0, n_syn=1, ltp_prob=0,
-            train=False, backend="interp")
+            jnp.ones((n, w), jnp.uint32), None, 40, 3, 0, 0, 1, 0,
+            train=False)
         np.testing.assert_array_equal(
             np.asarray(got[i]),
             np.asarray(jnp.sum(fired.astype(jnp.int32), axis=0)))
@@ -168,7 +198,8 @@ def test_infer_window_batch_bit_exact(n, w, b):
 @pytest.mark.parametrize("t_chunk", [1, 2, 4, 5, 9, 16])
 @pytest.mark.parametrize("train", [True, False])
 def test_chunked_window_equals_unchunked(t_chunk, train):
-    """t_chunk-slab streaming == whole-window launch, bit-exact.
+    """t_chunk-slab streaming == whole-window launch, bit-exact: learning
+    through ``SNNEngine.train``, the SU idle through the serving kernel.
 
     Covers dividing chunks (1, 9), ragged tails (2, 4, 5) and
     t_chunk > T (16) at T=9.
@@ -177,10 +208,13 @@ def test_chunked_window_equals_unchunked(t_chunk, train):
     weights, spk, v, teach, st = _window_operands(n, w, t_steps, seed=6)
     kw = dict(threshold=60, leak=4, w_exp=64, gain=4, n_syn=w * 32,
               ltp_prob=200)
-    want = ops.fused_snn_window(weights, spk, v, st, teach, train=train,
-                                backend="interp", **kw)
-    got = ops.fused_snn_window(weights, spk, v, st, teach, train=train,
-                               t_chunk=t_chunk, backend="interp", **kw)
+    if not train:
+        want = _infer_counts(weights, spk, **kw)
+        got = _infer_counts(weights, spk, t_chunk=t_chunk, **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        return
+    want = _train_one(weights, spk, v, st, teach, **kw)
+    got = _train_one(weights, spk, v, st, teach, t_chunk=t_chunk, **kw)
     for g, r in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
@@ -220,8 +254,10 @@ def test_train_window_batch_equals_sequential_streams(n, w, b, backend):
     got = ops.train_window_batch(weights, spk, v, st, teach,
                                  backend=backend, **kw)
     for i in range(b):
-        want = ops.fused_snn_window(weights[i], spk[i], v[i], st[i],
-                                    teach[i], backend="ref", **kw)
+        want = ref.fused_snn_window_ref(
+            weights[i], spk[i], v[i], st[i], teach[i], kw["threshold"],
+            kw["leak"], kw["w_exp"], kw["gain"], kw["n_syn"],
+            kw["ltp_prob"])
         for g, r in zip(got, want):
             np.testing.assert_array_equal(np.asarray(g[i]),
                                           np.asarray(r))

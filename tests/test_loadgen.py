@@ -244,7 +244,7 @@ def _engine(wl, clock):
     from repro.serving.snn import SNNServingEngine, SNNServingPolicy
 
     plan = SNNEnginePlan(threshold=192, leak=16, n_syn=wl.n_inputs,
-                         encode="kernel", cycle_backend="window",
+                         encode="kernel",
                          max_batch=16, t_chunk=8)
     return SNNServingEngine(
         init_weights(32, wl.words, density_seed=0), plan,

@@ -225,7 +225,7 @@ N_INPUTS = 256
 
 def _plan(max_batch=16):
     return SNNEnginePlan(threshold=192, leak=16, n_syn=N_INPUTS,
-                         encode="kernel", cycle_backend="window",
+                         encode="kernel",
                          max_batch=max_batch, t_chunk=8)
 
 

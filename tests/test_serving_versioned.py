@@ -28,7 +28,7 @@ from repro.serving import (FaultInjector, FaultSpec, SNNRefreshPolicy,
 N_CLASSES, BLOCKS, N_IN, W = 4, 2, 64, 2
 N = N_CLASSES * BLOCKS
 PLAN = SNNEnginePlan(threshold=24, leak=2, w_exp=128, n_syn=N_IN,
-                     encode="kernel", cycle_backend="window",
+                     encode="kernel",
                      max_batch=4, t_chunk=8)
 T = 16
 NEURON_CLASS = np.tile(np.arange(N_CLASSES), BLOCKS)

@@ -1,22 +1,24 @@
 """Batched training grid at the network/trainer level, and the keyed
 trainer randomness fix."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import network
 from repro.core.encoder import poisson_encode_batch
-from repro.core.lif import lif_params
-from repro.core.rvsnn import snn_regfile, snn_regfile_batch
-from repro.core.stdp import init_weights, stdp_params
+from repro.core.rvsnn import snn_regfile, snn_regfile_batch, snn_step
+from repro.core.stdp import init_weights
 from repro.core.trainer import SNNTrainConfig, _train_block, train
 from repro.data.digits import make_digits
+from repro.engine import (SNNEngine, SNNEnginePlan, train_stream,
+                          train_stream_batch)
 
 
 def _stream_operands(b, n, words, n_samples, t_steps):
-    lif = lif_params(40, 3)
-    stdp = stdp_params(words * 32, w_exp=30, gain=4, ltp_prob=500)
+    plan = SNNEnginePlan(threshold=40, leak=3, w_exp=30, gain=4,
+                         n_syn=words * 32, ltp_prob=500)
     w0 = init_weights(n, words, dense=False)
     trains = jnp.stack([
         poisson_encode_batch(
@@ -26,22 +28,22 @@ def _stream_operands(b, n, words, n_samples, t_steps):
         for i in range(b)])
     teach = jnp.asarray(np.random.default_rng(2).integers(
         -50, 50, (b, n_samples, n), dtype=np.int32))
-    return lif, stdp, w0, trains, teach
+    return plan, w0, trains, teach
 
 
 def test_train_stream_batch_matches_sequential_streams():
     """Each batched stream == a sequential train_stream run (weights,
     membrane, LFSR sequence and spike counts)."""
     b, n, words, n_samples, t_steps = 3, 12, 3, 4, 20
-    lif, stdp, w0, trains, teach = _stream_operands(
+    plan, w0, trains, teach = _stream_operands(
         b, n, words, n_samples, t_steps)
     seeds = [101, 202, 303]
     rfs = snn_regfile_batch(jnp.broadcast_to(w0, (b, n, words)), seeds)
-    rfs2, counts = network.train_stream_batch(rfs, trains, teach, lif,
-                                              stdp)
+    eng = SNNEngine(plan)
+    rfs2, counts = train_stream_batch(eng, rfs, trains, teach)
     for i in range(b):
-        rf2, c2 = network.train_stream(snn_regfile(w0, seed=seeds[i]),
-                                       trains[i], teach[i], lif, stdp)
+        rf2, c2 = train_stream(eng, snn_regfile(w0, seed=seeds[i]),
+                               trains[i], teach[i])
         np.testing.assert_array_equal(np.asarray(rfs2.weights[i]),
                                       np.asarray(rf2.weights))
         np.testing.assert_array_equal(np.asarray(rfs2.lfsr[i]),
@@ -53,13 +55,26 @@ def test_train_stream_batch_matches_sequential_streams():
 
 
 def test_train_stream_batch_step_fallback_matches_window():
+    """The batched window kernels == a per-cycle scan of
+    ``rvsnn.snn_step`` over every stream's samples (v reset between
+    samples)."""
     b, n, words, n_samples, t_steps = 2, 10, 2, 3, 12
-    lif, stdp, w0, trains, teach = _stream_operands(
+    plan, w0, trains, teach = _stream_operands(
         b, n, words, n_samples, t_steps)
     rfs = snn_regfile_batch(jnp.broadcast_to(w0, (b, n, words)), [7, 9])
-    rw, cw = network.train_stream_batch(rfs, trains, teach, lif, stdp)
-    rs, cs = network.train_stream_batch(rfs, trains, teach, lif, stdp,
-                                        cycle_backend="step")
+    rw, cw = train_stream_batch(SNNEngine(plan), rfs, trains, teach)
+
+    def stream(rf, trains_b, teach_b):
+        def sample(carry, inp):
+            window, tch = inp
+            carry, fired = jax.lax.scan(
+                lambda c, x: snn_step(c, x, plan.lif(), plan.stdp(), tch),
+                carry._replace(v=jnp.zeros_like(carry.v)), window)
+            return carry, jnp.sum(fired.astype(jnp.int32), axis=0)
+
+        return jax.lax.scan(sample, rf, (trains_b, teach_b))
+
+    rs, cs = jax.vmap(stream)(rfs, trains, teach)
     for a, bb in [(rw.weights, rs.weights), (rw.v, rs.v),
                   (rw.lfsr, rs.lfsr), (cw, cs)]:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(bb))
@@ -67,13 +82,13 @@ def test_train_stream_batch_step_fallback_matches_window():
 
 def test_train_stream_batch_interp_kernel_matches_ref():
     b, n, words, n_samples, t_steps = 2, 10, 2, 2, 9
-    lif, stdp, w0, trains, teach = _stream_operands(
+    plan, w0, trains, teach = _stream_operands(
         b, n, words, n_samples, t_steps)
     rfs = snn_regfile_batch(jnp.broadcast_to(w0, (b, n, words)), [3, 5])
-    rr, cr = network.train_stream_batch(rfs, trains, teach, lif, stdp)
-    ri, ci = network.train_stream_batch(rfs, trains, teach, lif, stdp,
-                                        kernel_backend="interp",
-                                        window_chunk=4)
+    rr, cr = train_stream_batch(SNNEngine(plan), rfs, trains, teach)
+    ri, ci = train_stream_batch(
+        SNNEngine(dataclasses.replace(plan, kernel_backend="interp",
+                                      t_chunk=4)), rfs, trains, teach)
     for a, bb in [(rr.weights, ri.weights), (rr.v, ri.v),
                   (rr.lfsr, ri.lfsr), (cr, ci)]:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(bb))

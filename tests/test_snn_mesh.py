@@ -41,14 +41,12 @@ def test_sharded_ops_match_unsharded_on_local_mesh():
     want = ops.infer_window_batch(weights, trains, threshold=60, leak=4)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
-    for train in (True, False):
-        got = snn_mesh.sharded_fused_snn_window(
-            weights, trains[0], v, st, teach, train=train, mesh=mesh,
-            **kw)
-        want = ops.fused_snn_window(weights, trains[0], v, st, teach,
-                                    train=train, **kw)
-        for g, r in zip(got, want):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+    # one stream: the B=1 launch of the batched train op
+    one = (weights[None], trains[:1], v[None], st[None], teach[None])
+    got = snn_mesh.sharded_train_window_batch(*one, mesh=mesh, **kw)
+    want = ops.train_window_batch(*one, **kw)
+    for g, r in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
 def test_sharded_handles_non_divisible_neuron_axis():

@@ -4,7 +4,8 @@ Interpret mode checks what a kernel computes, not whether the TPU
 compiler accepts its blocks: block tiling, SMEM operands and Mosaic's
 vector shape casts are only checked by compiling for the chip.  These
 tests compile the main path's window ops at the paper's width (784
-inputs, 40 neurons, T=72, B=32), the packed serving op at the
+inputs, 40 neurons, T=72, B=32, and the one-stream B=1 launches that
+``SNNEngine.train`` makes), the packed serving op at the
 4,096-neuron ensemble, the encode serving op at the offline cell's
 shape (6,400 neurons, B=256), the encode train op at the unsupervised
 cell's shape (one stream of 6,400 neurons with lateral inhibition and
@@ -74,8 +75,6 @@ def _window_op_cases(sharding, n: int, b: int = B):
 
     u, i, u8 = jnp.uint32, jnp.int32, jnp.uint8
     w = N_IN_WORDS
-    state = (s((n, w), u), s((T, w), u), s((n,), i), s((n, w), u),
-             s((n,), i))
     return {
         "infer_window_batch": (
             ops.infer_window_batch, (s((n, w), u), s((B, T, w), u)),
@@ -94,12 +93,17 @@ def _window_op_cases(sharding, n: int, b: int = B):
             (s((B, n, w), u), s((B, 784), u8), s((B,), i), s((B, n), i),
              s((B, n, w), u), s((B, n), i)),
             dict(n_steps=T, ltp_prob=s((B,), i), **LIF, **SU)),
-        "fused_snn_window_train": (
-            ops.fused_snn_window, state,
-            dict(ltp_prob=16, train=True, **LIF, **SU)),
-        "fused_snn_window_infer": (
-            ops.fused_snn_window, state,
-            dict(ltp_prob=16, train=False, **LIF, **SU)),
+        # one stream (SNNEngine.train): the B=1 launches
+        "train_window_batch_b1": (
+            ops.train_window_batch,
+            (s((1, n, w), u), s((1, T, w), u), s((1, n), i),
+             s((1, n, w), u), s((1, n), i)),
+            dict(ltp_prob=16, **LIF, **SU)),
+        "train_window_batch_encode_b1": (
+            ops.train_window_batch_encode,
+            (s((1, n, w), u), s((1, 784), u8), s((1,), i), s((1, n), i),
+             s((1, n, w), u), s((1, n), i)),
+            dict(n_steps=T, ltp_prob=16, **LIF, **SU)),
     }
 
 
@@ -110,7 +114,7 @@ def _compiled_text(op, args, kw) -> str:
 @pytest.mark.parametrize("name", [
     "infer_window_batch", "infer_window_batch_encode",
     "train_window_batch", "train_window_batch_encode",
-    "fused_snn_window_train", "fused_snn_window_infer"])
+    "train_window_batch_b1", "train_window_batch_encode_b1"])
 def test_window_op_compiles_for_v5e_at_paper_width(one_chip, name):
     op, args, kw = _window_op_cases(one_chip, 40)[name]
     assert "tpu_custom_call" in _compiled_text(op, args, kw)
